@@ -203,7 +203,9 @@ def _analyzer_step_v5(
             pallas_counters_merge,
         )
 
-        per_partition = pallas_counters_merge(m.per_partition, delta)
+        per_partition = pallas_counters_merge(
+            m.per_partition, delta, interpret=config.pallas_interpret
+        )
     else:
         per_partition = m.per_partition + delta
     earliest, latest, smallest, largest = extremes_update(
@@ -303,8 +305,14 @@ def analyzer_step(
 
     m = state.metrics
     if config.use_pallas_counters:
+        import functools
+
         from kafka_topic_analyzer_tpu.ops.pallas_counters import (
-            pallas_counters_update as counters_fn,
+            pallas_counters_update,
+        )
+
+        counters_fn = functools.partial(
+            pallas_counters_update, interpret=config.pallas_interpret
         )
     else:
         counters_fn = counters_update

@@ -23,6 +23,7 @@ Multi-device runs go through `kafka_topic_analyzer_tpu.parallel.sharded`
 
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -40,6 +41,7 @@ from kafka_topic_analyzer_tpu.backends.step import (
     superbatch_fold,
 )
 from kafka_topic_analyzer_tpu.config import AnalyzerConfig, DispatchConfig
+from kafka_topic_analyzer_tpu.jax_support import require_device_platform
 from kafka_topic_analyzer_tpu.models.state import AnalyzerState
 from kafka_topic_analyzer_tpu.obs import metrics as obs_metrics
 from kafka_topic_analyzer_tpu.packing import (
@@ -195,9 +197,14 @@ class TpuBackend(MetricBackend):
         use_native: bool = True,
         dispatch: "DispatchConfig | None" = None,
     ):
+        self.device = device if device is not None else jax.devices()[0]
+        require_device_platform(self.device)
+        if config.use_pallas_counters:
+            config = dataclasses.replace(
+                config, pallas_interpret=self.device.platform != "tpu"
+            )
         super().__init__(config)
         self.init_now_s = utc_now_seconds() if init_now_s is None else init_now_s
-        self.device = device if device is not None else jax.devices()[0]
         self.use_native = use_native
         key = str(self.device)
         if key not in _checked_devices and not os.environ.get("KTA_SKIP_SELFCHECK"):

@@ -59,6 +59,7 @@ class StaleLeaseEpochError(ValueError):
 #: unchanged.
 _EXECUTION_ONLY_FIELDS = (
     "use_pallas_counters",
+    "pallas_interpret",
     "wire_format",
     "alive_compaction",
 )

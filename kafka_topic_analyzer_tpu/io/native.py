@@ -37,25 +37,44 @@ _SO_NAME = f"libkta_ingest.v{_ABI}.so"
 _DISABLE_ENV = "KTA_DISABLE_NATIVE"
 
 
+def _host_isa() -> str:
+    """The instruction set a build on this host targets: the Makefile
+    compiles with ``-march=native``, so a binary built on one CPU may
+    trap (SIGILL) on another.  The CPU flags line names it."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line
+    except OSError:
+        pass
+    import platform
+
+    return platform.machine()
+
+
 def _build_dir() -> str:
-    """Prefer the in-tree build dir; for read-only installs (site-packages
-    owned by root, containers) fall back to a per-user cache.  The cache
-    key includes a hash of ingest.cpp, not just the ABI — the ABI is an
-    interface version, so a source bugfix without an interface change
-    must still invalidate the cached binary (in-tree builds get this from
-    make's mtime check)."""
-    in_tree = os.path.join(_NATIVE_DIR, "build")
+    """Where the shim for THIS source on THIS CPU lives: keyed on the ABI
+    and a hash of ingest.cpp and the host's instruction set, so a binary
+    left on disk (and copied along with the tree to another machine) is
+    never loaded in place of one built here from the source beside it —
+    the ABI is an interface version, and a source change without an
+    interface change must still select a fresh build.  The in-tree build
+    dir is preferred; read-only installs (site-packages owned by root,
+    containers) fall back to a per-user cache under the same key."""
+    import hashlib
+
+    digest = hashlib.sha256(_host_isa().encode())
+    with open(os.path.join(_NATIVE_DIR, "ingest.cpp"), "rb") as f:
+        digest.update(f.read())
+    key = f"v{_ABI}-{digest.hexdigest()[:12]}"
+    in_tree = os.path.join(_NATIVE_DIR, "build", key)
     if os.access(_NATIVE_DIR, os.W_OK) or os.path.exists(
         os.path.join(in_tree, _SO_NAME)
     ):
         return in_tree
-    import hashlib
+    return os.path.join(os.path.expanduser("~"), ".cache", "kta-native", key)
 
-    with open(os.path.join(_NATIVE_DIR, "ingest.cpp"), "rb") as f:
-        src = hashlib.sha256(f.read()).hexdigest()[:12]
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "kta-native", f"v{_ABI}-{src}"
-    )
 
 _lock = threading.Lock()
 _lib: "ctypes.CDLL | None" = None
@@ -80,13 +99,27 @@ class _KtaSynthSpec(ctypes.Structure):
 
 
 def _build(build_dir: str) -> None:
-    os.makedirs(build_dir, exist_ok=True)
-    subprocess.run(
-        ["make", "-C", _NATIVE_DIR, "-s", f"BUILD={build_dir}"],
-        check=True,
-        capture_output=True,
-        text=True,
-    )
+    """Build into a private directory, then move the library into place:
+    processes that build at once (test workers, a broker child and its
+    parent) each publish a whole file, and a loader never maps a half
+    written one."""
+    import shutil
+
+    tmp = f"{build_dir}.tmp-{os.getpid()}"
+    os.makedirs(tmp, exist_ok=True)
+    try:
+        subprocess.run(
+            ["make", "-C", _NATIVE_DIR, "-s", f"BUILD={tmp}"],
+            check=True,
+            capture_output=True,
+            text=True,
+        )
+        os.makedirs(build_dir, exist_ok=True)
+        os.replace(
+            os.path.join(tmp, _SO_NAME), os.path.join(build_dir, _SO_NAME)
+        )
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
 
 
 def load_library(build_if_missing: bool = True) -> ctypes.CDLL:

@@ -1,6 +1,6 @@
 """Scan doctor: automated bottleneck attribution from telemetry.
 
-Converts the manual BENCH_NOTES ledger procedure (rounds 7/9/10/11:
+Converts the manual bench-ledger procedure (rounds 7/9/10/11:
 reconstruct per-stage seconds from counters, argue which stage gated the
 scan) into computed, tested verdicts.  Inputs are the SAME merged
 registry snapshot ``--json`` embeds and ``gather_telemetry`` aggregates
@@ -23,8 +23,8 @@ verdicts:
   bottleneck.
 - **dispatch-bound** — the drive loop sits in dispatch, and decisively in
   the throttle wait (``kta_dispatch_throttle_seconds_total``); the
-  ingest workers stall on FULL queues.  The device (or the dispatch
-  tunnel) is the bottleneck, and ingest parallelism cannot help.
+  ingest workers stall on FULL queues.  The device (or the host→device
+  dispatch) is the bottleneck, and ingest parallelism cannot help.
 - **balanced** — neither stage dominates (the pipeline overlap is doing
   its job), or too little was booked to call it.
 

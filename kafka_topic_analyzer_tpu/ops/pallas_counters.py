@@ -7,6 +7,9 @@ sum (guide: /opt/skills/guides/pallas_guide.md):
 
     contrib[16, N] · one_hot[N, P] → [16, P]    (per 1024-record block)
 
+Each input streams as a (1, 1024) lane row of a (1, B) view, so every
+operand inside the kernel stays 2-D.
+
 **Exactness.**  The MXU accumulates in f32, which is exact only below 2^24.
 Counts are 0/1 so they are safe, but byte lengths are not — so the two byte
 channels are decomposed into 12-bit digits (lo = v & 0xFFF, hi = v >> 12):
@@ -24,9 +27,14 @@ Channel plane layout (rows of the [16, P] accumulator):
 
 Partition counts beyond 128 tile the grid's leading dimension (one
 accumulator pass per 128-partition tile), and the kernel runs under
-`shard_map` meshes (parallel/sharded.py relaxes the vma check for it).
+`shard_map` meshes (parallel/sharded.py relaxes the vma check for the
+interpreter only).
 Enabled by ``AnalyzerConfig.use_pallas_counters``; the lax scatter path
 remains the default until the kernel is benchmarked faster on real hardware.
+
+``interpret`` is the caller's choice: the backends set it from the platform
+of their own device (``AnalyzerConfig.pallas_interpret``), so a kernel
+compiled for a TPU is never silently swapped for the interpreter.
 """
 
 from __future__ import annotations
@@ -36,9 +44,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kafka_topic_analyzer_tpu.jax_support import jnp, varying_mesh_axes
+from kafka_topic_analyzer_tpu.jax_support import jnp
 
-#: Records per grid step: an (8, 128) int32 tile.
+#: Records per grid step: one (1, 1024) int32 lane row per input.
 BLOCK = 1024
 #: Max records per pallas_call: keeps i32 scratch sums < 2^31
 #: (2^18 · 4095 ≈ 1.07e9).
@@ -57,12 +65,14 @@ def _kernel(part_ref, klen_ref, vlen_ref, kn_ref, vn_ref, valid_ref, out_ref, ac
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    part = part_ref[:].reshape(-1)          # [BLOCK] i32
-    klen = klen_ref[:].reshape(-1)
-    vlen = vlen_ref[:].reshape(-1)
-    kn = kn_ref[:].reshape(-1)              # i32 0/1: valid & key non-null
-    vn = vn_ref[:].reshape(-1)              # i32 0/1: valid & value non-null
-    valid = valid_ref[:].reshape(-1)        # i32 0/1
+    # Every operand stays 2-D: a (1, BLOCK) lane row per input (Mosaic
+    # cannot relayout an (8, 128) tile into a flat vector).
+    part = part_ref[:]                      # [1, BLOCK] i32
+    klen = klen_ref[:]
+    vlen = vlen_ref[:]
+    kn = kn_ref[:]                          # i32 0/1: valid & key non-null
+    vn = vn_ref[:]                          # i32 0/1: valid & value non-null
+    valid = valid_ref[:]                    # i32 0/1
 
     tomb = valid - vn                       # valid & value_null
     knull = valid - kn
@@ -80,24 +90,27 @@ def _kernel(part_ref, klen_ref, vlen_ref, kn_ref, vn_ref, valid_ref, out_ref, ac
         k_bytes >> 12,                       # key_size_sum hi
         v_bytes >> 12,                       # value_size_sum hi
     ]
-    zeros = jnp.zeros_like(valid)
-    planes += [zeros] * (PLANES - len(planes))
-    contrib = jnp.stack(planes).astype(jnp.float32)        # [16, BLOCK]
+    # Plane c lands on sublane c by select (rows 9..15 stay zero).
+    row = jax.lax.broadcasted_iota(jnp.int32, (PLANES, BLOCK), 0)
+    contrib = jnp.zeros((PLANES, BLOCK), jnp.int32)
+    for c, plane in enumerate(planes):
+        contrib = jnp.where(row == c, plane, contrib)
+    contrib = contrib.astype(jnp.float32)                   # [16, BLOCK]
 
-    # One-hot over this tile's partition range [j·128, (j+1)·128); invalid
-    # records carry partition 0 but all their contribution planes are 0,
-    # so they add nothing.
-    iota = jax.lax.broadcasted_iota(jnp.int32, (BLOCK, PART_TILE), 1)
+    # Transposed one-hot over this tile's partition range
+    # [j·128, (j+1)·128); invalid records carry partition 0 but all their
+    # contribution planes are 0, so they add nothing.
+    iota = jax.lax.broadcasted_iota(jnp.int32, (PART_TILE, BLOCK), 0)
     iota = iota + j * PART_TILE
-    one_hot = (part[:, None] == iota).astype(jnp.float32)  # [BLOCK, 128]
+    one_hot_t = (part == iota).astype(jnp.float32)         # [128, BLOCK]
 
     # precision=HIGHEST: without it the MXU may run f32 operands through
     # bf16 passes, whose 8-bit mantissa cannot represent the 12-bit digit
     # planes — preferred_element_type alone only fixes the accumulator.
     block_out = jax.lax.dot_general(
         contrib,
-        one_hot,
-        dimension_numbers=(((1,), (0,)), ((), ())),
+        one_hot_t,
+        dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=jax.lax.Precision.HIGHEST,
     )                                                       # [16, 128] tile j
@@ -108,20 +121,23 @@ def _kernel(part_ref, klen_ref, vlen_ref, kn_ref, vn_ref, valid_ref, out_ref, ac
         out_ref[:] = acc_ref[:]
 
 
+#: Block-index zero as i32: under jax_enable_x64 a bare ``0`` in an index
+#: map traces as i64, which Mosaic cannot return next to the i32 grid ids.
+_ZERO = np.int32(0)
+
+
 def _call(part, klen, vlen, kn, vn, valid, p_pad: int, interpret: bool):
     n = part.shape[0]
     assert n % BLOCK == 0 and n <= MAX_CALL
     assert p_pad % PART_TILE == 0
-    rows = n // 128
 
-    def shape2d(x):
-        return x.reshape(rows, 128)
+    def row(x):
+        return x.reshape(1, n)
 
-    block_rows = BLOCK // 128
     # Under a check_vma shard_map the output aval must declare its
     # varying mesh axes; the reduction preserves the inputs' variance
     # (per-device records → per-device counts), so propagate their vma.
-    vma = varying_mesh_axes(part) or None
+    vma = jax.typeof(part).vma or None
     out_aval = (
         jax.ShapeDtypeStruct((PLANES, p_pad), jnp.int32, vma=vma)
         if vma
@@ -129,19 +145,16 @@ def _call(part, klen, vlen, kn, vn, valid, p_pad: int, interpret: bool):
     )
     # Partition tiles lead the grid so each tile streams all record
     # blocks through its own accumulator pass (i innermost).
-    in_spec = pl.BlockSpec((block_rows, 128), lambda j, i: (i, 0))
+    in_spec = pl.BlockSpec((1, BLOCK), lambda j, i: (_ZERO, i))
     out = pl.pallas_call(
         _kernel,
-        grid=(p_pad // PART_TILE, rows // block_rows),
+        grid=(p_pad // PART_TILE, n // BLOCK),
         in_specs=[in_spec] * 6,
-        out_specs=pl.BlockSpec((PLANES, PART_TILE), lambda j, i: (0, j)),
+        out_specs=pl.BlockSpec((PLANES, PART_TILE), lambda j, i: (_ZERO, j)),
         out_shape=out_aval,
         scratch_shapes=[pltpu.VMEM((PLANES, PART_TILE), jnp.int32)],
         interpret=interpret,
-    )(
-        shape2d(part), shape2d(klen), shape2d(vlen),
-        shape2d(kn), shape2d(vn), shape2d(valid),
-    )
+    )(row(part), row(klen), row(vlen), row(kn), row(vn), row(valid))
     return out
 
 
@@ -161,9 +174,6 @@ def pallas_counters_update(
     b = partition.shape[0]
     if b % BLOCK != 0:
         raise ValueError(f"batch size {b} must be a multiple of {BLOCK}")
-    # The compiled kernel targets TPU; on the CPU platform (tests, virtual
-    # meshes) fall back to the interpreter automatically.
-    interpret = interpret or jax.default_backend() == "cpu"
     kn = (valid & ~key_null).astype(jnp.int32)
     vn = (valid & ~value_null).astype(jnp.int32)
     v32 = valid.astype(jnp.int32)
@@ -176,7 +186,7 @@ def pallas_counters_update(
     # Under a check_vma shard_map the kernel output varies over the mesh
     # axes its inputs vary over; the zeros accumulator starts replicated
     # and must be explicitly cast to match before the add.
-    axes = tuple(sorted(varying_mesh_axes(partition)))
+    axes = tuple(sorted(jax.typeof(partition).vma))
     if axes:
         total = jax.lax.pvary(total, axes)
     for lo in range(0, b, MAX_CALL):
@@ -204,8 +214,7 @@ def pallas_counters_update(
 # arrives as a pre-reduced i64[P, 7] delta table: there is no scatter left
 # for the one-hot matmul above to replace — the whole fold is an elementwise
 # i64 add.  This kernel keeps the pallas path compiled against the v5 table
-# inputs (still untimed on real hardware — blocked since BENCH round 2; see
-# round 11): the add runs on the VPU as two u32 digit planes with an
+# inputs (untimed on the chip): the add runs on the VPU as two u32 digit planes with an
 # explicit carry, the same exactness discipline as the matmul kernel's
 # 12-bit digits (TPU pallas has no native i64 lanes).
 
@@ -226,7 +235,6 @@ def pallas_counters_merge(per_partition, delta, interpret: bool = False):
     counter tables via a pallas VPU kernel — exact for every i64 value
     (lo/hi u32 digits with carry).  Drop-in for the plain jnp add the
     default v5 path uses; selected by ``use_pallas_counters``."""
-    interpret = interpret or jax.default_backend() == "cpu"
     shape = per_partition.shape
     n = 1
     for d in shape:
@@ -237,7 +245,7 @@ def pallas_counters_merge(per_partition, delta, interpret: bool = False):
         flat = v.reshape(-1)
         if pad:
             zeros = jnp.zeros((pad,), dtype=flat.dtype)
-            axes = tuple(sorted(varying_mesh_axes(v)))
+            axes = tuple(sorted(jax.typeof(v).vma))
             if axes:
                 # Under a check_vma shard_map the pad constant starts
                 # replicated and must match the data's variance to concat.
@@ -253,7 +261,7 @@ def pallas_counters_merge(per_partition, delta, interpret: bool = False):
     alo, ahi = planes(per_partition)
     blo, bhi = planes(delta.astype(jnp.int64))
     rows = alo.shape[0]
-    vma = varying_mesh_axes(per_partition) | varying_mesh_axes(delta)
+    vma = jax.typeof(per_partition).vma | jax.typeof(delta).vma
     vma = vma or None
 
     def out_aval(dtype):
@@ -261,7 +269,7 @@ def pallas_counters_merge(per_partition, delta, interpret: bool = False):
             return jax.ShapeDtypeStruct((rows, 128), dtype, vma=vma)
         return jax.ShapeDtypeStruct((rows, 128), dtype)
 
-    spec = pl.BlockSpec((_MERGE_ROWS, 128), lambda i: (i, 0))
+    spec = pl.BlockSpec((_MERGE_ROWS, 128), lambda i: (i, _ZERO))
     lo, hi = pl.pallas_call(
         _merge_kernel,
         grid=(rows // _MERGE_ROWS,),

@@ -3,7 +3,7 @@
 The reference consumes a topic strictly sequentially (src/kafka.rs:74-137),
 and the single-device scan path used to as well: one ``batches()`` stream
 feeding the device through a depth-2 prefetch thread, which caps a scan at
-the one-core host-ingest ceiling (BENCH_NOTES.md round 5: ~3.2-3.7M rec/s).
+the one-core host-ingest ceiling (round 5, host-CPU: ~3.2-3.7M rec/s).
 The same round's multi-stream measurement showed the GIL share stays flat
 as streams are added (the native fetch/decode/pack path releases the GIL),
 so the way past the ceiling is more ingest *threads*, not faster ones.

@@ -39,6 +39,7 @@ like the single-device path.
 
 from __future__ import annotations
 
+import dataclasses as _dc
 import functools
 from typing import List, Optional
 
@@ -66,7 +67,11 @@ from kafka_topic_analyzer_tpu.packing import (
     unpack_device,
     unpack_pair_table_device,
 )
-from kafka_topic_analyzer_tpu.jax_support import jnp, lax, shard_map
+from kafka_topic_analyzer_tpu.jax_support import (
+    jnp,
+    lax,
+    require_device_platform,
+)
 from kafka_topic_analyzer_tpu.models.compaction import AliveBitmapState
 from kafka_topic_analyzer_tpu.models.message_metrics import MessageMetricsState
 from kafka_topic_analyzer_tpu.models.state import AnalyzerState
@@ -106,6 +111,22 @@ def _state_specs(config: AnalyzerConfig) -> AnalyzerState:
     hll = HLLState(regs=P(_DEV)) if config.enable_hll else None
     quantiles = DDSketchState(counts=P(_DEV)) if config.enable_quantiles else None
     return AnalyzerState(metrics=metrics, alive=alive, hll=hll, quantiles=quantiles)
+
+
+def _stack(x, axes):
+    """Every device's ``x`` on a new leading axis, replicated over ``axes``.
+
+    Each device writes ``x`` into its own row of zeros and a psum
+    assembles the rows.  Two things need that over pmin/pmax/all_gather:
+    the TPU lowers only SUM all-reduces of 64-bit integers, so 64-bit
+    min/max merges reduce this table; and JAX infers a psum's result as
+    replicated, which an all_gather's is not.  Callers stack small
+    (per-partition or per-process) values, so the bytes stay small."""
+    table = jnp.zeros((lax.axis_size(axes),) + x.shape, x.dtype)
+    table = lax.dynamic_update_index_in_dim(
+        table, x, lax.axis_index(axes), 0
+    )
+    return lax.psum(table, axes)
 
 
 def _global_put(x: np.ndarray, mesh, spec) -> jax.Array:
@@ -199,9 +220,15 @@ class ShardedTpuBackend(MetricBackend):
         use_native: bool = True,
         dispatch: "DispatchConfig | None" = None,
     ):
+        self.mesh = mesh if mesh is not None else make_mesh(*config.mesh_shape)
+        device = self.mesh.devices.flat[0]
+        require_device_platform(device)
+        if config.use_pallas_counters:
+            config = _dc.replace(
+                config, pallas_interpret=device.platform != "tpu"
+            )
         super().__init__(config)
         self.init_now_s = utc_now_seconds() if init_now_s is None else init_now_s
-        self.mesh = mesh if mesh is not None else make_mesh(*config.mesh_shape)
         if dict(zip(self.mesh.axis_names, self.mesh.devices.shape)) != {
             DATA_AXIS: config.data_shards,
             SPACE_AXIS: config.space_shards,
@@ -213,7 +240,6 @@ class ShardedTpuBackend(MetricBackend):
         # (data, space) device — shape (D, S, chunk_nbytes).
         self._buf_sharding = NamedSharding(self.mesh, P(DATA_AXIS, SPACE_AXIS))
         self._row_sharding = NamedSharding(self.mesh, P(DATA_AXIS))
-        import dataclasses as _dc
 
         if config.batch_size % config.space_shards:
             raise ValueError(
@@ -290,14 +316,12 @@ class ShardedTpuBackend(MetricBackend):
 
         # The Pallas counter kernel declares its varying axes (vma) so the
         # static checker stays on for compiled TPU runs; jax's pallas
-        # INTERPRETER (the CPU fallback the tests/virtual meshes use)
-        # internally builds replicated constants that trip the checker —
-        # a jax-side limitation its own error message acknowledges — so
-        # only that combination relaxes it.
-        relax_vma = (
-            config.use_pallas_counters and jax.default_backend() == "cpu"
-        )
-        step = shard_map(
+        # INTERPRETER (what a mesh off the TPU runs) internally builds
+        # replicated constants that trip the checker — a jax-side
+        # limitation its own error message acknowledges — so only that
+        # combination relaxes it.
+        relax_vma = config.use_pallas_counters and config.pallas_interpret
+        step = jax.shard_map(
             _step_body,
             mesh=self.mesh,
             in_specs=(
@@ -359,7 +383,7 @@ class ShardedTpuBackend(MetricBackend):
                 token = jnp.sum(n_valid).astype(jnp.int32).reshape(1, 1)
                 return jax.tree.map(lambda x: x[None], local), token
 
-            superstep = shard_map(
+            superstep = jax.shard_map(
                 _superstep_body,
                 mesh=self.mesh,
                 in_specs=(
@@ -412,10 +436,10 @@ class ShardedTpuBackend(MetricBackend):
             dev_axes = (DATA_AXIS, SPACE_AXIS)
             merged = MessageMetricsState(
                 per_partition=lax.psum(m.per_partition, dev_axes),
-                earliest_s=lax.pmin(m.earliest_s, dev_axes),
-                latest_s=lax.pmax(m.latest_s, dev_axes),
-                smallest=lax.pmin(m.smallest, dev_axes),
-                largest=lax.pmax(m.largest, dev_axes),
+                earliest_s=_stack(m.earliest_s, dev_axes).min(0),
+                latest_s=_stack(m.latest_s, dev_axes).max(0),
+                smallest=_stack(m.smallest, dev_axes).min(0),
+                largest=_stack(m.largest, dev_axes).max(0),
                 overall_size=lax.psum(m.overall_size, dev_axes),
                 overall_count=lax.psum(m.overall_count, dev_axes),
             )
@@ -427,9 +451,11 @@ class ShardedTpuBackend(MetricBackend):
                 )
                 pops = jnp.sum(lax.population_count(words).astype(jnp.int64))
                 # The OR-reduced words are equal on every data shard but vma
-                # still marks them varying over 'data'; a scalar pmax makes
-                # the replication explicit (and is a no-op numerically).
-                alive_count = lax.pmax(lax.psum(pops, SPACE_AXIS), DATA_AXIS)
+                # still marks them varying over 'data'; a max over the data
+                # rows makes the replication explicit (a no-op numerically).
+                alive_count = _stack(
+                    lax.psum(pops, SPACE_AXIS), DATA_AXIS
+                ).max(0)
             hll_regs = (
                 lax.pmax(local.hll.regs, dev_axes) if local.hll is not None else None
             )
@@ -446,7 +472,7 @@ class ShardedTpuBackend(MetricBackend):
             P() if config.enable_hll else None,
             P() if config.enable_quantiles else None,
         )
-        return shard_map(
+        return jax.shard_map(
             merge_body,
             mesh=self.mesh,
             in_specs=(specs,),
@@ -674,7 +700,7 @@ class ShardedTpuBackend(MetricBackend):
                 return lax.psum(x, DATA_AXIS)
 
             self._any_fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     body,
                     mesh=self.mesh,
                     in_specs=P(DATA_AXIS),
@@ -733,7 +759,7 @@ class ShardedTpuBackend(MetricBackend):
         # Round 1: agree on the widest payload (pmax over 'data').
         if not hasattr(self, "_pmax_fn"):
             self._pmax_fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     lambda x: lax.pmax(x, DATA_AXIS),
                     mesh=self.mesh,
                     in_specs=P(DATA_AXIS),
@@ -744,11 +770,13 @@ class ShardedTpuBackend(MetricBackend):
         width = int(np.asarray(
             self._pmax_fn(_row_array(lens, self._row_sharding, d))
         ).max())
-        # Round 2: all_gather the length-prefixed, zero-padded rows.  Not
+        # Round 2: gather the length-prefixed, zero-padded rows, as int32
+        # (a psum of bytes is not a reduction every backend lowers) and
+        # replicated, as the P(None, None) out_spec requires.  Not
         # cached/jitted: the width varies per call and this runs once per
         # scan.
-        gather = shard_map(
-            lambda x: lax.all_gather(x, DATA_AXIS, tiled=True),
+        gather = jax.shard_map(
+            lambda x: _stack(x[0].astype(jnp.int32), DATA_AXIS),
             mesh=self.mesh,
             in_specs=P(DATA_AXIS, None),
             out_specs=P(None, None),
@@ -763,7 +791,7 @@ class ShardedTpuBackend(MetricBackend):
         sharding = NamedSharding(self.mesh, P(DATA_AXIS, None))
         gathered = np.asarray(
             jax.jit(gather)(_row_array(rows, sharding, d))
-        )
+        ).astype(np.uint8)
         out: "dict[int, dict]" = {}
         for r in range(d):
             n = int.from_bytes(gathered[r, :4].tobytes(), "big")

@@ -243,7 +243,7 @@ def render_bottleneck(diagnosis) -> str:
     ranked verdict, the per-stage occupancy it was computed from, the
     queue-theory evidence, and (when a flight recorder ran) the windowed
     verdict timeline — the shipped replacement for the hand-built
-    BENCH_NOTES ledger procedure."""
+    bench-ledger procedure."""
     if diagnosis is None:
         return ""
     pct = lambda v: f"{v * 100.0:.0f}%"  # noqa: E731

@@ -19,7 +19,9 @@ magic/crc (io/kafka_codec.py:encode_record_batch), and record offset
 deltas are relative to base_offset — so an 8-byte patch retargets a batch
 to any window at memcpy speed.  Distinct templates carry distinct key
 sets, so HLL/alive-key paths still see `templates × records_per_batch`
-unique keys cycling through the topic.
+unique keys cycling through the topic.  With at least as many templates
+as partitions, each partition cycles through its own template range, so
+a key lives in one partition, as Kafka's key partitioner places it.
 """
 
 from __future__ import annotations
@@ -110,6 +112,8 @@ class TemplateBroker:
         self.tmpl_tails = [memoryview(t)[8:] for t in templates]
         self.windows = windows_per_partition
         self.templates = templates
+        #: Templates each partition cycles through (disjoint ranges).
+        self.per_partition = max(1, len(templates) // partitions)
         self.R = records_per_batch
         self.end_offset = windows_per_partition * records_per_batch
         #: N listener sockets = N advertised broker nodes (partition p is
@@ -218,7 +222,6 @@ class TemplateBroker:
         and the KIP-74 request-level ``max_bytes``, with the first
         non-empty partition always granted one whole batch (minOneMessage),
         which the wire client's starvation logic relies on."""
-        K = len(self.templates)
         plan = []  # (pid, err, first_window, n_windows, rs_bytes)
         budget = max_bytes
         served_any = False
@@ -233,7 +236,7 @@ class TemplateBroker:
             while w0 + n < self.windows and (
                 size < lim or (n == 0 and not served_any)
             ):
-                size += len(self.templates[(w0 + n) % K])
+                size += len(self.templates[self._template(pid, w0 + n)])
                 n += 1
             if n:
                 served_any = True
@@ -255,8 +258,13 @@ class TemplateBroker:
             for i in range(n):
                 w = w0 + i
                 iov.append(struct.pack(">q", w * self.R))
-                iov.append(self.tmpl_tails[w % K])
+                iov.append(self.tmpl_tails[self._template(pid, w)])
         return iov
+
+    def _template(self, pid: int, window: int) -> int:
+        """Index of the template served as ``window`` of partition ``pid``."""
+        k = self.per_partition
+        return (pid * k + window % k) % len(self.templates)
 
     def _dispatch(self, api_key: int, api_version: int, r: kc.ByteReader) -> bytes:
         if api_key == kc.API_VERSIONS:
@@ -361,9 +369,18 @@ class BrokerProcess:
             daemon=True,
         )
         self._proc.start()
-        if not self._parent.poll(120):
-            self._proc.terminate()
-            raise RuntimeError("bench broker failed to start within 120s")
+        deadline = time.monotonic() + 120
+        while not self._parent.poll(0.5):
+            # The parent still holds the child's pipe end, so a child that
+            # dies never shows as EOF: watch the process itself.
+            if not self._proc.is_alive():
+                raise RuntimeError(
+                    f"bench broker exited with code {self._proc.exitcode} "
+                    "before serving"
+                )
+            if time.monotonic() > deadline:
+                self._proc.terminate()
+                raise RuntimeError("bench broker failed to start within 120s")
         return self._parent.recv()
 
     def __exit__(self, *exc) -> None:
@@ -430,7 +447,7 @@ def run(argv: "list[str] | None" = None) -> int:
                     help="run the pipeline flight recorder during the "
                          "scan and print the doctor's BOTTLENECK verdict "
                          "— the shipped replacement for the manual "
-                         "BENCH_NOTES ledger procedure. Also the overhead "
+                         "bench-ledger procedure. Also the overhead "
                          "referee: an A/B against a run without this flag "
                          "must stay within 2%% (DESIGN.md §17)")
     ap.add_argument("--service-obs", metavar="DIR",
@@ -497,14 +514,6 @@ def run(argv: "list[str] | None" = None) -> int:
         wire_format={"v4": 4, "v5": 5}[args.wire_format],
         alive_compaction=args.alive_compaction,
     )
-    degraded = False
-    if args.backend == "tpu":
-        from kafka_topic_analyzer_tpu.jax_support import (
-            detect_cpu_fallback,
-            ensure_responsive_accelerator,
-        )
-
-        degraded = not ensure_responsive_accelerator() or detect_cpu_fallback()
     # Same validation as the CLI (cli.resolve_dispatch): an explicit
     # --superbatch K>1 on the cpu backend is rejected, never silently
     # dropped — a published bench number must not claim a dispatch
@@ -639,12 +648,6 @@ def run(argv: "list[str] | None" = None) -> int:
                 for k, v in diagnosis.window_share.items()
             },
         }
-    if degraded:
-        # Same honesty rule as bench.py; --backend cpu runs are deliberate
-        # host pipeline measurements and keep their ratio.
-        from kafka_topic_analyzer_tpu.jax_support import mark_degraded
-
-        mark_degraded(doc)
     print(json.dumps(doc))
     return 0
 

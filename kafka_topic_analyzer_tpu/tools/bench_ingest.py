@@ -1,5 +1,5 @@
 """Host-ingest capacity benchmark: where does the wire drain bind, and what
-is the per-core ceiling?  (VERDICT r2 next #3.)
+is the per-core ceiling?
 
 Three measurements, one JSON line:
 
@@ -15,7 +15,6 @@ Three measurements, one JSON line:
    production, NIC/softirq work lands on other cores and the remote
    broker's send cost is not ours).
 
-The per-core ceiling analysis derived from these lives in BENCH_NOTES.md.
 This replaces profiling the reference's consume loop (src/kafka.rs:92-135,
 whose published figure is 590,221 msgs/s end to end).
 """
@@ -39,8 +38,7 @@ class _StallSampler(threading.Thread):
     """Measures the client process's longest GIL-held stretches during a
     drain: a thread asking for a 1 ms sleep can only resume once it can
     re-acquire the GIL, so (observed - requested) bounds the serialized
-    GIL-held share that would block a second drain thread (VERDICT r3 #5 /
-    r4 #3 — is the 3.1M rec/s/core x N-core extrapolation killed by the
+    GIL-held share that would block a second drain thread (is the 3.1M rec/s/core x N-core extrapolation killed by the
     GIL?).  On a 1-core box, OS timeslices granted to the broker child
     land in the same delay, so this is an UPPER bound on GIL stalls."""
 
@@ -329,8 +327,7 @@ def main(argv: "list[str] | None" = None) -> int:
         rates.append(n / dt)
     # Best is the headline (capacity is a max: on a shared box interference
     # only subtracts), but the median and full run list ship alongside so a
-    # lucky draw over a wide spread cannot read as the typical rate
-    # (VERDICT r3 weak #5).
+    # lucky draw over a wide spread cannot read as the typical rate.
     if rates:
         doc["pipeline_msgs_per_sec"] = round(max(rates))
         doc["pipeline_msgs_per_sec_median"] = round(
@@ -374,7 +371,7 @@ def main(argv: "list[str] | None" = None) -> int:
         )
 
     # --- 3b: socket-free pipeline, N concurrent threads ------------------
-    # Referee for the parallel-ingest design claim (BENCH_NOTES r5/r6):
+    # Referee for the parallel-ingest design claim (rounds 5/6):
     # the client's fetch→decode→pack compute parallelizes across threads
     # because the native path releases the GIL.  Measured WITHOUT sockets,
     # so loopback-TCP kernel time (which inflates the --workers scan's sys

@@ -1,62 +1,35 @@
-"""Shared hardware measurement protocol.
-
-One implementation of the two measurements both bench.py (the driver's
-headline JSON line) and tools/bench_hw.py (the staged campaign) report, so
-the protocols cannot drift: host->device transfer bandwidth, and the
-analyzer-step rate with donated state (streamed or device-resident).
+"""The two device measurements bench.py's breakdown reports: host->device
+transfer bandwidth, and the device-resident analyzer-step rate with
+donated state.
 """
 
 from __future__ import annotations
 
 import time
-from typing import Optional, Sequence
 
 
-def measure_transfer_gbps(dev=None, mib_sizes: Sequence[int] = (8,)) -> dict:
-    """Time one synchronous device_put per size; returns {MiB: GB/s}."""
+#: The size of the one timed put behind bench.py's `transfer_gbps`.
+TRANSFER_MIB = 8
+
+
+def transfer_gbps() -> float:
+    """Time one synchronous ``TRANSFER_MIB`` device_put; returns GB/s."""
     import jax
     import numpy as np
 
     # Untimed warmup put: the process's first transfer pays one-time
-    # allocator/stream setup, which would otherwise deflate the first
-    # size's figure.
-    jax.device_put(np.ones((1 << 16,), np.uint8), dev).block_until_ready()
-    out = {}
-    for mib in mib_sizes:
-        host = np.ones((mib << 20,), np.uint8)
-        t0 = time.perf_counter()
-        d = jax.device_put(host, dev)
-        d.block_until_ready()
-        out[mib] = round(mib / 1024 / (time.perf_counter() - t0), 4)
-        del d
-    return out
+    # allocator/stream setup, which would otherwise deflate the figure.
+    jax.device_put(np.ones((1 << 16,), np.uint8)).block_until_ready()
+    host = np.ones((TRANSFER_MIB << 20,), np.uint8)
+    t0 = time.perf_counter()
+    jax.device_put(host).block_until_ready()
+    return round(TRANSFER_MIB / 1024 / (time.perf_counter() - t0), 4)
 
 
-#: The size every reporter uses for its comparable `transfer_gbps` figure.
-HEADLINE_TRANSFER_MIB = 8
-
-
-def headline_transfer_gbps(dev=None) -> float:
-    """The single-put bandwidth figure reported as `transfer_gbps` by both
-    bench.py and tools/bench_hw.py — one policy, one key, comparable
-    across reports."""
-    return measure_transfer_gbps(dev, (HEADLINE_TRANSFER_MIB,))[
-        HEADLINE_TRANSFER_MIB
-    ]
-
-
-def timed_step_loop(
-    config,
-    feed,
-    *,
-    steps: int,
-    device_resident: bool,
-    dev=None,
-    state=None,
-) -> dict:
+def timed_step_loop(config, feed, *, steps: int) -> dict:
     """Warmup-compile the packed analyzer step, then time `steps` steps
-    with donated state, cycling `feed` (packed uint8 buffers — device
-    arrays when ``device_resident`` else host arrays put each step).
+    with donated state, cycling `feed` (packed uint8 buffers already on
+    the device).
 
     Returns {"msgs_per_sec", "compile_s", "state"} — rate uses
     config.batch_size records per step.
@@ -66,12 +39,8 @@ def timed_step_loop(
     from kafka_topic_analyzer_tpu.backends.tpu import make_packed_step
     from kafka_topic_analyzer_tpu.models.state import AnalyzerState
 
-    if state is None:
-        state = AnalyzerState.init(config)
+    state = AnalyzerState.init(config)
     step = jax.jit(make_packed_step(config), donate_argnums=(0,))
-
-    def put(buf):
-        return buf if device_resident else jax.device_put(buf, dev)
 
     # Compacted alive configs take a pair-table buffer per step.  The
     # feed is already-packed rows (no decoded batch to dedupe), so the
@@ -79,7 +48,7 @@ def timed_step_loop(
     # static under jit, so the timed rate still includes the full
     # per-dispatch pair-apply work.
     pair_feed = None
-    if getattr(config, "compact_alive", False):
+    if config.compact_alive:
         from kafka_topic_analyzer_tpu.packing import (
             pack_pair_table,
             pair_table_capacity,
@@ -89,10 +58,10 @@ def timed_step_loop(
         # ONE shared buffer: the step never donates it, and a mask-form
         # table can be tens of MB — duplicating it per feed entry would
         # just pin device memory for identical bytes.
-        pair_feed = jax.device_put(pack_pair_table([], config, cap)[0], dev)
+        pair_feed = jax.device_put(pack_pair_table([], config, cap)[0])
 
     def run(i, st):
-        buf = put(feed[i % len(feed)])
+        buf = feed[i % len(feed)]
         if pair_feed is not None:
             return step(st, buf, pair_feed)
         return step(st, buf)

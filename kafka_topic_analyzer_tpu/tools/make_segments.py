@@ -24,8 +24,8 @@ def spec_from_kv(text: "str | None") -> SyntheticSpec:
 class _Parser(argparse.ArgumentParser):
     """Points ``--partitions 4``-style mistakes at the ``--synthetic`` kv
     form (the workload shape is a spec string, not individual flags —
-    VERDICT r3 weak #6: the bare "unrecognized arguments" error cost a
-    first-time user real confusion)."""
+    the bare "unrecognized arguments" error cost a first-time user real
+    confusion)."""
 
     def error(self, message: str) -> "None":
         if "unrecognized arguments" in message:

@@ -15,12 +15,7 @@ import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-os.environ.setdefault("KTA_ACCEL_OK", "1")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-from kafka_topic_analyzer_tpu.jax_support import force_platform  # noqa: E402
-
-force_platform("cpu")
 
 import jax  # noqa: E402
 

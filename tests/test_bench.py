@@ -1,5 +1,5 @@
-"""bench.py output contract: honest degraded reporting (VERDICT r2 weak #5)
-and setup-phase error messages that name the offending key (weak #3)."""
+"""bench.py output contract: a result line that names its device, and
+setup-phase error messages that name the offending key."""
 
 import json
 import os
@@ -11,16 +11,10 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_degraded_bench_nulls_vs_baseline():
-    """A host-CPU fallback run must not print a headline vs_baseline ratio:
-    13.66x-on-a-CPU reads as the result at a glance.  The ratio moves to
-    vs_baseline_on_fallback_host; vs_baseline goes null."""
-    env = dict(os.environ)
-    env["KTA_BENCH_CHILD"] = "1"   # run main() directly, no supervisor
-    env["KTA_ACCEL_OK"] = "1"      # skip the probe; JAX_PLATFORMS=cpu is
-    env["JAX_PLATFORMS"] = "cpu"   # honored by the short-circuit fix
-    env.pop("KTA_JAX_PLATFORMS", None)  # an explicit override would read
-    #                                     as deliberate, not degraded
+def test_bench_result_names_its_device():
+    """The result line says what it was measured on, as JAX reports it:
+    a host-CPU run reads as platform "cpu", never as a chip number."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"),
          "--batch-size", "2048", "--batches", "2", "--steps", "4",
@@ -29,22 +23,19 @@ def test_degraded_bench_nulls_vs_baseline():
     )
     assert proc.returncode == 0, proc.stderr
     lines = [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert lines, proc.stdout
+    assert len(lines) == 1, proc.stdout
     doc = json.loads(lines[-1])
-    assert doc["degraded_cpu_fallback"] is True
-    assert doc["vs_baseline"] is None
-    assert doc["vs_baseline_on_fallback_host"] > 0
     assert doc["platform"] == "cpu"
+    assert doc["device_kind"] == "cpu"
+    assert doc["device_count"] >= 1
+    assert "transfer_gbps" not in doc
 
 
 def test_accuracy_seed_referee_matches_main_run_cardinality():
     """The per-seed sketch-error referee must run at the SAME dataset size
     as the main draw (HLL error depends on cardinality — r4 weak #5), and
     the recorded JSON must say what N each seed used."""
-    env = dict(os.environ)
-    env["KTA_BENCH_CHILD"] = "1"
-    env["KTA_ACCEL_OK"] = "1"
-    env["JAX_PLATFORMS"] = "cpu"
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py"),
          "--batch-size", "2048", "--batches", "6", "--steps", "6",

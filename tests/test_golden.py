@@ -1,4 +1,4 @@
-"""Golden-bytes conformance tier (VERDICT r2 next #4).
+"""Golden-bytes conformance tier.
 
 Every Kafka wire frame in this file is authored BYTE BY BYTE from the
 public protocol specification (kafka.apache.org/protocol + KIP-98 record

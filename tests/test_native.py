@@ -53,3 +53,25 @@ def test_native_hash_batch_matches_scalar():
     for i, s in enumerate(slices):
         assert int(h32[i]) == fnv1a32_ref(s)
         assert int(h64[i]) == fnv1a64(s)
+
+
+@pytest.mark.parametrize("change", ["source", "cpu"])
+def test_build_dir_is_keyed_on_source_and_cpu(tmp_path, monkeypatch, change):
+    """A changed ingest.cpp, or a CPU with another instruction set (the
+    build uses -march=native), selects a new in-tree build path: a binary
+    left on disk, or copied with the tree to another machine, is never
+    loaded for them."""
+    src = tmp_path / "ingest.cpp"
+    src.write_text("int kta_version() { return 14; }\n")
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_host_isa", lambda: "flags : sse4_2 avx2")
+    before = native._build_dir()
+    if change == "source":
+        src.write_text("int kta_version() { return 14; } // fixed\n")
+    else:
+        monkeypatch.setattr(native, "_host_isa", lambda: "flags : sse4_2")
+    after = native._build_dir()
+    assert before != after
+    for d in (before, after):
+        assert d.startswith(str(tmp_path / "build")), d
+        assert f"v{native._ABI}-" in d

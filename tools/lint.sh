@@ -9,8 +9,8 @@
 # CLI prints), and the kafka_topic_analyzer_tpu/tools/ bench/probe scripts
 # (standalone __main__ programs whose stdout IS their output format).
 #
-# AST-based, not grep: strings like the `python -c "print('ok', ...)"`
-# subprocess probe in jax_support.py must not trip it.
+# AST-based, not grep: a print inside a string (e.g. a `python -c`
+# snippet) must not trip it.
 cd "$(dirname "$0")/.." || exit 1
 python - <<'EOF'
 import ast
