@@ -129,23 +129,9 @@ def _timed(fn, hist):
 
 
 def instrument_steps(cls):
-    """Class decorator for concrete backends: record step-dispatch and
-    finalize latency into the obs histograms.  The engine's step entry
-    point is ``update_shards`` when the class defines one (the sharded
-    backend's ``update`` delegates to it — wrapping both would double
-    count), ``update`` otherwise.  Async backends therefore book dispatch
-    latency, not device time — the device side lives in the
-    ``--profile-dir`` XLA trace."""
-    step = "update_shards" if "update_shards" in cls.__dict__ else "update"
-    setattr(cls, step, _timed(
-        cls.__dict__[step], obs_metrics.BACKEND_STEP_SECONDS))
-    # Superbatch entry points are separate engine-facing steps (they do not
-    # delegate to update/update_shards), so they book their own dispatch
-    # latency — includes throttle blocking, i.e. real backpressure time.
-    for super_step in ("update_superbatch", "update_shards_superbatch"):
-        if super_step in cls.__dict__:
-            setattr(cls, super_step, _timed(
-                cls.__dict__[super_step], obs_metrics.BACKEND_STEP_SECONDS))
+    """Class decorator for concrete backends: record finalize latency into
+    the obs histogram.  Dispatch time is the engine's ``dispatch`` stage;
+    device time lives in the profiler trace (``--profile-dir``)."""
     setattr(cls, "finalize", _timed(
         cls.__dict__["finalize"], obs_metrics.BACKEND_FINALIZE_SECONDS))
     return cls

@@ -7,10 +7,17 @@ the config captured statically, so each feature combination compiles once.
 
 It replaces the reference's hot loop body (src/kafka.rs:98-133 fanning out to
 ``handle_message`` per message) with a handful of fused batched reductions.
+
+Each phase runs under a named scope (obs/trace.py::device_scope) —
+``kta.counters`` (counters and extremes), ``kta.alive`` (the alive
+bitmap), ``kta.hll``, ``kta.ddsketch``; the unpack is ``kta.unpack``
+(packing.py) — so the device ops in a profiler trace name the phase they
+belong to.  The scopes are metadata: they change no compiled instruction.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict
 
 from kafka_topic_analyzer_tpu.config import AnalyzerConfig
@@ -19,12 +26,14 @@ from kafka_topic_analyzer_tpu.models.message_metrics import MessageMetricsState
 from kafka_topic_analyzer_tpu.models.quantiles import DDSketchState
 from kafka_topic_analyzer_tpu.models.state import AnalyzerState
 from kafka_topic_analyzer_tpu.jax_support import jnp
+from kafka_topic_analyzer_tpu.obs import trace as obs_trace
 from kafka_topic_analyzer_tpu.ops.bitmap import bitmap_apply_pairs
 from kafka_topic_analyzer_tpu.ops.counters import counters_update, extremes_update
 from kafka_topic_analyzer_tpu.ops.ddsketch import ddsketch_update
 from kafka_topic_analyzer_tpu.ops.hll import hll_apply
 
 
+@obs_trace.device_scope("alive")
 def apply_pair_table(
     state: AnalyzerState,
     pairs,
@@ -128,6 +137,7 @@ def superbatch_fold(
     return state, n_valid
 
 
+@obs_trace.device_scope("alive")
 def _apply_alive(
     alive_state,
     arrays: Dict[str, "jnp.ndarray"],
@@ -197,38 +207,39 @@ def _analyzer_step_v5(
     makes the result byte-identical to the v4 fold; the superbatch scan
     and sharded chunk paths carry over untouched for the same reason."""
     m = state.metrics
-    delta = arrays["counts"]  # int64[P, 7], COUNTER_CHANNELS order
-    if config.use_pallas_counters:
-        from kafka_topic_analyzer_tpu.ops.pallas_counters import (
-            pallas_counters_merge,
-        )
+    with obs_trace.device_scope("counters"):
+        delta = arrays["counts"]  # int64[P, 7], COUNTER_CHANNELS order
+        if config.use_pallas_counters:
+            from kafka_topic_analyzer_tpu.ops.pallas_counters import (
+                pallas_counters_merge,
+            )
 
-        per_partition = pallas_counters_merge(
-            m.per_partition, delta, interpret=config.pallas_interpret
+            per_partition = pallas_counters_merge(
+                m.per_partition, delta, interpret=config.pallas_interpret
+            )
+        else:
+            per_partition = m.per_partition + delta
+        earliest, latest, smallest, largest = extremes_update(
+            m.earliest_s,
+            m.latest_s,
+            m.smallest,
+            m.largest,
+            arrays["ts_min"],
+            arrays["ts_max"],
+            arrays["sz_min"],
+            arrays["sz_max"],
         )
-    else:
-        per_partition = m.per_partition + delta
-    earliest, latest, smallest, largest = extremes_update(
-        m.earliest_s,
-        m.latest_s,
-        m.smallest,
-        m.largest,
-        arrays["ts_min"],
-        arrays["ts_max"],
-        arrays["sz_min"],
-        arrays["sz_max"],
-    )
-    metrics = MessageMetricsState(
-        per_partition=per_partition,
-        earliest_s=earliest,
-        latest_s=latest,
-        smallest=smallest,
-        largest=largest,
-        # Global sums are the column sums of the delta table: channels 5/6
-        # are the key/value byte sums, channel 0 the record count.
-        overall_size=m.overall_size + jnp.sum(delta[:, 5] + delta[:, 6]),
-        overall_count=m.overall_count + jnp.sum(delta[:, 0]),
-    )
+        metrics = MessageMetricsState(
+            per_partition=per_partition,
+            earliest_s=earliest,
+            latest_s=latest,
+            smallest=smallest,
+            largest=largest,
+            # Global sums are the column sums of the delta table: channels
+            # 5/6 are the key/value byte sums, channel 0 the record count.
+            overall_size=m.overall_size + jnp.sum(delta[:, 5] + delta[:, 6]),
+            overall_count=m.overall_count + jnp.sum(delta[:, 0]),
+        )
 
     alive_state = state.alive
     if alive_state is not None and "alive_slot" in arrays:
@@ -240,31 +251,34 @@ def _analyzer_step_v5(
 
     hll_state = state.hll
     if hll_state is not None:
-        if "hll_regs" in arrays:
-            regs = jnp.maximum(
-                hll_state.regs,
-                arrays["hll_regs"].astype(jnp.int32).reshape(
-                    -1, hll_state.regs.shape[1]
-                ),
-            )
-        elif "hll_idx32" in arrays:
-            # v5 flat pairs: the index already encodes (row << p | bucket),
-            # so the scatter-max lands on the flattened register file.
-            from kafka_topic_analyzer_tpu.ops.hll import hll_apply_flat
+        with obs_trace.device_scope("hll"):
+            if "hll_regs" in arrays:
+                regs = jnp.maximum(
+                    hll_state.regs,
+                    arrays["hll_regs"].astype(jnp.int32).reshape(
+                        -1, hll_state.regs.shape[1]
+                    ),
+                )
+            elif "hll_idx32" in arrays:
+                # v5 flat pairs: the index already encodes
+                # (row << p | bucket), so the scatter-max lands on the
+                # flattened register file.
+                from kafka_topic_analyzer_tpu.ops.hll import hll_apply_flat
 
-            regs = hll_apply_flat(
-                hll_state.regs, arrays["hll_idx32"], arrays["hll_rho"]
-            )
-        else:
-            regs = hll_apply(
-                hll_state.regs, arrays["hll_idx"], arrays["hll_rho"],
-                partition=None,
-            )
-        hll_state = HLLState(regs=regs)
+                regs = hll_apply_flat(
+                    hll_state.regs, arrays["hll_idx32"], arrays["hll_rho"]
+                )
+            else:
+                regs = hll_apply(
+                    hll_state.regs, arrays["hll_idx"], arrays["hll_rho"],
+                    partition=None,
+                )
+            hll_state = HLLState(regs=regs)
 
     q_state = state.quantiles
     if q_state is not None:
-        q_state = DDSketchState(counts=q_state.counts + arrays["qcounts"])
+        with obs_trace.device_scope("ddsketch"):
+            q_state = DDSketchState(counts=q_state.counts + arrays["qcounts"])
 
     return AnalyzerState(
         metrics=metrics, alive=alive_state, hll=hll_state, quantiles=q_state
@@ -304,51 +318,50 @@ def analyzer_step(
     value_len = arrays["value_len"]
 
     m = state.metrics
-    if config.use_pallas_counters:
-        import functools
+    with obs_trace.device_scope("counters"):
+        if config.use_pallas_counters:
+            from kafka_topic_analyzer_tpu.ops.pallas_counters import (
+                pallas_counters_update,
+            )
 
-        from kafka_topic_analyzer_tpu.ops.pallas_counters import (
-            pallas_counters_update,
+            counters_fn = functools.partial(
+                pallas_counters_update, interpret=config.pallas_interpret
+            )
+        else:
+            counters_fn = counters_update
+        per_partition = counters_fn(
+            m.per_partition,
+            arrays["partition"],
+            key_len,
+            value_len,
+            key_null,
+            value_null,
+            valid,
+            config.num_partitions,
         )
-
-        counters_fn = functools.partial(
-            pallas_counters_update, interpret=config.pallas_interpret
+        earliest, latest, smallest, largest = extremes_update(
+            m.earliest_s,
+            m.latest_s,
+            m.smallest,
+            m.largest,
+            arrays["ts_min"],
+            arrays["ts_max"],
+            arrays["sz_min"],
+            arrays["sz_max"],
         )
-    else:
-        counters_fn = counters_update
-    per_partition = counters_fn(
-        m.per_partition,
-        arrays["partition"],
-        key_len,
-        value_len,
-        key_null,
-        value_null,
-        valid,
-        config.num_partitions,
-    )
-    earliest, latest, smallest, largest = extremes_update(
-        m.earliest_s,
-        m.latest_s,
-        m.smallest,
-        m.largest,
-        arrays["ts_min"],
-        arrays["ts_max"],
-        arrays["sz_min"],
-        arrays["sz_max"],
-    )
-    kn = valid & ~key_null
-    vn = valid & ~value_null
-    k_bytes = jnp.where(kn, key_len, 0).astype(jnp.int64)
-    v_bytes = jnp.where(vn, value_len, 0).astype(jnp.int64)
-    metrics = MessageMetricsState(
-        per_partition=per_partition,
-        earliest_s=earliest,
-        latest_s=latest,
-        smallest=smallest,
-        largest=largest,
-        overall_size=m.overall_size + jnp.sum(k_bytes + v_bytes),
-        overall_count=m.overall_count + jnp.sum(valid.astype(jnp.int64)),
-    )
+        kn = valid & ~key_null
+        vn = valid & ~value_null
+        k_bytes = jnp.where(kn, key_len, 0).astype(jnp.int64)
+        v_bytes = jnp.where(vn, value_len, 0).astype(jnp.int64)
+        metrics = MessageMetricsState(
+            per_partition=per_partition,
+            earliest_s=earliest,
+            latest_s=latest,
+            smallest=smallest,
+            largest=largest,
+            overall_size=m.overall_size + jnp.sum(k_bytes + v_bytes),
+            overall_count=m.overall_count + jnp.sum(valid.astype(jnp.int64)),
+        )
 
     alive_state = state.alive
     if alive_state is not None and "alive_slot" in arrays:
@@ -360,43 +373,50 @@ def analyzer_step(
 
     hll_state = state.hll
     if hll_state is not None:
-        if "hll_regs" in arrays:
-            # Table mode (wire v3): the host already reduced the batch to
-            # a register table (R rows — 1 global or P per-partition) —
-            # merge elementwise, no scatter on the device hot path.
-            regs = jnp.maximum(
-                hll_state.regs,
-                arrays["hll_regs"].astype(jnp.int32).reshape(
-                    -1, hll_state.regs.shape[1]
-                ),
-            )
-        else:
-            regs = hll_apply(
-                hll_state.regs,
-                arrays["hll_idx"],
-                arrays["hll_rho"],
-                partition=(
-                    arrays["partition"]
-                    if config.distinct_keys_per_partition
-                    else None
-                ),
-            )
-        hll_state = HLLState(regs=regs)
+        with obs_trace.device_scope("hll"):
+            if "hll_regs" in arrays:
+                # Table mode (wire v3): the host already reduced the batch
+                # to a register table (R rows — 1 global or P
+                # per-partition) — merge elementwise, no scatter on the
+                # device hot path.
+                regs = jnp.maximum(
+                    hll_state.regs,
+                    arrays["hll_regs"].astype(jnp.int32).reshape(
+                        -1, hll_state.regs.shape[1]
+                    ),
+                )
+            else:
+                regs = hll_apply(
+                    hll_state.regs,
+                    arrays["hll_idx"],
+                    arrays["hll_rho"],
+                    partition=(
+                        arrays["partition"]
+                        if config.distinct_keys_per_partition
+                        else None
+                    ),
+                )
+            hll_state = HLLState(regs=regs)
 
     q_state = state.quantiles
     if q_state is not None:
-        msg_size = k_bytes + v_bytes
-        counts = ddsketch_update(
-            q_state.counts,
-            msg_size,
-            vn,  # quantiles over sized (non-tombstone) messages, like min/max
-            config.quantile_gamma,
-            config.quantile_buckets,
-            partition=(
-                arrays["partition"] if config.quantiles_per_partition else None
-            ),
-        )
-        q_state = DDSketchState(counts=counts)
+        with obs_trace.device_scope("ddsketch"):
+            msg_size = k_bytes + v_bytes
+            counts = ddsketch_update(
+                q_state.counts,
+                msg_size,
+                # quantiles over sized (non-tombstone) messages, like
+                # min/max
+                vn,
+                config.quantile_gamma,
+                config.quantile_buckets,
+                partition=(
+                    arrays["partition"]
+                    if config.quantiles_per_partition
+                    else None
+                ),
+            )
+            q_state = DDSketchState(counts=counts)
 
     return AnalyzerState(
         metrics=metrics, alive=alive_state, hll=hll_state, quantiles=q_state

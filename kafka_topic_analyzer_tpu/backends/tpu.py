@@ -44,6 +44,7 @@ from kafka_topic_analyzer_tpu.config import AnalyzerConfig, DispatchConfig
 from kafka_topic_analyzer_tpu.jax_support import require_device_platform
 from kafka_topic_analyzer_tpu.models.state import AnalyzerState
 from kafka_topic_analyzer_tpu.obs import metrics as obs_metrics
+from kafka_topic_analyzer_tpu.obs import trace as obs_trace
 from kafka_topic_analyzer_tpu.packing import (
     SuperbatchStager,
     batch_alive_pairs,
@@ -439,5 +440,13 @@ class TpuBackend(MetricBackend):
         # Retire every in-flight dispatch first so the latency histogram
         # is complete (device_get below syncs anyway).
         self.drain_dispatch()
-        host_state = jax.tree.map(np.asarray, jax.device_get(self.state))
-        return metrics_from_state(host_state, self.config, self.init_now_s)
+        # The copy waits for the last step, then moves the whole state.
+        with obs_trace.maybe_span("finalize.device_get", cat="finalize"):
+            host_state = jax.tree.map(np.asarray, jax.device_get(self.state))
+        obs_metrics.FINALIZE_D2H_BYTES.inc(
+            sum(leaf.nbytes for leaf in jax.tree.leaves(host_state))
+        )
+        with obs_trace.maybe_span("finalize.host", cat="finalize"):
+            return metrics_from_state(
+                host_state, self.config, self.init_now_s
+            )

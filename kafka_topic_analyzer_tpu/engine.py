@@ -12,6 +12,7 @@ backend and maps them back in the result.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Optional
 
@@ -181,6 +182,20 @@ def _note_fetch_streams(source, workers: int) -> None:
         fetchsched.note_streams(workers)
 
 
+def _in_scan_span(fn):
+    """Run ``fn`` (run_scan) inside its ``scan`` span: the scan's id,
+    which every span of the scan carries (obs/trace.py)."""
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        tracer = kwargs.get("tracer") or obs_trace.active()
+        with obs_trace.scan_span(tracer):
+            return fn(*args, **kwargs)
+
+    return wrapped
+
+
+@_in_scan_span
 def run_scan(
     topic: str,
     source: RecordSource,
@@ -940,7 +955,11 @@ def run_scan(
                     tracker.observe_packed(batch)
                     if staged is None:
                         staged = batch.staged
+                    # The dispatch span's batch= links it to the row's
+                    # decode-and-pack and stage spans (obs/trace.py).
+                    span_args = {"batch": batch.seq}
                 else:
+                    span_args = {}
                     last = len(batch) - 1
                     last_partition = int(batch.partition[last])  # true id, pre-remap
                     last_offset = (
@@ -959,6 +978,7 @@ def run_scan(
                     # change it) so the stat stays comparable across backends.
                     with profile.stage(
                         "dispatch", items=nvalid, nbytes=batch.nbytes,
+                        **span_args,
                     ):
                         backend.update(staged)
                     seq += nvalid

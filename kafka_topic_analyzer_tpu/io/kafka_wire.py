@@ -312,9 +312,17 @@ class BrokerConnection:
             r.skip_tags()
 
     def read_response(self, corr: int) -> kc.ByteReader:
-        """Pipelining half 2: read the next response; must match ``corr``."""
+        """Pipelining half 2: read the next response; must match ``corr``.
+
+        The wait for its first bytes (the 4-byte length) is the time the
+        broker takes to answer, spanned and booked apart from the
+        transfer of the rest."""
         with self._lock:
-            (length,) = struct.unpack(">i", self._recv_exact(4))
+            t0 = _perf_counter()
+            with obs_trace.maybe_span("fetch.first_byte", cat="io"):
+                head = self._recv_exact(4)
+            obs_metrics.FETCH_FIRST_BYTE_SECONDS.inc(_perf_counter() - t0)
+            (length,) = struct.unpack(">i", head)
             payload = self._recv_exact(length)
             meta = self._inflight.pop(corr, None)
         r = kc.ByteReader(payload)
@@ -1909,12 +1917,16 @@ class KafkaWireSource(RecordSource):
                 )
                 pools.append(pool)
             if pool is not None and len(by_leader) > 1:
-                results = list(
-                    pool.map(
-                        lambda kv: fetch_leader_guarded(kv[0], kv[1], fetch_round),
-                        by_leader.items(),
+                # One context copy per task: the fetch spans carry the
+                # scan's id (obs/trace.py).
+                futures = [
+                    pool.submit(
+                        obs_trace.in_context(fetch_leader_guarded),
+                        leader, lparts, fetch_round,
                     )
-                )
+                    for leader, lparts in by_leader.items()
+                ]
+                results = [f.result() for f in futures]
             else:
                 results = [
                     fetch_leader_guarded(leader, lparts, fetch_round)
@@ -2091,10 +2103,15 @@ class KafkaWireSource(RecordSource):
                         # malformed) takes the per-frame chain below,
                         # entering the same rows via push_chunk.
                         _t_dec = _perf_counter()
-                        n_acc, used, covered, last = sink.append_record_set(
-                            data, next_offset[p], end[p], p,
-                            self.verify_crc, prescan=scans.get(p),
-                        )
+                        with obs_trace.maybe_span(
+                            "decode_pack", cat="io", batch=sink.batch
+                        ):
+                            n_acc, used, covered, last = (
+                                sink.append_record_set(
+                                    data, next_offset[p], end[p], p,
+                                    self.verify_crc, prescan=scans.get(p),
+                                )
+                            )
                         # Fused streams skip the phase-1 pre-decode; their
                         # decode IS this pack, booked on the same counter.
                         obs_metrics.DECODE_SECONDS.inc(
@@ -2121,9 +2138,11 @@ class KafkaWireSource(RecordSource):
                             # pre-decode pass — the doctor's decode
                             # evidence must see this path too.
                             _t_dec = _perf_counter()
-                            soa, used, covered = decode_record_set_native(
-                                data, self.verify_crc, prescan=scans.get(p)
-                            )
+                            with obs_trace.maybe_span("decode", cat="io"):
+                                soa, used, covered = decode_record_set_native(
+                                    data, self.verify_crc,
+                                    prescan=scans.get(p),
+                                )
                             obs_metrics.DECODE_SECONDS.inc(
                                 _perf_counter() - _t_dec
                             )

@@ -16,6 +16,8 @@ import os
 
 import jax
 
+from kafka_topic_analyzer_tpu.obs import metrics as obs_metrics
+
 jax.config.update("jax_enable_x64", True)
 
 #: The persistent compilation cache used when ``JAX_COMPILATION_CACHE_DIR``
@@ -28,6 +30,8 @@ CHECKOUT_CACHE_DIR = os.path.join(
 
 if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
     jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+
+obs_metrics.install_program_build_listener()
 
 
 def require_device_platform(device) -> None:

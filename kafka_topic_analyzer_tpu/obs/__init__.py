@@ -9,9 +9,10 @@ Dependency-free observability for the scan pipeline:
   (``--metrics-port``);
 - ``obs.events``    — structured JSONL event log (``--events-jsonl``) and
   the rate-limited heartbeat;
-- ``obs.trace``     — host-side span tracer exporting Chrome trace-event
-  JSON (``--trace-json``), complementary to the ``--profile-dir`` XLA
-  trace.
+- ``obs.trace``     — host-side spans as ``kta.*`` JAX profiler
+  annotations (on the device's clock in a ``--profile-dir`` trace), also
+  exported as Chrome trace-event JSON (``--trace-json``), and the step's
+  ``kta.*`` device scopes.
 
 ``telemetry_session`` is the CLI's one-stop wiring: it attaches exactly
 the sinks the flags ask for, yields the tracer for ``run_scan``, and

@@ -95,10 +95,8 @@ def _default_tracks() -> "List[Tuple[str, str, Callable[[], float]]]":
         ("decode_s", "cum", lambda: m.DECODE_SECONDS.value),
         ("fetch_rounds", "cum", lambda: m.FETCH_REQUESTS.value),
         ("fetch_bytes", "cum", lambda: m.FETCH_BYTES.value),
-        # Device step/retire latency totals (histogram sums are cumulative
-        # seconds — delta/window = device-side busy fraction as seen from
-        # the dispatching thread).
-        ("step_s", "cum", lambda: _hist_sum(m.BACKEND_STEP_SECONDS)),
+        # Superbatch launch-to-retire latency total (a histogram sum:
+        # cumulative seconds, device queue time included).
         ("retire_s", "cum", lambda: _hist_sum(m.DISPATCH_SECONDS)),
         # Scan progress, so windows carry a records-rate alongside.
         ("records", "cum", lambda: m.SCAN_RECORDS.value),

@@ -12,6 +12,8 @@ for durations, base units only.
 
 from __future__ import annotations
 
+import threading
+
 from kafka_topic_analyzer_tpu.obs.registry import (
     BATCH_SIZE_BUCKETS,
     LATENCY_BUCKETS_S,
@@ -297,6 +299,12 @@ FETCH_SECONDS = _REG.counter(
     "Seconds spent blocked reading fetch responses off broker sockets "
     "(the wire scan's source-wait side — booked per fetch round, on the "
     "fetching thread, mirroring the 'fetch' trace span)")
+FETCH_FIRST_BYTE_SECONDS = _REG.counter(
+    "kta_fetch_first_byte_seconds_total",
+    "Seconds spent waiting for the first bytes of a broker response (its "
+    "4-byte length), the time the broker takes to answer; the rest of "
+    "kta_fetch_seconds_total is transfer.  Booked in the window of the "
+    "'fetch.first_byte' span")
 DECODE_SECONDS = _REG.counter(
     "kta_decode_seconds_total",
     "Seconds spent in record-set decode: the native whole-response "
@@ -417,14 +425,20 @@ DISPATCH_SECONDS = _REG.histogram(
 
 # -- backends -----------------------------------------------------------------
 
-BACKEND_STEP_SECONDS = _REG.histogram(
-    "kta_backend_step_seconds",
-    "Backend update dispatch latency (async backends: dispatch only)",
-    buckets=LATENCY_BUCKETS_S)
 BACKEND_FINALIZE_SECONDS = _REG.histogram(
     "kta_backend_finalize_seconds",
     "Backend finalize (device sync + collective merge) latency",
     buckets=LATENCY_BUCKETS_S)
+FINALIZE_D2H_BYTES = _REG.counter(
+    "kta_finalize_d2h_bytes_total",
+    "Bytes of analyzer state copied device to host at finalize (the "
+    "'finalize.device_get' span's transfer)")
+PROGRAM_BUILD_SECONDS = _REG.counter(
+    "kta_program_build_seconds_total",
+    "Seconds JAX spent building device programs in this process: tracing "
+    "to a jaxpr, lowering to MLIR, and compiling or loading the result "
+    "from the compilation cache (jax.monitoring duration events; "
+    "install_program_build_listener)")
 
 # -- follow-mode service (serve/follow.py + io/kafka_wire.py) -----------------
 
@@ -624,3 +638,35 @@ ALERTS_TRANSITIONS = _REG.counter(
     "one row, so the alert trace is reconstructible from the counter "
     "alone (tools/lint.sh rule 12); no silent state changes",
     labelnames=("rule", "state"))
+
+# -- program builds (jax.monitoring) ------------------------------------------
+
+#: The jax.monitoring duration events that make up one program build.
+PROGRAM_BUILD_EVENTS = frozenset((
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+))
+_build_listener_lock = threading.Lock()
+_build_listener_installed = False
+
+
+def _on_duration_event(event: str, duration_secs: float, **_) -> None:
+    if event in PROGRAM_BUILD_EVENTS:
+        PROGRAM_BUILD_SECONDS.inc(duration_secs)
+
+
+def install_program_build_listener() -> None:
+    """Book ``kta_program_build_seconds_total`` from JAX's own compile
+    events.  Once per process, however often it is called; the caller has
+    imported JAX (jax_support does, at its import)."""
+    global _build_listener_installed
+    with _build_listener_lock:
+        if _build_listener_installed:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_duration_event
+        )
+        _build_listener_installed = True

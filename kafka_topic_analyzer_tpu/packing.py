@@ -80,6 +80,7 @@ from typing import Dict, Tuple
 import numpy as np
 
 from kafka_topic_analyzer_tpu.config import AnalyzerConfig
+from kafka_topic_analyzer_tpu.obs import trace as obs_trace
 from kafka_topic_analyzer_tpu.records import RecordBatch
 
 HEADER_BYTES = 16
@@ -581,6 +582,7 @@ def unpack_pair_table_numpy(
     return out
 
 
+@obs_trace.device_scope("unpack")
 def unpack_pair_table_device(buf, config: AnalyzerConfig, capacity: int):
     """uint8[pair_table_nbytes] → typed device arrays (runs under jit) —
     the pair-table twin of `unpack_device`, same bitcast rules."""
@@ -914,17 +916,21 @@ class PackedRow:
     throughput stats.  ``staged`` carries the backend-staged form
     (StagedBatch / PackedShard) when the sink was given a stage callback —
     it runs on the producing (worker) thread, exactly like
-    ``backend.prepare`` does on the chained path."""
+    ``backend.prepare`` does on the chained path.  ``seq`` is the row's
+    batch sequence number (obs/trace.py::next_batch), the ``batch=`` of
+    the spans that decoded, staged and dispatched it."""
 
     __slots__ = (
         "buf", "staged", "n_valid", "next_offsets", "counts",
-        "last_partition", "last_offset", "last_ts_s", "pairs",
+        "last_partition", "last_offset", "last_ts_s", "pairs", "seq",
     )
 
     def __init__(self, buf, staged, n_valid, next_offsets, counts,
-                 last_partition, last_offset, last_ts_s, pairs=None):
+                 last_partition, last_offset, last_ts_s, pairs=None,
+                 seq=None):
         self.buf = buf
         self.staged = staged
+        self.seq = seq
         #: Compacted-path alive pairs of THIS row — (slot u32[n], flag
         #: u8[n]) host arrays in row stream order, None when the config
         #: ships per-row pair sections instead (the staged form carries
@@ -1011,6 +1017,9 @@ class FusedPackSink:
         #: re-init and accumulate here until the row completes.
         self._compact = getattr(pack_config, "compact_alive", False)
         self._row_pairs: "list[tuple[np.ndarray, np.ndarray]]" = []
+        #: Batch sequence number of the row being filled, or of the next
+        #: row where none is open (obs/trace.py::next_batch).
+        self.batch = obs_trace.next_batch()
 
     # -- row lifecycle -------------------------------------------------------
 
@@ -1082,15 +1091,19 @@ class FusedPackSink:
         obs_metrics.FUSED_BATCHES.inc()
         obs_metrics.FUSED_RECORDS.inc(self._count)
         pairs = self._take_row_pairs()
+        seq, self.batch = self.batch, obs_trace.next_batch()
         if self._stage is None:
             staged = None
-        elif self._compact:
-            # Compacted path: the stage callback carries the row's pairs
-            # into the staged form so the backend's dispatch merge sees
-            # them without re-reading the (sectionless) row.
-            staged = self._stage(row, pairs)
         else:
-            staged = self._stage(row)
+            with obs_trace.maybe_span("stage", cat="io", batch=seq):
+                if self._compact:
+                    # Compacted path: the stage callback carries the row's
+                    # pairs into the staged form so the backend's dispatch
+                    # merge sees them without re-reading the
+                    # (sectionless) row.
+                    staged = self._stage(row, pairs)
+                else:
+                    staged = self._stage(row)
         self._done.append(
             PackedRow(
                 row,
@@ -1100,6 +1113,7 @@ class FusedPackSink:
                 self._counts,
                 *self._last,
                 pairs=pairs,
+                seq=seq,
             )
         )
 
@@ -1412,6 +1426,7 @@ def unpack_numpy(buf: np.ndarray, config: AnalyzerConfig) -> Dict[str, np.ndarra
 # elementwise.
 
 
+@obs_trace.device_scope("unpack")
 def unpack_device(buf, config: AnalyzerConfig):
     """uint8[N] → dict of typed device arrays (runs under jit)."""
     from kafka_topic_analyzer_tpu.jax_support import jnp, lax

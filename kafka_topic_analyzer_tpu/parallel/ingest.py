@@ -60,6 +60,7 @@ from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 from kafka_topic_analyzer_tpu.io.source import RecordSource
 from kafka_topic_analyzer_tpu.obs import metrics as obs_metrics
+from kafka_topic_analyzer_tpu.obs import trace as obs_trace
 from kafka_topic_analyzer_tpu.packing import PackedRow
 from kafka_topic_analyzer_tpu.records import RecordBatch
 
@@ -198,6 +199,9 @@ class _IngestWorker(threading.Thread):
         self._active = obs_metrics.INGEST_WORKER_ACTIVE_SECONDS.labels(
             worker=wid
         )
+        # The stream runs in a copy of the creating thread's context, so
+        # its spans carry that thread's scan id (obs/trace.py).
+        self._drain_in_context = obs_trace.in_context(self._drain)
 
     def _put(self, item: object) -> bool:
         """Bounded put; gives up when the consumer cancelled.  Time spent
@@ -228,6 +232,9 @@ class _IngestWorker(threading.Thread):
             self._stall.inc(time.perf_counter() - t0)
 
     def run(self) -> None:
+        self._drain_in_context()
+
+    def _drain(self) -> None:
         # Lifetime booking brackets the whole stream: busy fraction =
         # (active - stall) / active stays honest for workers whose
         # partitions drain early (obs/doctor.py reads both counters).

@@ -14,6 +14,8 @@ import queue
 import threading
 from typing import Iterator, TypeVar
 
+from kafka_topic_analyzer_tpu.obs import trace as obs_trace
+
 T = TypeVar("T")
 
 _SENTINEL = object()
@@ -40,7 +42,11 @@ class PrefetchIterator:
         self._it = it
         self._cancel = threading.Event()
         self._source_closed = False
-        self._thread = threading.Thread(target=self._fill, daemon=True)
+        # The worker runs in a copy of the caller's context, so the spans
+        # of the iterator it drains carry the caller's scan id.
+        self._thread = threading.Thread(
+            target=obs_trace.in_context(self._fill), daemon=True
+        )
         self._thread.start()
 
     def _put(self, item: object) -> bool:

@@ -6,10 +6,11 @@ metric here, the engine keeps per-stage (ingest / dispatch / finalize)
 wall-time and record counters, and can wrap the scan in a JAX profiler trace
 (``--profile-dir``) for XLA-level analysis on TPU.
 
-With a span tracer attached (``--trace-json``, obs/trace.py) every stage
-window is also mirrored into the Chrome trace with the *same* measured
-duration, so the host trace's per-stage totals agree with ``--stats``
-exactly.
+Every stage window is a ``kta.<stage>`` profiler annotation
+(obs/trace.py), so ``--profile-dir`` shows the stages on the device's
+clock.  With a span tracer attached (``--trace-json``) each window is also
+mirrored into the Chrome trace with the *same* measured duration, so the
+host trace's per-stage totals agree with ``--stats`` exactly.
 """
 
 from __future__ import annotations
@@ -20,6 +21,7 @@ import time
 from typing import Dict, Iterator, Optional
 
 from kafka_topic_analyzer_tpu.obs import metrics as obs_metrics
+from kafka_topic_analyzer_tpu.obs import trace as obs_trace
 
 #: Canonical drive-loop stage order: pipeline position, not insertion
 #: order (insertion order varies with which stage fires first — e.g. a
@@ -56,30 +58,36 @@ class ScanProfile:
         self.tracer = tracer
 
     @contextlib.contextmanager
-    def stage(self, name: str, items: int = 0, nbytes: int = 0) -> Iterator[None]:
+    def stage(
+        self, name: str, items: int = 0, nbytes: int = 0, **args
+    ) -> Iterator[None]:
+        """One window of stage ``name``; ``args`` (a batch's ``batch=``)
+        label its profiler annotation."""
         st = self.stages.setdefault(name, StageStats())
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            st.seconds += dt
-            st.items += items
-            st.bytes += nbytes
-            if self.tracer is not None:
-                # Same t0/dt as the stat above: the trace and --stats can
-                # never drift apart.
-                self.tracer.add_complete(name, t0, dt, cat="stage")
-            # Book the SAME measurement into the live stage counters at
-            # every window exit (not once at scan end): the flight
-            # recorder samples these mid-scan for per-stage occupancy,
-            # and the --stats stage digest renders from the registry
-            # snapshot — one measurement, every surface (DESIGN.md §17).
-            obs_metrics.STAGE_SECONDS.labels(stage=name).inc(dt)
-            if items:
-                obs_metrics.STAGE_RECORDS.labels(stage=name).inc(items)
-            if nbytes:
-                obs_metrics.STAGE_BYTES.labels(stage=name).inc(nbytes)
+        with obs_trace.annotate(name, **args):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                dt = time.perf_counter() - t0
+                st.seconds += dt
+                st.items += items
+                st.bytes += nbytes
+                if self.tracer is not None:
+                    # Same t0/dt as the stat above: the trace and --stats
+                    # can never drift apart.
+                    self.tracer.add_complete(name, t0, dt, cat="stage")
+                # Book the SAME measurement into the live stage counters
+                # at every window exit (not once at scan end): the flight
+                # recorder samples these mid-scan for per-stage occupancy,
+                # and the --stats stage digest renders from the registry
+                # snapshot — one measurement, every surface (DESIGN.md
+                # §17).
+                obs_metrics.STAGE_SECONDS.labels(stage=name).inc(dt)
+                if items:
+                    obs_metrics.STAGE_RECORDS.labels(stage=name).inc(items)
+                if nbytes:
+                    obs_metrics.STAGE_BYTES.labels(stage=name).inc(nbytes)
 
     @property
     def wall_seconds(self) -> float:

@@ -100,11 +100,13 @@ def test_engine_counters_match_scan_result():
     # The forced closing heartbeat reports drained partitions: zero lag.
     for s in obs_metrics.PARTITION_LAG.samples():
         assert s["value"] == 0.0
-    # Step/finalize latency histograms saw every dispatch.
+    # The dispatch stage booked every batch, and the finalize histogram
+    # the one finalize.
     assert (
-        obs_metrics.BACKEND_STEP_SECONDS.samples()[0]["count"]
-        == obs_metrics.SCAN_BATCHES.value
+        obs_metrics.STAGE_RECORDS.labels(stage="dispatch").value
+        == result.metrics.overall_count
     )
+    assert obs_metrics.STAGE_SECONDS.labels(stage="dispatch").value > 0
     assert obs_metrics.BACKEND_FINALIZE_SECONDS.samples()[0]["count"] == 1
 
 
