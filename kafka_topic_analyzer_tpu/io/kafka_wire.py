@@ -54,7 +54,10 @@ import dataclasses
 import logging
 import socket
 import struct
+import sys
 import threading
+from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import wait as wait_futures
 from time import perf_counter as _perf_counter
 from typing import Dict, Iterator, List, Optional, Tuple
 
@@ -91,6 +94,10 @@ MAX_PARTITION_FETCH_BYTES = 1 << 30
 #: forever; a matching kind — the deterministic-on-disk case — settles
 #: after a single re-fetch regardless).
 _MAX_SUSPECT_ROUNDS = 4
+
+#: Room above fetch.max.bytes in a receive buffer: the response's own
+#: headers, so a full response still fits the ring's buffers.
+_RESPONSE_SLACK = 1 << 20
 
 #: librdkafka property names that are VALID for the reference's consumer
 #: (src/kafka.rs:24-44 sets several of them) but have no observable effect
@@ -150,6 +157,60 @@ def _hash_keys(
     return fnv1a32_ref_batch(padded, lengths), fnv1a64_batch(padded, lengths)
 
 
+class RecvBufferRing:
+    """Receive buffers for one connection's response bodies, reused once
+    nothing outside the ring references them.
+
+    Fetch responses run to tens of MB; a fresh buffer for each pays an
+    mmap, a page fault and zero-fill on every page, and an munmap.  Two
+    buffers suffice for a stream that receives the next response while
+    the current one decodes.  A buffer is taken again only when
+    ``sys.getrefcount`` shows the ring's own reference alone: every
+    memoryview slice and ``np.frombuffer`` array of a response keeps its
+    buffer referenced, so bytes someone still holds are never
+    overwritten — such a holder just costs a fresh buffer.  Booked on
+    ``kta_fetch_buffers_total{outcome}``."""
+
+    def __init__(self, slots: int = 2, min_bytes: int = 0):
+        self._slots = slots
+        #: Size of a new buffer at least (a response seldom exceeds the
+        #: request's fetch.max.bytes), so growth stays rare.
+        self._min_bytes = min_bytes
+        self._replace = 0  # the slot a ring of held buffers replaces next
+        self._lock = threading.Lock()
+        self._bufs: "List[np.ndarray]" = [np.empty(0, dtype=np.uint8)]
+        #: What _refs reads for a buffer that only the ring references.
+        self._own_refs = self._refs(0)
+        self._bufs = []
+
+    def _refs(self, i: int) -> int:
+        return sys.getrefcount(self._bufs[i])
+
+    def _held(self, i: int) -> bool:
+        return self._refs(i) > self._own_refs
+
+    def take(self, n: int) -> "memoryview":
+        """An ``n``-byte writable view of a buffer no one else holds."""
+        with self._lock:
+            free = [i for i in range(len(self._bufs)) if not self._held(i)]
+            fit = [i for i in free if self._bufs[i].size >= n]
+            if fit:
+                buf = self._bufs[fit[0]]
+                outcome = "reused"
+            else:
+                buf = np.empty(max(n, self._min_bytes), dtype=np.uint8)
+                outcome = "fresh"
+                if free:  # grow a free buffer too small for this response
+                    self._bufs[free[0]] = buf
+                elif len(self._bufs) < self._slots:
+                    self._bufs.append(buf)
+                else:  # every buffer held: its holder keeps the old one
+                    self._bufs[self._replace] = buf
+                    self._replace = (self._replace + 1) % self._slots
+        obs_metrics.FETCH_BUFFERS.labels(outcome=outcome).inc()
+        return memoryview(buf).cast("B")[:n]
+
+
 class BrokerConnection:
     """One blocking TCP (optionally TLS) connection to a broker.
 
@@ -207,6 +268,9 @@ class BrokerConnection:
         self._corr = 0
         self._inflight: "Dict[int, Tuple[int, int]]" = {}
         self._lock = threading.Lock()
+        #: Where response bodies land; None = a fresh buffer per response.
+        #: A wire stream gives its own connections a ring.
+        self.ring: Optional[RecvBufferRing] = None
         #: ApiVersions handshake result, filled lazily ({} = legacy broker).
         self.api_versions: "Optional[Dict[int, tuple[int, int]]]" = None
         if sasl is not None:
@@ -262,17 +326,20 @@ class BrokerConnection:
         except OSError:
             pass
 
-    def _recv_exact(self, n: int) -> "memoryview":
+    def _recv_exact(
+        self, n: int, ring: "Optional[RecvBufferRing]" = None
+    ) -> "memoryview":
         # recv_into one preallocated buffer: fetch responses run to tens
         # of MB, so chunk-list assembly (or a final bytes() copy) would
         # duplicate every byte.  numpy's allocator skips the zero-fill a
-        # bytearray(n) would pay (a full extra memset pass at 64 MB).
-        # ByteReader and the frame decoders slice/unpack memoryviews;
-        # string fields go through bytes() at the decode site.
-        import numpy as _np
-
-        buf = _np.empty(n, dtype=_np.uint8)
-        view = memoryview(buf).cast("B")
+        # bytearray(n) would pay (a full extra memset pass at 64 MB), and
+        # a ring's buffer skips the allocation too.  ByteReader and the
+        # frame decoders slice/unpack memoryviews; string fields go
+        # through bytes() at the decode site.
+        if ring is not None:
+            view = ring.take(n)
+        else:
+            view = memoryview(np.empty(n, dtype=np.uint8)).cast("B")
         got = 0
         while got < n:
             r = self.sock.recv_into(view[got:])
@@ -323,7 +390,7 @@ class BrokerConnection:
                 head = self._recv_exact(4)
             obs_metrics.FETCH_FIRST_BYTE_SECONDS.inc(_perf_counter() - t0)
             (length,) = struct.unpack(">i", head)
-            payload = self._recv_exact(length)
+            payload = self._recv_exact(length, self.ring)
             meta = self._inflight.pop(corr, None)
         r = kc.ByteReader(payload)
         got_corr = r.i32()
@@ -343,7 +410,7 @@ class BrokerConnection:
                 kc.encode_request(api_key, api_version, corr, CLIENT_ID, body)
             )
             (length,) = struct.unpack(">i", self._recv_exact(4))
-            payload = self._recv_exact(length)
+            payload = self._recv_exact(length, self.ring)
         r = kc.ByteReader(payload)
         got_corr = r.i32()
         if got_corr != corr:
@@ -1668,15 +1735,19 @@ class KafkaWireSource(RecordSource):
                     f"leader {leader} missing from cluster metadata"
                 )
             host, port = addr
+            moved = None
             with conn_lock:
                 c = own_conns.get(leader)
                 if c is not None and (c.host, c.port) != (host, port):
                     # Leader moved (metadata reload): reconnect.
-                    c.close()
-                    own_conns.pop(leader, None)
-                    c = None
-                if c is not None:
+                    moved = own_conns.pop(leader)
+                elif c is not None:
                     return c
+            if moved is not None:
+                fl = inflight.get(leader)
+                if fl is not None and fl[0] is moved:
+                    wait_futures([fl[5]])  # no close under its receiver
+                moved.close()
             # Connect OUTSIDE the lock: TCP+TLS+SASL setup can block up to
             # the socket timeout, and one slow broker must not serialize
             # every other leader thread's first round.
@@ -1688,6 +1759,9 @@ class KafkaWireSource(RecordSource):
                 sasl=self._sasl,
                 sock_opts=self._sock_opts,
             )
+            c.ring = RecvBufferRing(
+                min_bytes=self.max_bytes + _RESPONSE_SLACK
+            )
             with conn_lock:
                 winner = own_conns.get(leader)
                 if winner is not None:  # lost a (same-leader) race
@@ -1695,6 +1769,40 @@ class KafkaWireSource(RecordSource):
                     return winner
                 own_conns[leader] = c
             return c
+
+        receivers: "Dict[int, ThreadPoolExecutor]" = {}
+
+        def receiver_of(leader: int) -> "ThreadPoolExecutor":
+            # One receiver thread per leader this stream reads from: a
+            # single one would serialize the transfers that leaders'
+            # fetch threads otherwise run side by side.  Joined by the
+            # stream's finally (``pools``) before its sockets close.
+            with conn_lock:
+                ex = receivers.get(leader)
+                if ex is None:
+                    ex = receivers[leader] = ThreadPoolExecutor(
+                        max_workers=1, thread_name_prefix="kta-recv"
+                    )
+                    pools.append(ex)
+                return ex
+
+        def receive(conn: BrokerConnection, corr: int) -> kc.ByteReader:
+            with obs_trace.maybe_span("fetch.recv", cat="io"):
+                return conn.read_response(corr)
+
+        def drain(leader: int, fl: tuple) -> bool:
+            """Wait out a discarded speculative fetch's response; False
+            when its receive failed, which evicts the connection."""
+            obs_metrics.FETCH_AHEAD.labels(outcome="discarded").inc()
+            try:
+                fl[5].result()
+                return True
+            except Exception:
+                fl[0].close()
+                with conn_lock:
+                    if own_conns.get(leader) is fl[0]:
+                        own_conns.pop(leader, None)
+                return False
 
         def fetch_leader(leader: int, lparts: List[int], fetch_round: int):
             """Phase 1 of a round, one leader: (re)send, read, decode —
@@ -1711,21 +1819,15 @@ class KafkaWireSource(RecordSource):
             k = fetch_round % len(lp)
             order = lp[k:] + lp[:k]
             # Pipelining: if last round sent ahead for this leader, its
-            # response is already in flight.  A stale in-flight
-            # (connection changed, or it no longer covers this round's
-            # partitions) is drained and discarded — the stream stays
-            # ordered either way.
+            # response is already arriving on the receiver.  A stale
+            # in-flight (connection changed, or it no longer covers this
+            # round's partitions) is drained and discarded — the stream
+            # stays ordered either way.
             fl = inflight.pop(leader, None)
             if fl is not None and (
                 fl[0] is not conn or not set(lp) <= set(fl[3])
             ):
-                try:
-                    fl[0].read_response(fl[1])
-                except Exception:
-                    fl[0].close()
-                    with conn_lock:
-                        if own_conns.get(leader) is fl[0]:
-                            own_conns.pop(leader, None)
+                if not drain(leader, fl):
                     conn = own_conn(leader)
                 fl = None
             if fl is None:
@@ -1753,23 +1855,29 @@ class KafkaWireSource(RecordSource):
                     {p: next_offset[p] for p in order},
                     order,
                     pmax_sent,
+                    None,
                 )
-            conn, corr, sent_offsets, order, pmax_sent = fl
+            conn, corr, sent_offsets, order, pmax_sent, recv = fl
             _t_fetch = _perf_counter()
             with obs_trace.maybe_span("fetch", cat="io"):
-                r = conn.read_response(corr)
+                r = conn.read_response(corr) if recv is None else recv.result()
             # Same window as the span, booked per fetch round — the
-            # flight recorder's source-wait track (obs/doctor.py).
+            # flight recorder's source-wait track (obs/doctor.py): the
+            # time this stream blocked, wherever the response was read.
             obs_metrics.FETCH_SECONDS.inc(_perf_counter() - _t_fetch)
+            obs_metrics.FETCH_AHEAD.labels(
+                outcome="inline" if recv is None else "ahead"
+            ).inc()
             fps = kc.decode_fetch_response(r, self._version(conn, kc.API_FETCH))
             obs_metrics.FETCH_REQUESTS.inc()
             obs_metrics.FETCH_BYTES.inc(
                 sum(len(fp.records) for fp in fps)
             )
             # Send-ahead: while this response's records decode, let the
-            # broker build the NEXT one.  A cheap native header scan of
-            # each partition's record set yields the exact offsets
-            # processing will arrive at (covered_end, compaction-aware);
+            # broker build the NEXT one and the receiver take it off the
+            # socket.  A cheap native header scan of each partition's
+            # record set yields the exact offsets processing will arrive
+            # at (covered_end, compaction-aware; an empty set stays put);
             # only clean all-native responses qualify, and a
             # post-processing mismatch discards the speculative response
             # (correctness never depends on the speculation being right).
@@ -1784,9 +1892,15 @@ class KafkaWireSource(RecordSource):
                     p = fp.partition
                     if p not in remaining:
                         continue
-                    if fp.error or len(fp.records) == 0:
+                    if fp.error:
                         clean = False
                         break
+                    if len(fp.records) == 0:
+                        # Nothing consumed (a sibling spent the response
+                        # budget, or nothing is retained): the next fetch
+                        # asks again from the same offset.
+                        spec[p] = next_offset[p]
+                        continue
                     nrec, used, covered = scan_record_set_native(
                         fp.records, self.verify_crc
                     )
@@ -1801,7 +1915,9 @@ class KafkaWireSource(RecordSource):
                         clean = False
                         break
                     spec[p] = min(covered, end[p])
-                if clean and spec:
+                if clean and any(
+                    spec[p] > next_offset[p] for p in spec
+                ):
                     lp2 = sorted(
                         p for p in order if p in spec and spec[p] < end[p]
                     )
@@ -1826,12 +1942,16 @@ class KafkaWireSource(RecordSource):
                                 fetch_v2,
                             ),
                         )
+                        # ...and receive it while this one decodes.
                         inflight[leader] = (
                             conn,
                             corr2,
                             {p: spec[p] for p in order2},
                             order2,
                             pmax2,
+                            receiver_of(leader).submit(
+                                obs_trace.in_context(receive), conn, corr2
+                            ),
                         )
                         spec_sent = True
             # Pre-decode the clean full-prefix record sets here (the
@@ -1862,7 +1982,9 @@ class KafkaWireSource(RecordSource):
             try:
                 return fetch_leader(leader, lparts, fetch_round)
             except (OSError, kc.KafkaProtocolError) as e:
-                inflight.pop(leader, None)
+                fl = inflight.pop(leader, None)
+                if fl is not None:
+                    drain(leader, fl)  # no close under its receiver
                 with conn_lock:
                     c = own_conns.pop(leader, None)
                 if c is not None:
@@ -1906,8 +2028,6 @@ class KafkaWireSource(RecordSource):
             progressed = False
             fetch_round += 1
             if len(by_leader) > 1 and pool is None:
-                from concurrent.futures import ThreadPoolExecutor
-
                 # max_workers is a CAP, not a pre-spawn: the executor
                 # creates threads lazily up to the concurrent task count,
                 # so leaders discovered later (metadata reload) still get
@@ -2372,18 +2492,14 @@ class KafkaWireSource(RecordSource):
                         p in remaining and next_offset[p] != off
                         for p, off in fl2[2].items()
                     ):
-                        # Speculation missed (compressed tail, error,
+                        # Speculation missed (corrupt frame, error,
                         # truncation): drain and discard so the next round
                         # fetches from the authoritative offsets.
-                        inflight.pop(leader, None)
-                        try:
-                            fl2[0].read_response(fl2[1])
-                        except Exception:
-                            fl2[0].close()
-                            with conn_lock:
-                                if own_conns.get(leader) is fl2[0]:
-                                    own_conns.pop(leader, None)
+                        drain(leader, inflight.pop(leader))
                 yield from flush(force=False)
+            # Let go of this round's responses: the next receive reuses a
+            # ring buffer only once nothing references it.
+            futures = results = result = fps = fp = data = None
             if transport_failed and remaining:
                 # Dead/reset connections this round: refresh the topology
                 # (a restarted broker or migrated leader shows up in fresh

@@ -305,6 +305,21 @@ FETCH_FIRST_BYTE_SECONDS = _REG.counter(
     "4-byte length), the time the broker takes to answer; the rest of "
     "kta_fetch_seconds_total is transfer.  Booked in the window of the "
     "'fetch.first_byte' span")
+FETCH_AHEAD = _REG.counter(
+    "kta_fetch_ahead_total",
+    "Fetch responses by how a wire stream took them: ahead = received on "
+    "the stream's background receiver while the previous response "
+    "decoded, inline = read on the stream itself, discarded = a "
+    "speculative response drained unused (its offsets proved wrong or "
+    "stale)",
+    labelnames=("outcome",))
+FETCH_BUFFERS = _REG.counter(
+    "kta_fetch_buffers_total",
+    "Receive buffers taken for response bodies on a wire stream's own "
+    "connections: reused = a ring buffer nothing else referenced, fresh = "
+    "newly allocated (the ring filling, a buffer still referenced, or a "
+    "response larger than the buffer)",
+    labelnames=("outcome",))
 DECODE_SECONDS = _REG.counter(
     "kta_decode_seconds_total",
     "Seconds spent in record-set decode: the native whole-response "

@@ -633,3 +633,227 @@ def test_api_versions_downgrade_dance():
         assert src._version(conn, kc.API_METADATA) == 5  # classic fallback
         assert conn.api_versions  # handshake completed despite the 35
         src.close()
+
+
+# ---------------------------------------------------------------------------
+# receive-ahead and the receive-buffer ring
+
+
+def _outcomes(name):
+    from kafka_topic_analyzer_tpu.obs.registry import default_registry
+
+    doc = default_registry().snapshot().get(name)
+    out = {}
+    for s in doc["samples"] if doc else []:
+        key = s["labels"]["outcome"]
+        out[key] = out.get(key, 0.0) + s["value"]
+    return out
+
+
+class _Booked:
+    """Deltas of a labelled counter over a ``with`` block, by outcome."""
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self._before = _outcomes(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        after = _outcomes(self.name)
+        self.delta = {k: v - self._before.get(k, 0.0) for k, v in after.items()
+                      if v != self._before.get(k, 0.0)}
+
+    def __getitem__(self, outcome):
+        return self.delta.get(outcome, 0.0)
+
+
+def _starved_broker(records, **kwargs):
+    """The budget-starvation shape: every response holds one whole batch
+    of the partition it leads with, and its siblings come back empty."""
+    return FakeBroker(
+        "wire.topic", records, max_records_per_fetch=10,
+        honor_partition_max_bytes=True, honor_max_bytes=True, **kwargs,
+    )
+
+
+#: Below any batch: a response is the first batch alone (KIP-74).
+ONE_BATCH = {"fetch.max.bytes": "1"}
+FAST_RETRY = {"retry.backoff.ms": "5", "reconnect.backoff.max.ms": "40"}
+
+
+def _assert_same_scan(result, records):
+    direct = _scan_direct(records, sorted(records))
+    m = result.metrics
+    assert np.array_equal(m.per_partition, direct.per_partition)
+    assert m.alive_keys == direct.alive_keys
+    assert m.overall_count == sum(len(r) for r in records.values())
+
+
+def test_receive_ahead_engages_when_siblings_starve():
+    """Responses whose other partitions came back empty still send the
+    next fetch ahead, and the receiver takes it off the socket."""
+    records = {p: _mk_records(p, 50) for p in range(3)}
+    with _starved_broker(records) as broker:
+        with _Booked("kta_fetch_ahead_total") as ahead, \
+                _Booked("kta_fetch_buffers_total") as bufs:
+            result = _scan_via_wire(broker, overrides=ONE_BATCH)
+    _assert_same_scan(result, records)
+    assert ahead["ahead"] >= 10 and ahead["inline"] >= 1
+    assert ahead["discarded"] == 0
+    # Two buffers fill the ring; every later response reuses one.
+    assert bufs["fresh"] <= 2 and bufs["reused"] >= 10
+
+
+def _poisoned_chunk(records, chunk):
+    """A CorruptionInjector whose damage the header walk cannot see: the
+    first record's length of ``chunk`` turns negative."""
+    from fake_broker import CorruptionInjector
+
+    clean = FakeBroker("wire.topic", records, max_records_per_fetch=10)
+    byte = clean._chunks[0][chunk][2][61]  # the first record's length
+    clean.stop()
+    return CorruptionInjector().flip_byte(0, chunk=chunk, offset=61,
+                                          xor=byte ^ 0x7F)
+
+
+def test_speculation_miss_on_a_corrupt_frame_is_discarded():
+    """Without check.crcs a record-body corruption passes the header walk,
+    so the next fetch goes ahead; processing stops at the frame for its
+    re-fetch, the speculation is drained, and the scan is the clean one
+    minus the poisoned frame."""
+    from kafka_topic_analyzer_tpu.config import CorruptionConfig
+
+    records = {0: _mk_records(0, 60)}
+    with FakeBroker(
+        "wire.topic", records, max_records_per_fetch=10,
+        corruption=_poisoned_chunk(records, 2),
+    ) as broker:
+        src = KafkaWireSource(
+            f"127.0.0.1:{broker.port}", "wire.topic", overrides=FAST_RETRY,
+            corruption=CorruptionConfig(policy="skip"),
+        )
+        cfg = AnalyzerConfig(num_partitions=1, batch_size=64,
+                             count_alive_keys=True, alive_bitmap_bits=20)
+        with _Booked("kta_fetch_ahead_total") as ahead:
+            result = run_scan("wire.topic", src,
+                              CpuExactBackend(cfg, init_now_s=10**10), 64)
+        src.close()
+    assert ahead["discarded"] >= 1 and ahead["ahead"] >= 1
+    assert result.corrupt_partitions[0]["frames"] == 1
+    _assert_same_scan(result, {0: records[0][:20] + records[0][30:]})
+
+
+def test_compressed_tail_is_read_inline():
+    """A gzip frame ends the native prefix, so its response is not clean:
+    no fetch goes ahead of it, the next one is read on the stream, and the
+    scan is unchanged."""
+    rows = _mk_records(0, 80)
+    with FakeBroker("wire.topic", {0: rows[:50]},
+                    max_records_per_fetch=10) as broker:
+        broker.compression = kc.COMPRESSION_GZIP
+        for i in range(50, 80, 10):
+            broker.produce(0, rows[i:i + 10])
+        with _Booked("kta_fetch_ahead_total") as ahead:
+            result = _scan_via_wire(broker)
+    _assert_same_scan(result, {0: rows})
+    assert ahead["ahead"] >= 4
+    assert ahead["inline"] >= 3  # the first, and after each gzip response
+    assert ahead["discarded"] == 0
+
+
+def test_connection_cut_mid_receive_is_a_transport_failure():
+    """The broker cuts the response the receiver is reading: the failure
+    comes back through the receive's future, is booked like any transport
+    failure, and the reconnected scan equals the fault-free one."""
+    from fake_broker import ChaosTrigger, FaultInjector
+
+    from kafka_topic_analyzer_tpu.obs import metrics as obs_metrics
+
+    records = {0: _mk_records(0, 200)}
+    with FakeBroker(
+        "wire.topic", records, max_records_per_fetch=20,
+        # Every fetch answer waits, so the cut lands on one the receiver
+        # is already waiting for.
+        response_delay=lambda api, _node: 0.02 if api == kc.API_FETCH else 0,
+    ) as broker:
+        src = KafkaWireSource(f"127.0.0.1:{broker.port}", "wire.topic",
+                              overrides=FAST_RETRY)
+
+        def cut():
+            broker.faults = FaultInjector().drop_connection(after_bytes=40)
+
+        cfg = AnalyzerConfig(num_partitions=1, batch_size=16,
+                             count_alive_keys=True, alive_bitmap_bits=20)
+        failures = obs_metrics.TRANSPORT_FAILURES.value
+        with _Booked("kta_fetch_ahead_total") as ahead:
+            result = run_scan("wire.topic", ChaosTrigger(src, 3, cut),
+                              CpuExactBackend(cfg, init_now_s=10**10), 16)
+        src.close()
+        assert broker.faults.exhausted()
+    assert obs_metrics.TRANSPORT_FAILURES.value - failures == 1
+    assert ahead["ahead"] >= 3
+    assert not result.degraded_partitions
+    _assert_same_scan(result, records)
+
+
+def _ring_conn(broker, **ring):
+    from kafka_topic_analyzer_tpu.io.kafka_wire import (
+        BrokerConnection,
+        RecvBufferRing,
+    )
+
+    conn = BrokerConnection("127.0.0.1", broker.port)
+    conn.ring = RecvBufferRing(**ring)
+    return conn
+
+
+def _fetch(conn, parts):
+    body = kc.encode_fetch_request("wire.topic", parts, 100, 1, 1 << 20,
+                                   1 << 20, 4)
+    return kc.decode_fetch_response(conn.request(kc.API_FETCH, 4, body), 4)
+
+
+def test_ring_reuses_a_buffer_nothing_holds():
+    with FakeBroker("wire.topic", {0: _mk_records(0, 40)},
+                    max_records_per_fetch=10) as broker:
+        conn = _ring_conn(broker, slots=1, min_bytes=1 << 16)
+        with _Booked("kta_fetch_buffers_total") as bufs:
+            fps = _fetch(conn, [(0, 0)])
+            first = bytes(fps[0].records)
+            del fps
+            fps = _fetch(conn, [(0, 10)])
+            assert bytes(fps[0].records) != first
+        conn.close()
+    assert bufs.delta == {"fresh": 1.0, "reused": 1.0}
+
+
+def test_ring_never_overwrites_a_held_view():
+    """A view kept into the previous response keeps its buffer: the next
+    receive lands in a fresh one, and the view's bytes stay as they were."""
+    with FakeBroker("wire.topic", {0: _mk_records(0, 40)},
+                    max_records_per_fetch=10) as broker:
+        conn = _ring_conn(broker, slots=1, min_bytes=1 << 16)
+        with _Booked("kta_fetch_buffers_total") as bufs:
+            held = _fetch(conn, [(0, 0)])[0].records
+            before = bytes(held)
+            later = _fetch(conn, [(0, 10)])[0].records
+            assert bytes(held) == before and bytes(later) != before
+        conn.close()
+    assert bufs.delta == {"fresh": 2.0}
+
+
+def test_ring_grows_for_a_larger_response():
+    records = {p: _mk_records(p, 20) for p in range(2)}
+    with FakeBroker("wire.topic", records, max_records_per_fetch=10) as broker:
+        conn = _ring_conn(broker, slots=1)  # buffers sized to a response
+        with _Booked("kta_fetch_buffers_total") as bufs:
+            one = _fetch(conn, [(0, 0)])
+            del one
+            both = _fetch(conn, [(0, 0), (1, 0)])  # two batches: larger
+            assert [len(fp.records) > 0 for fp in both] == [True, True]
+            del both
+            _fetch(conn, [(1, 10)])  # fits the grown buffer
+        conn.close()
+    assert bufs.delta == {"fresh": 2.0, "reused": 1.0}
