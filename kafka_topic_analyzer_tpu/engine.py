@@ -807,6 +807,10 @@ def run_scan(
                 if not multiproc:
                     fault_flush = flush_rounds
             alive = {r: True for r in feed_rows}
+            row_records = {
+                r: obs_metrics.MESH_ROW_RECORDS.labels(row=r)
+                for r in feed_rows
+            }
             while True:
                 shard_batches: "list" = [None] * d
                 step_valid = 0
@@ -820,6 +824,7 @@ def run_scan(
                         b, staged = item
                         step_valid += b.num_valid
                         step_bytes += b.nbytes
+                        row_records[r].inc(b.num_valid)
                         if isinstance(b, PackedRow):
                             tracker.observe_packed(b)
                             shard_batches[r] = staged

@@ -33,6 +33,11 @@ SCAN_BYTES = _REG.counter(
 BATCH_RECORDS = _REG.histogram(
     "kta_batch_records", "Valid records per engine step",
     buckets=BATCH_SIZE_BUCKETS)
+MESH_ROW_RECORDS = _REG.counter(
+    "kta_mesh_row_records_total",
+    "Valid records folded per data row of a sharded (--mesh) scan; the "
+    "rows step in lockstep, so the heaviest row sets the scan's pace",
+    labelnames=("row",))
 STAGE_SECONDS = _REG.counter(
     "kta_stage_seconds_total",
     "Cumulative wall seconds per scan stage, booked LIVE at every stage "
@@ -448,6 +453,10 @@ FINALIZE_D2H_BYTES = _REG.counter(
     "kta_finalize_d2h_bytes_total",
     "Bytes of analyzer state copied device to host at finalize (the "
     "'finalize.device_get' span's transfer)")
+MESH_MERGE_SECONDS = _REG.counter(
+    "kta_mesh_merge_seconds_total",
+    "Seconds the mesh backend's finalize spent in its collective merge, "
+    "from dispatch to results ready (the 'finalize.merge' span)")
 PROGRAM_BUILD_SECONDS = _REG.counter(
     "kta_program_build_seconds_total",
     "Seconds JAX spent building device programs in this process: tracing "

@@ -41,6 +41,7 @@ from __future__ import annotations
 
 import dataclasses as _dc
 import functools
+import time
 from typing import List, Optional
 
 import jax
@@ -76,6 +77,7 @@ from kafka_topic_analyzer_tpu.models.compaction import AliveBitmapState
 from kafka_topic_analyzer_tpu.models.message_metrics import MessageMetricsState
 from kafka_topic_analyzer_tpu.models.state import AnalyzerState
 from kafka_topic_analyzer_tpu.obs import metrics as obs_metrics
+from kafka_topic_analyzer_tpu.obs import trace as obs_trace
 from kafka_topic_analyzer_tpu.ops.bitmap import bitmap_num_words
 from kafka_topic_analyzer_tpu.parallel.mesh import DATA_AXIS, SPACE_AXIS, make_mesh
 from kafka_topic_analyzer_tpu.records import RecordBatch
@@ -87,6 +89,11 @@ from kafka_topic_analyzer_tpu.utils.timefmt import utc_now_seconds
 #: data AND space jointly (D·S rows), since each (data, space) device folds
 #: its own record chunk.
 _DEV = (DATA_AXIS, SPACE_AXIS)
+
+#: The merge program last compiled, under its (config, mesh).  Every scan
+#: builds a backend of its own, so back-to-back audits of one topic would
+#: otherwise trace, lower and load the merge again at each finalize.
+_MERGE_PROGRAM: dict = {}
 
 
 def _state_specs(config: AnalyzerConfig) -> AnalyzerState:
@@ -291,6 +298,11 @@ class ShardedTpuBackend(MetricBackend):
             else 0
         )
         self._pair_sharding = NamedSharding(self.mesh, P(DATA_AXIS, None))
+        # The step bodies close over locals, never over ``self``: a jitted
+        # function held by the backend and holding it back would keep the
+        # state (a 512 MiB bitmap a chip) alive after the scan, until the
+        # cyclic garbage collector ran.
+        pair_cap1 = self._pair_cap1
 
         def _step_body(state, bufs, ptabs=None):
             local = jax.tree.map(lambda x: x[0], state)
@@ -307,7 +319,7 @@ class ShardedTpuBackend(MetricBackend):
                 new = apply_pair_table(
                     new,
                     unpack_pair_table_device(
-                        ptabs[0], config, self._pair_cap1
+                        ptabs[0], config, pair_cap1
                     ),
                     config,
                     space_index=space_idx,
@@ -347,7 +359,7 @@ class ShardedTpuBackend(MetricBackend):
         self.superbatch_k = self.dispatch_config.resolve(config.batch_size)
         self.dispatch_depth = self.dispatch_config.depth
         if self.superbatch_k > 1:
-            self._pair_cap_k = (
+            self._pair_cap_k = pair_cap_k = (
                 pair_table_capacity(
                     config, config.batch_size, self.superbatch_k
                 )
@@ -372,7 +384,7 @@ class ShardedTpuBackend(MetricBackend):
                     # per-scan-step pair all_gather is gone.
                     pairs=(
                         unpack_pair_table_device(
-                            ptabs[0], config, self._pair_cap_k
+                            ptabs[0], config, pair_cap_k
                         )
                         if ptabs is not None
                         else None
@@ -428,6 +440,7 @@ class ShardedTpuBackend(MetricBackend):
         config = self.config
         specs = self._specs
 
+        @obs_trace.device_scope("merge")
         def merge_body(state):
             local = jax.tree.map(lambda x: x[0], state)
             m = local.metrics
@@ -909,28 +922,51 @@ class ShardedTpuBackend(MetricBackend):
 
     # -- finalize ------------------------------------------------------------
 
-    def finalize(self) -> TopicMetrics:
-        # Complete the dispatch-latency histogram before the merge
-        # collective syncs the state anyway.
-        self.drain_dispatch()
-        merged, alive_count, hll_regs, dd_counts = self._merge(self.state)
-        merged = jax.tree.map(np.asarray, jax.device_get(merged))
-        alive_count = int(alive_count)
+    def _merge_program(self):
+        """The merge compiled for this backend's state: the last one built
+        where its config and mesh are this backend's, else a new one."""
+        key = (self.config, self.mesh)
+        program = _MERGE_PROGRAM.get(key)
+        if program is None:
+            program = self._merge.lower(self.state).compile()
+            _MERGE_PROGRAM.clear()
+            _MERGE_PROGRAM[key] = program
+        return program
 
+    def finalize(self) -> TopicMetrics:
+        # Retire every in-flight step and build the merge first, so that
+        # the merge span times the collective merge alone (and the latency
+        # histogram closes).
+        self.block_until_ready()
+        merge = self._merge_program()
+        t0 = time.perf_counter()
+        with obs_trace.maybe_span("finalize.merge", cat="finalize"):
+            merged_dev = jax.block_until_ready(merge(self.state))
+        obs_metrics.MESH_MERGE_SECONDS.inc(time.perf_counter() - t0)
+        # The merged values are replicated: the copy reads one device's.
+        with obs_trace.maybe_span("finalize.device_get", cat="finalize"):
+            host = jax.tree.map(np.asarray, jax.device_get(merged_dev))
+        obs_metrics.FINALIZE_D2H_BYTES.inc(
+            sum(leaf.nbytes for leaf in jax.tree.leaves(host))
+        )
+        merged, alive_count, hll_regs, dd_counts = host
         from kafka_topic_analyzer_tpu.models.compaction import HLLState
         from kafka_topic_analyzer_tpu.models.quantiles import DDSketchState
 
-        host_state = AnalyzerState(
-            metrics=merged,
-            alive=None,
-            hll=HLLState(regs=np.asarray(hll_regs)) if hll_regs is not None else None,
-            quantiles=(
-                DDSketchState(counts=np.asarray(dd_counts))
-                if dd_counts is not None
-                else None
-            ),
-        )
-        metrics = metrics_from_state(host_state, self.config, self.init_now_s)
-        if self.config.count_alive_keys:
-            metrics.alive_keys = alive_count
-        return metrics
+        with obs_trace.maybe_span("finalize.host", cat="finalize"):
+            host_state = AnalyzerState(
+                metrics=merged,
+                alive=None,
+                hll=HLLState(regs=hll_regs) if hll_regs is not None else None,
+                quantiles=(
+                    DDSketchState(counts=dd_counts)
+                    if dd_counts is not None
+                    else None
+                ),
+            )
+            metrics = metrics_from_state(
+                host_state, self.config, self.init_now_s
+            )
+            if self.config.count_alive_keys:
+                metrics.alive_keys = int(alive_count)
+            return metrics

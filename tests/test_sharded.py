@@ -1,6 +1,8 @@
 """Sharded (mesh) backend parity vs the CPU oracle on the virtual 8-device
 CPU platform — SURVEY.md §4 'multi-core without a cluster'."""
 
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -232,3 +234,51 @@ def test_non_dense_partitions_sharded_engine_scan(tmp_path):
     assert np.array_equal(m.per_partition, want.per_partition)
     assert m.overall_count == want.overall_count
     assert m.alive_keys == want.alive_keys
+
+
+def test_a_dropped_backend_frees_its_state_without_the_cycle_collector():
+    """No jitted step holds the backend: its device state (the alive
+    bitmap, 512 MiB a chip at the CLI default) goes with its last
+    reference, not at some later cyclic collection."""
+    import gc
+    import weakref
+
+    cfg = AnalyzerConfig(
+        num_partitions=7, batch_size=512, count_alive_keys=True,
+        alive_bitmap_bits=16, mesh_shape=(4, 1),
+    )
+    be = ShardedTpuBackend(cfg, init_now_s=10**10)
+    run_scan("t", SyntheticSource(SPEC), be, cfg.batch_size)
+    ref = weakref.ref(be)
+    gc.disable()
+    try:
+        del be
+        assert ref() is None
+    finally:
+        gc.enable()
+
+
+def test_back_to_back_scans_compile_the_merge_once():
+    """Each scan builds its own backend; the second of two with one config
+    and mesh merges with the program the first compiled, so that its
+    ``finalize.merge`` times the collective and no build."""
+    from kafka_topic_analyzer_tpu.parallel import sharded
+
+    cfg = AnalyzerConfig(
+        num_partitions=7, batch_size=512, count_alive_keys=True,
+        alive_bitmap_bits=20, enable_hll=True, enable_quantiles=True,
+        mesh_shape=(4, 1),
+    )
+    first = run_sharded(cfg)
+    (program,) = sharded._MERGE_PROGRAM.values()
+    second = ShardedTpuBackend(cfg, init_now_s=10**10)
+    assert second._merge_program() is program
+    again = run_scan("t", SyntheticSource(SPEC), second, cfg.batch_size)
+    assert np.array_equal(again.metrics.per_partition, first.per_partition)
+    assert again.metrics.alive_keys == first.alive_keys
+    assert again.metrics.alive_keys == run_cpu(cfg).alive_keys
+
+    other = dataclasses.replace(cfg, mesh_shape=(2, 1))
+    third = ShardedTpuBackend(other, init_now_s=10**10)
+    run_scan("t", SyntheticSource(SPEC), third, other.batch_size)
+    assert list(sharded._MERGE_PROGRAM) == [(other, third.mesh)]

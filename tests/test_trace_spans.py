@@ -184,3 +184,56 @@ def test_finalize_d2h_bytes_equal_the_state_bytes():
     before = obs_metrics.FINALIZE_D2H_BYTES.value
     backend.finalize()
     assert obs_metrics.FINALIZE_D2H_BYTES.value - before == state_bytes
+
+
+@pytest.mark.skipif(len(__import__("jax").devices()) < 4,
+                    reason="needs 4 (virtual) devices")
+def test_sharded_finalize_books_its_merge_and_rows():
+    from kafka_topic_analyzer_tpu.io.synthetic import (
+        SyntheticSource,
+        SyntheticSpec,
+    )
+    from kafka_topic_analyzer_tpu.parallel.sharded import ShardedTpuBackend
+
+    cfg = AnalyzerConfig(
+        num_partitions=5, batch_size=128, count_alive_keys=True,
+        alive_bitmap_bits=16, enable_hll=True, hll_p=8,
+        enable_quantiles=True, mesh_shape=(4, 1),
+    )
+    spec = SyntheticSpec(num_partitions=5, messages_per_partition=300,
+                         keys_per_partition=40, seed=7)
+    backend = ShardedTpuBackend(cfg, init_now_s=10**10)
+    text = backend._merge.lower(backend.state).as_text(debug_info=True)
+    assert '"kta.merge/' in text
+
+    rows = obs_metrics.MESH_ROW_RECORDS
+    before = {r: rows.labels(row=r).value for r in range(4)}
+    scan0 = obs_metrics.SCAN_RECORDS.value
+    merge0 = obs_metrics.MESH_MERGE_SECONDS.value
+    d2h0 = obs_metrics.FINALIZE_D2H_BYTES.value
+    tracer = obs_trace.SpanTracer()
+    obs_trace.set_active(tracer)
+    try:
+        result = run_scan("t", SyntheticSource(spec), backend, cfg.batch_size)
+    finally:
+        obs_trace.set_active(None)
+    assert result.metrics.overall_count == 1500
+
+    booked = [rows.labels(row=r).value - before[r] for r in range(4)]
+    # Round-robin: partitions 0 and 4 on row 0, one partition on each other.
+    assert booked == [600, 300, 300, 300]
+    assert sum(booked) == obs_metrics.SCAN_RECORDS.value - scan0
+    assert obs_metrics.MESH_MERGE_SECONDS.value > merge0
+    # The merged counters and sketches come back, not the rows' bitmaps.
+    d2h = obs_metrics.FINALIZE_D2H_BYTES.value - d2h0
+    assert 0 < d2h < (1 << 16) // 8 * 4
+
+    spans = {e["name"]: e for e in tracer.events()
+             if e["name"].startswith("finalize")}
+    outer = spans["finalize"]
+    for name in ("finalize.merge", "finalize.device_get", "finalize.host"):
+        inner = spans[name]
+        assert outer["ts"] <= inner["ts"]
+        assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+    assert (spans["finalize.merge"]["ts"] + spans["finalize.merge"]["dur"]
+            <= spans["finalize.device_get"]["ts"])

@@ -166,10 +166,16 @@ def test_pallas_counters_merge_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("mesh_shape", [(4, 1), (2, 2)], ids=["4", "2x2"])
-def test_sharded_merge_compiles_for_v5e(topo, monkeypatch, mesh_shape):
+@pytest.mark.parametrize(
+    "mesh_shape,bits",
+    [((4, 1), 26), ((2, 2), 26), ((4, 1), 32)],
+    ids=["4", "2x2", "4-bits32"],
+)
+def test_sharded_merge_compiles_for_v5e(topo, monkeypatch, mesh_shape, bits):
     """The finalize collectives: the TPU lowers only SUM all-reduces of
-    64-bit integers, so a 64-bit pmin/pmax there is refused."""
+    64-bit integers, so a 64-bit pmin/pmax there is refused.  At the CLI's
+    2^32-slot bitmap each chip gathers every row's 512 MiB of it, and the
+    merge must still fit one chip."""
     import jax
     from jax.sharding import NamedSharding
 
@@ -185,10 +191,10 @@ def test_sharded_merge_compiles_for_v5e(topo, monkeypatch, mesh_shape):
         ),
     )
     config = dataclasses.replace(
-        _scan_config(26, 1 << 18), mesh_shape=mesh_shape
+        _scan_config(bits, 1 << 18), mesh_shape=mesh_shape
     )
     backend = sharded.ShardedTpuBackend(
         config, mesh=make_mesh(*mesh_shape, devices=topo.devices), init_now_s=0
     )
-    compiled = backend._merge.lower(backend.state).compile()
+    compiled = _compile(backend._merge, (backend.state,))
     assert "all-reduce" in compiled.as_text()
