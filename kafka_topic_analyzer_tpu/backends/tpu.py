@@ -119,6 +119,18 @@ def make_packed_superstep(config: AnalyzerConfig, k: int = 1):
     return superstep
 
 
+def _with_token(step):
+    """``step`` returning, besides the new state, one element of it as a
+    completion token: an output of its own that no later step's donation
+    of the state takes, so the dispatch queue can block on it."""
+
+    def step_t(state, *bufs):
+        state = step(state, *bufs)
+        return state, jax.tree.leaves(state)[0].ravel()[:1]
+
+    return step_t
+
+
 class StagedBatch:
     """A batch already packed for (or launched into) host→device transfer.
 
@@ -223,7 +235,8 @@ class TpuBackend(MetricBackend):
         # the extra copy per step costs nothing measurable there.
         self._donate = (0,) if self.device.platform != "cpu" else ()
         self._step = jax.jit(
-            make_packed_step(config), donate_argnums=self._donate
+            _with_token(make_packed_step(config)),
+            donate_argnums=self._donate,
         )
         # Superbatch dispatch layer (config.DispatchConfig): K packed
         # buffers per jitted scan dispatch, up to `depth` superbatches in
@@ -232,6 +245,10 @@ class TpuBackend(MetricBackend):
         self.dispatch_config = dispatch if dispatch is not None else DispatchConfig()
         self.superbatch_k = self.dispatch_config.resolve(config.batch_size)
         self.dispatch_depth = self.dispatch_config.depth
+        # Bounds the superbatches in flight, and at K=1 the batches staged
+        # on the host that ``update`` places (each in-flight step holds
+        # its input rows on the device).
+        self._queue = DispatchQueue(self.dispatch_depth)
         # Compacted alive path (DESIGN.md §19): per-dispatch pair-table
         # capacities for the two dispatch shapes this backend compiles.
         self._compact = config.compact_alive
@@ -255,7 +272,6 @@ class TpuBackend(MetricBackend):
                 self.superbatch_k,
                 self.dispatch_depth,
             )
-            self._queue = DispatchQueue(self.dispatch_depth)
             self._empty_buf: "np.ndarray | None" = None
 
     def set_dispatch_depth(self, depth: int) -> None:
@@ -268,9 +284,7 @@ class TpuBackend(MetricBackend):
         sized then and a wider window would outrun its slots."""
         depth = max(1, min(int(depth), self.dispatch_config.depth))
         self.dispatch_depth = depth
-        q = getattr(self, "_queue", None)
-        if q is not None:
-            q.depth = depth
+        self._queue.depth = depth
 
     def _pack_pairs(self, pair_lists, cap) -> np.ndarray:
         """Merge + pack a dispatch's pair table, booking the raw→emitted
@@ -282,7 +296,34 @@ class TpuBackend(MetricBackend):
         obs_metrics.ALIVE_PAIRS_EMITTED.inc(emitted)
         return buf
 
-    def prepare(self, batch: RecordBatch) -> StagedBatch:
+    def _stage(self, buf, pairs, place: bool) -> StagedBatch:
+        """A packed row and its raw alive pairs in their staged form.  At
+        K=1 the pairs are packed into the batch's own table here, and with
+        ``place`` both buffers start their host→device transfer; at K>1
+        both stay raw on the host for the dispatch-time stack and merge."""
+        if self.superbatch_k > 1:
+            return StagedBatch(buf, pairs)
+        staged = StagedBatch(
+            buf,
+            self._pack_pairs([pairs], self._pair_cap1)
+            if self._compact else None,
+        )
+        return self.place(staged) if place else staged
+
+    def place(self, staged: StagedBatch) -> StagedBatch:
+        """Start the host→device transfer of a batch staged with
+        ``place=False`` (``update`` does, at dispatch).  At K>1 rows cross
+        in their superbatch's stack at dispatch time, so the batch stays
+        as it is."""
+        if self.superbatch_k > 1:
+            return staged
+        return StagedBatch(
+            jax.device_put(staged.buf, self.device),
+            None if staged.pairs is None
+            else jax.device_put(staged.pairs, self.device),
+        )
+
+    def prepare(self, batch: RecordBatch, place: bool = True) -> StagedBatch:
         """Pack (and, at superbatch K=1, start the host→device transfer
         for) a batch that will be fed to ``update``/``update_superbatch``
         later.  Safe to call from a worker thread (jax dispatch is
@@ -291,43 +332,27 @@ class TpuBackend(MetricBackend):
         time and crosses in the stack's single large transfer.  Compacted
         alive configs stage the batch's pairs alongside: packed + put at
         K=1 (the whole table is this batch's), raw host arrays at K>1
-        (the dispatch-time merge spans the superbatch)."""
+        (the dispatch-time merge spans the superbatch).  ``place=False``
+        packs without the transfer: ``update`` starts it at dispatch,
+        where the dispatch depth bounds the staged rows on the device."""
         buf = pack_batch(batch, self.config, use_native=self.use_native)
-        if self.superbatch_k > 1:
-            if self._compact:
-                return StagedBatch(
-                    buf, batch_alive_pairs(batch, self.config, self.use_native)
-                )
-            return StagedBatch(buf)
-        if self._compact:
-            pairbuf = self._pack_pairs(
-                [batch_alive_pairs(batch, self.config, self.use_native)],
-                self._pair_cap1,
-            )
-            return StagedBatch(
-                jax.device_put(buf, self.device),
-                jax.device_put(pairbuf, self.device),
-            )
-        return StagedBatch(jax.device_put(buf, self.device))
+        pairs = (
+            batch_alive_pairs(batch, self.config, self.use_native)
+            if self._compact else None
+        )
+        return self._stage(buf, pairs, place)
 
-    def make_fused_sink(self, dense_of):
+    def make_fused_sink(self, dense_of, place: bool = True):
         """A packing.FusedPackSink staged for this backend: fused rows
         come out exactly like ``prepare``'s output (async ``device_put``
-        on the producing thread at K=1; host buffer at K>1, copied into
-        its superbatch stager row at fan-in time).  One sink per ingest
-        stream — sinks are single-threaded state."""
+        on the producing thread at K=1 unless ``place`` is False; host
+        buffer at K>1, copied into its superbatch stager row at fan-in
+        time).  One sink per ingest stream — sinks are single-threaded
+        state."""
         from kafka_topic_analyzer_tpu.packing import FusedPackSink
 
         def stage(buf, pairs=None):
-            if self.superbatch_k > 1:
-                return StagedBatch(buf, pairs)
-            if self._compact:
-                pairbuf = self._pack_pairs([pairs], self._pair_cap1)
-                return StagedBatch(
-                    jax.device_put(buf, self.device),
-                    jax.device_put(pairbuf, self.device),
-                )
-            return StagedBatch(jax.device_put(buf, self.device))
+            return self._stage(buf, pairs, place)
 
         return FusedPackSink(
             self.config, self.config.batch_size, dense_of, stage=stage
@@ -335,12 +360,24 @@ class TpuBackend(MetricBackend):
 
     def update(self, batch: "RecordBatch | StagedBatch") -> None:
         if isinstance(batch, StagedBatch):
+            # Staged with ``place=False`` (the ingest streams of a
+            # multi-stream scan): placed here, once fewer than
+            # ``dispatch_depth`` such steps are in flight, so the device
+            # holds no more staged rows than the one-stream path.
+            on_host = isinstance(batch.buf, np.ndarray)
+            if on_host:
+                self._queue.throttle()
+                batch = self.place(batch)
             obs_metrics.WIRE_BYTES.inc(int(batch.buf.nbytes))
             if self._compact:
                 obs_metrics.WIRE_BYTES.inc(int(batch.pairs.nbytes))
-                self.state = self._step(self.state, batch.buf, batch.pairs)
+                self.state, token = self._step(
+                    self.state, batch.buf, batch.pairs
+                )
             else:
-                self.state = self._step(self.state, batch.buf)
+                self.state, token = self._step(self.state, batch.buf)
+            if on_host:
+                self._queue.launched(token, 1)
             return
         buf = pack_batch(batch, self.config, use_native=self.use_native)
         obs_metrics.WIRE_BYTES.inc(int(buf.nbytes))
@@ -350,13 +387,15 @@ class TpuBackend(MetricBackend):
                 self._pair_cap1,
             )
             obs_metrics.WIRE_BYTES.inc(int(pairbuf.nbytes))
-            self.state = self._step(
+            self.state, _ = self._step(
                 self.state,
                 jax.device_put(buf, self.device),
                 jax.device_put(pairbuf, self.device),
             )
             return
-        self.state = self._step(self.state, jax.device_put(buf, self.device))
+        self.state, _ = self._step(
+            self.state, jax.device_put(buf, self.device)
+        )
 
     def _empty_packed(self) -> np.ndarray:
         """Identity-fold pad for a partial superbatch tail: a packed empty
@@ -419,8 +458,7 @@ class TpuBackend(MetricBackend):
         close out and the snapshotted state is provably quiescent.  (The
         single-device twin of ShardedTpuBackend.drain_dispatch, where the
         no-new-collective property is what makes it lockstep-safe.)"""
-        if self.superbatch_k > 1:
-            self._queue.drain()
+        self._queue.drain()
 
     def block_until_ready(self) -> None:
         self.drain_dispatch()

@@ -201,18 +201,21 @@ def build_parser() -> argparse.ArgumentParser:
                         "(implies --quantiles)")
     p.add_argument("--mesh", metavar="DATA[,SPACE]", default="1",
                    help="Device mesh shape: data shards[, space shards]")
-    p.add_argument("--ingest-workers", default="1", metavar="N|auto",
+    p.add_argument("--ingest-workers", default=None, metavar="N|auto",
                    help="Parallel partition-sharded ingest for one scan: "
                         "shard the partition set over N private "
                         "fetch+decode+pack worker threads feeding the "
                         "backend through deterministic fan-ins — results "
                         "stay byte-identical to the sequential scan. "
                         "'auto' sizes from the host (min(cores-1, "
-                        "partitions)). Composes with --mesh: each "
-                        "controller resolves the count against ITS data "
-                        "shard's partitions and fans in per data row "
+                        "partitions)); 1 is the sequential scan. The "
+                        "device holds no more staged batches with N "
+                        "streams than with one. Composes with --mesh: "
+                        "each controller resolves the count against ITS "
+                        "data shard's partitions and fans in per data row "
                         "(host x device x dispatch parallelism in one "
-                        "scan). Default: 1")
+                        "scan). Default: auto on one device, one stream "
+                        "per data row on a mesh")
     p.add_argument("--superbatch", default="1", metavar="K|auto",
                    help="Superbatch dispatch: stack K packed batches into "
                         "one uint8[K, N] host array and fold them in a "
@@ -632,13 +635,22 @@ def resolve_ingest_workers(args, mesh_shape, num_partitions):
     PER CONTROLLER — auto = min(cores-1, that controller's shard
     partition count), explicit N clamped the same way — because under
     multi-controller neither the global partition count nor this
-    process's core count describes the other hosts (DESIGN.md §14)."""
+    process's core count describes the other hosts (DESIGN.md §14).
+
+    Without the flag a single device runs ``auto`` and a mesh one stream
+    per data row: a mesh row's stacked chunks cross at dispatch with no
+    bound on the steps in flight, so more streams a row let the drive
+    loop run ahead of the chips, each queued step holding its rows on
+    the device."""
     from kafka_topic_analyzer_tpu.config import IngestConfig
 
-    cfg = IngestConfig.parse(args.ingest_workers)
     if mesh_shape != (1, 1):
-        return cfg
-    return cfg.resolve(num_partitions)
+        if args.ingest_workers is None:
+            return IngestConfig(1)
+        return IngestConfig.parse(args.ingest_workers)
+    return IngestConfig.parse(args.ingest_workers or "auto").resolve(
+        num_partitions
+    )
 
 
 def resolve_dispatch(args):
@@ -1087,7 +1099,7 @@ def run_fleet(args, topics: "list[str] | None" = None) -> int:
                 "solo with --from-timestamp instead"
             )
         dispatch = resolve_dispatch(args)
-        ingest_cfg = IngestConfig.parse(args.ingest_workers)
+        ingest_cfg = IngestConfig.parse(args.ingest_workers or "auto")
         text = str(args.fleet_concurrency).strip().lower()
         explicit_concurrency = None
         if text != "auto":

@@ -79,15 +79,15 @@ class IngestConfig:
     scan resumes under any other worker count.
     """
 
-    #: ``1`` = the sequential path (today's default), ``N`` = that many
-    #: partition-sharded ingest workers, ``"auto"`` = size from the host:
-    #: min(cores - 1, partitions), keeping one core for the merge loop +
-    #: device dispatch.  On a sharded mesh the count resolves PER
+    #: ``"auto"`` (the default) = size from the host: min(cores - 1,
+    #: partitions), keeping one core for the merge loop + device
+    #: dispatch; ``N`` = that many partition-sharded ingest workers, and
+    #: ``1`` the sequential path.  On a sharded mesh the count resolves PER
     #: CONTROLLER: ``resolve`` is called with that controller's shard
     #: partition count, and the result splits across its data rows
     #: (parallel/ingest.py::allocate_row_workers) — so the same CLI line
     #: sizes every host of a heterogeneous fleet correctly (DESIGN.md §14).
-    workers: "int | str" = 1
+    workers: "int | str" = "auto"
 
     def __post_init__(self) -> None:
         if isinstance(self.workers, str):

@@ -169,13 +169,15 @@ class _ProgressTracker:
             self.next_offsets[p] = self.next_offsets.get(p, 0) + c
 
 
-def _note_fetch_streams(source, workers: int) -> None:
-    """Tell the process-wide fetch scheduler how many ingest streams this
-    scan just resolved: under ``--fetch-concurrency auto`` the shared
-    pool grows so every stream can keep a demand fetch plus some
+def _note_streams(source, workers: int) -> None:
+    """Book the ingest streams this scan just resolved (the resolved-
+    workers gauge and the once-per-scan streams counter), and tell the
+    process-wide fetch scheduler: under ``--fetch-concurrency auto`` the
+    shared pool grows so every stream can keep a demand fetch plus some
     speculation in flight (an explicit size is never overridden).  Only
-    remote segment sources feed the scheduler — everything else is a
-    no-op."""
+    remote segment sources feed the scheduler."""
+    obs_metrics.INGEST_RESOLVED_WORKERS.set(workers)
+    obs_metrics.INGEST_STREAMS.inc(workers)
     if bool(getattr(getattr(source, "store", None), "is_remote", False)):
         from kafka_topic_analyzer_tpu.io import fetchsched
 
@@ -239,7 +241,10 @@ def run_scan(
     resolves PER CONTROLLER against this process's shard partition count
     and splits across its data rows, composing host-parallel ingest with
     the device-parallel collective scan (DESIGN.md §14); single-device
-    backends clamp to the topic's partition count as before.
+    backends clamp to the topic's partition count as before, and place
+    each stream's rows on the device at their dispatch.  The
+    default here stays 1, the sequential scan a library caller gets
+    unless it asks: the CLI and ``IngestConfig`` default to ``"auto"``.
 
     Follow-mode pass hooks (serve/follow.py — the follow service reruns
     this function per poll on the SAME backend, so state accumulates):
@@ -739,8 +744,7 @@ def run_scan(
             used_workers = max(1, sum(row_workers.values()))
             # Recorded per process so the gather below can report the
             # RESOLVED per-controller counts, not just a global scalar.
-            obs_metrics.INGEST_RESOLVED_WORKERS.set(used_workers)
-            _note_fetch_streams(source, used_workers)
+            _note_streams(source, used_workers)
             # Cold sources (segment catalogs) know per-partition record
             # counts: balance each row's worker groups by records
             # (greedy-LPT), exactly like the single-device path below.
@@ -878,8 +882,7 @@ def run_scan(
                 else None
             )
             used_workers = ingest_cfg.resolve(len(pindex))
-            obs_metrics.INGEST_RESOLVED_WORKERS.set(used_workers)
-            _note_fetch_streams(source, used_workers)
+            _note_streams(source, used_workers)
             if used_workers > 1:
                 # Partition-sharded parallel ingest (--ingest-workers): N
                 # private fetch→decode→pack streams, merged through a
@@ -893,6 +896,18 @@ def run_scan(
                     shard_partitions,
                 )
 
+                # Workers pack on the host; the backend places each row
+                # at its dispatch, under the dispatch depth, so the device
+                # holds no more staged rows than with one stream, whatever
+                # N is.
+                worker_sink = make_sink
+                if hasattr(backend, "place"):
+                    stage = (
+                        lambda b: prepare(_dense_copy(b), place=False)
+                    )
+                    worker_sink = functools.partial(
+                        _make_sink, _dense_map.__getitem__, place=False
+                    )
                 # Cold sources (segment catalogs) know per-partition record
                 # counts up front: balance workers by records, not partition
                 # count.  Byte-identity is grouping-independent (DESIGN §11),
@@ -911,7 +926,7 @@ def run_scan(
                         start_at=start_at,
                         stage=stage,
                         depth=max(prefetch_depth, 1),
-                        sink_factory=make_sink if fused else None,
+                        sink_factory=worker_sink if fused else None,
                     )
                 )
             else:

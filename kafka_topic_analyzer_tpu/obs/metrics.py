@@ -97,6 +97,10 @@ INGEST_RESOLVED_WORKERS = _REG.gauge(
     "Controllers feed disjoint partition sets, so the cross-controller "
     "merge (gather_telemetry) sums to the fleet-wide thread count",
     merge="sum")
+INGEST_STREAMS = _REG.counter(
+    "kta_ingest_streams_total",
+    "Ingest streams (private fetch-decode-pack pipelines) opened, booked "
+    "once per scan; a mesh scan counts every data row's streams")
 INGEST_WORKER_RECORDS = _REG.counter(
     "kta_ingest_worker_records_total",
     "Valid records produced per parallel-ingest worker",

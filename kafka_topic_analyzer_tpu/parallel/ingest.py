@@ -28,6 +28,9 @@ This module runs N of them inside one scan:
   the native GIL-releasing work parallelizes;
 - workers push (batch, staged) pairs into bounded per-worker queues
   (backpressure = queue depth, the prefetch contract's ``prefetch_depth``);
+  what they stage stays on the HOST, so the device's staged batches do
+  not grow with N: the backend places each row at its dispatch, under
+  the dispatch depth;
 - the consuming thread merges the queues in a DETERMINISTIC round-robin
   order (worker 0, 1, ..., N-1, 0, ... — exhausted workers drop out of the
   rotation).
@@ -159,8 +162,8 @@ def allocate_row_workers(
 
 class _IngestWorker(threading.Thread):
     """One worker: a private ``source.batches()`` stream for one partition
-    group, staged (pack + host→device transfer start) on this thread, fed
-    into a bounded queue.  Mirrors the prefetch contract: errors travel to
+    group, staged (packed on the host) on this thread, fed into a bounded
+    queue.  Mirrors the prefetch contract: errors travel to
     the consumer as `_Error`, exhaustion as a sentinel, and close-on-exit
     drains the thread AND closes the underlying generator."""
 
@@ -232,7 +235,13 @@ class _IngestWorker(threading.Thread):
             self._stall.inc(time.perf_counter() - t0)
 
     def run(self) -> None:
-        self._drain_in_context()
+        try:
+            self._drain_in_context()
+        finally:
+            # As Thread.run drops its target: the bound method would keep
+            # this worker, and the backend its stage closes over (and that
+            # backend's device state), alive until the cyclic collector.
+            del self._drain_in_context
 
     def _drain(self) -> None:
         # Lifetime booking brackets the whole stream: busy fraction =

@@ -27,6 +27,7 @@ from kafka_topic_analyzer_tpu.engine import run_scan
 from kafka_topic_analyzer_tpu.io import kafka_codec as kc
 from kafka_topic_analyzer_tpu.io.kafka_wire import KafkaWireSource
 from kafka_topic_analyzer_tpu.io.synthetic import SyntheticSource, SyntheticSpec
+from kafka_topic_analyzer_tpu.obs import metrics as obs_metrics
 from kafka_topic_analyzer_tpu.parallel.ingest import (
     ParallelIngest,
     shard_partitions,
@@ -563,15 +564,48 @@ class _ExplodingSource(SyntheticSource):
             yield b
 
 
-def test_worker_error_aborts_scan_without_leaks():
+class _SlowSource(SyntheticSource):
+    def batches(self, batch_size, partitions=None, start_at=None):
+        for b in super().batches(batch_size, partitions, start_at):
+            time.sleep(0.2)
+            yield b
+
+
+@pytest.mark.parametrize("backend,fails", [
+    ("cpu", "worker"),
+    # The staged backend places rows at dispatch: a failure in a stream
+    # or in a dispatch must stop every thread.
+    ("tpu", "worker"),
+    ("tpu", "dispatch"),
+])
+def test_worker_error_aborts_scan_without_leaks(backend, fails):
+    from kafka_topic_analyzer_tpu.backends.tpu import TpuBackend
+
     spec = SyntheticSpec(num_partitions=4, messages_per_partition=4000)
     cfg = AnalyzerConfig(num_partitions=4, batch_size=128)
+    if backend == "cpu":
+        backend = CpuExactBackend(cfg, init_now_s=0)
+    else:
+        backend = TpuBackend(cfg, init_now_s=0)
+    source = _ExplodingSource(spec, bad_partition=1)
+    if fails == "dispatch":
+        # Streams slower than the drive loop: it is waiting on a worker's
+        # queue when the scan fails.
+        source = _SlowSource(spec)
+        update, calls = backend.update, []
+
+        def failing_update(staged):
+            calls.append(1)
+            if len(calls) > 6:
+                raise _Boom()
+            update(staged)
+
+        backend.update = failing_update
     before = threading.active_count()
+    t0 = time.monotonic()
     with pytest.raises(_Boom):
-        run_scan(
-            "t", _ExplodingSource(spec, bad_partition=1),
-            CpuExactBackend(cfg, init_now_s=0), 128, ingest_workers=3,
-        )
+        run_scan("t", source, backend, 128, ingest_workers=3)
+    assert time.monotonic() - t0 < 4  # no join waited out its timeout
     deadline = time.monotonic() + 5
     while threading.active_count() > before and time.monotonic() < deadline:
         time.sleep(0.05)
@@ -681,3 +715,210 @@ def test_cli_stats_and_json_report_worker_count(capsys):
     doc = json.loads(out.out.splitlines()[-1])
     assert doc["ingest_workers"] == 3
     assert "ingest: 3 worker(s)" in out.err
+
+
+# ---------------------------------------------------------------------------
+# one staging point: the device holds no more staged batches with N streams
+# than with one
+
+
+STAGE_PARTS = 16
+STAGE_RECORDS = {p: _mk_records(p, 2000) for p in range(STAGE_PARTS)}
+#: A fetch response carries 60 records a partition, under one 1024-record
+#: row, so the one-stream path completes at most one row a response and
+#: holds, at most, its prefetch depth (2), the row its thread is staging,
+#: and the two the drive loop holds around a dispatch.
+ONE_STREAM_STAGED = 2 + 1 + 2
+_ONE_STREAM: dict = {}
+
+
+def _staged_rows_peak(monkeypatch, workers):
+    """Most packed rows the device held at once while a compacted (-c)
+    scan at K=1 read the 16-partition topic, with a drive loop slow enough
+    that every queue fills; and the scan's full doc."""
+    import weakref
+
+    import jax
+
+    from kafka_topic_analyzer_tpu.backends.tpu import TpuBackend
+    from kafka_topic_analyzer_tpu.packing import packed_nbytes
+
+    backend = TpuBackend(
+        AnalyzerConfig(
+            num_partitions=STAGE_PARTS, batch_size=1024,
+            count_alive_keys=True, alive_bitmap_bits=16,
+        ),
+        init_now_s=10**10,
+    )
+    assert backend.superbatch_k == 1 and backend.config.compact_alive
+    row = (packed_nbytes(backend.config, backend.config.batch_size),)
+    lock = threading.Lock()
+    live = {"now": 0, "peak": 0}
+
+    def freed():
+        with lock:
+            live["now"] -= 1
+
+    put = jax.device_put
+
+    def counting_put(x, *args, **kwargs):
+        out = put(x, *args, **kwargs)
+        if getattr(x, "dtype", None) == np.uint8 and x.shape == row:
+            with lock:
+                live["now"] += 1
+                live["peak"] = max(live["peak"], live["now"])
+            weakref.finalize(out, freed)
+        return out
+
+    update = backend.update
+
+    def slow_update(staged):
+        time.sleep(0.004)
+        update(staged)
+
+    backend.update = slow_update
+    with monkeypatch.context() as m:
+        m.setattr(jax, "device_put", counting_put)
+        with FakeBroker(
+            TOPIC, STAGE_RECORDS, max_records_per_fetch=60
+        ) as broker:
+            result = run_scan(
+                TOPIC,
+                KafkaWireSource(
+                    f"127.0.0.1:{broker.port}", TOPIC,
+                    overrides=dict(FAST_RETRY),
+                ),
+                backend, backend.config.batch_size, ingest_workers=workers,
+            )
+    assert live["now"] == 0
+    return live["peak"], result
+
+
+@pytest.mark.parametrize("workers", [1, 4, "auto"])
+def test_device_staged_batches_bounded_by_one_stream(monkeypatch, workers):
+    from kafka_topic_analyzer_tpu.config import DispatchConfig
+
+    peak, result = _staged_rows_peak(monkeypatch, workers)
+    assert result.ingest_workers == IngestConfig(workers).resolve(STAGE_PARTS)
+    assert 1 <= peak <= ONE_STREAM_STAGED
+    if result.ingest_workers > 1:
+        # Rows are placed at their dispatch: no more than the steps the
+        # dispatch depth lets be in flight.
+        assert peak <= DispatchConfig().depth
+    if not _ONE_STREAM:
+        _ONE_STREAM["doc"] = _full_doc(
+            _staged_rows_peak(monkeypatch, 1)[1]
+        ) if workers != 1 else _full_doc(result)
+    assert _full_doc(result) == _ONE_STREAM["doc"]
+
+
+def test_cli_defaults_to_auto_with_the_sequential_report(capsys):
+    """With no flag the CLI runs ``auto`` streams, and its report equals
+    ``--ingest-workers 1``'s line for line, keyed (-c --distinct-keys
+    --quantiles) and keyless."""
+    from kafka_topic_analyzer_tpu import cli
+
+    args = cli.build_parser().parse_args(["-t", "t"])
+    assert cli.resolve_ingest_workers(args, (1, 1), 6) == IngestConfig(
+        "auto"
+    ).resolve(6)
+    # A mesh keeps one stream per data row unless asked for more.
+    assert cli.resolve_ingest_workers(args, (2, 1), 6) == IngestConfig(1)
+    assert IngestConfig().workers == "auto"
+    parts = 6
+    keyless = {
+        p: [(o, ts, None, v) for o, ts, _, v in _mk_records(p, 400)]
+        for p in range(parts)
+    }
+    keyed = {p: _mk_records(p, 400) for p in range(parts)}
+    for records, flags in (
+        (keyed, ["-c", "--distinct-keys", "--quantiles"]),
+        (keyless, ["--quantiles"]),
+    ):
+        reports = []
+        for workers in ([], ["--ingest-workers", "1"]):
+            streams = obs_metrics.INGEST_STREAMS.value
+            with FakeBroker(
+                TOPIC, records, max_records_per_fetch=60
+            ) as broker:
+                rc = cli.main([
+                    "-t", TOPIC, "-b", f"127.0.0.1:{broker.port}",
+                    "--backend", "tpu", "--alive-bitmap-bits", "16",
+                    "--batch-size", "1024", "--quiet", *flags, *workers,
+                ])
+            assert rc == 0
+            reports.append([
+                line for line in capsys.readouterr().out.splitlines()
+                if not line.startswith(("Scanning took:", "Estimated Msg/s:"))
+            ])
+            assert obs_metrics.INGEST_STREAMS.value - streams == (
+                1 if workers else IngestConfig("auto").resolve(parts)
+            )
+        assert reports[0] == reports[1]
+        assert any(line.startswith("Topic ") for line in reports[0])
+
+
+@pytest.mark.parametrize("mesh,workers,streams", [
+    (1, 3, 3),
+    (1, "auto", None),
+    (2, 1, 2),  # every data row opens one stream
+    (2, 3, 3),
+])
+def test_streams_counter_books_the_resolved_count(mesh, workers, streams):
+    import jax
+
+    if mesh > len(jax.devices()):
+        pytest.skip("needs more virtual devices")
+    before = obs_metrics.INGEST_STREAMS.value
+    r = run_scan(
+        "t", SyntheticSource(MATRIX_SPEC), _composed_backend(mesh, 1), 256,
+        ingest_workers=workers,
+    )
+    if streams is None:
+        streams = IngestConfig(workers).resolve(5)
+    assert r.ingest_workers == streams
+    assert obs_metrics.INGEST_STREAMS.value - before == streams
+
+
+@pytest.mark.parametrize("mesh", ["1", "2"])
+@pytest.mark.parametrize("workers", ["1", "3"])
+def test_a_finished_audit_frees_its_backend_without_the_cycle_collector(
+    monkeypatch, capsys, mesh, workers
+):
+    """An audit's device state (the alive bitmap, 512 MiB at the CLI
+    default) goes with the audit, whatever the ingest streams: no stream
+    keeps the backend in a reference cycle that only a later cyclic
+    collection would free, or back-to-back audits would hold several."""
+    import gc
+    import weakref
+
+    import jax
+
+    from kafka_topic_analyzer_tpu import cli
+    from kafka_topic_analyzer_tpu.backends.tpu import TpuBackend
+    from kafka_topic_analyzer_tpu.parallel.sharded import ShardedTpuBackend
+
+    if int(mesh) > len(jax.devices()):
+        pytest.skip("needs more virtual devices")
+    built = []
+    for cls in (TpuBackend, ShardedTpuBackend):
+        def init(self, *args, _init=cls.__init__, **kwargs):
+            _init(self, *args, **kwargs)
+            built.append(weakref.ref(self))
+
+        monkeypatch.setattr(cls, "__init__", init)
+    with FakeBroker(TOPIC, RECORDS, max_records_per_fetch=60) as broker:
+        gc.collect()
+        gc.disable()
+        try:
+            rc = cli.main([
+                "-t", TOPIC, "-b", f"127.0.0.1:{broker.port}",
+                "--backend", "tpu", "-c", "--alive-bitmap-bits", "16",
+                "--batch-size", "1024", "--mesh", mesh,
+                "--ingest-workers", workers, "--quiet",
+            ])
+            capsys.readouterr()
+            assert rc == 0 and len(built) == 1
+            assert built[0]() is None
+        finally:
+            gc.enable()
