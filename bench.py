@@ -58,20 +58,6 @@ def main() -> int:
     ap.add_argument("--keys", type=int, default=200_000)
     ap.add_argument("--vmin", type=int, default=100)
     ap.add_argument("--vmax", type=int, default=420)
-    ap.add_argument("--pallas", action="store_true",
-                    help="use the Pallas MXU counter kernel")
-    ap.add_argument("--wire-format", choices=["v4", "v5"], default="v5",
-                    help="Packed wire format referee: v5 combiner rows "
-                         "(host pre-reduced fold tables, default) vs v4 "
-                         "per-record columns — byte-identical results, "
-                         "different device fold cost (BENCH round 11)")
-    ap.add_argument("--alive-compaction", choices=["auto", "off"],
-                    default="auto",
-                    help="host-side LWW alive-pair compaction referee "
-                         "(BENCH round 13): 'auto' ships one bounded "
-                         "per-dispatch pair table applied after the scan, "
-                         "'off' keeps the per-row pair sections and the "
-                         "in-scan pair scatter — byte-identical results")
     ap.add_argument("--superbatch", default="1", metavar="K|auto",
                     help="stack K packed batches per jitted scan dispatch "
                          "(state donated once per superbatch; 'auto' "
@@ -129,9 +115,6 @@ def main() -> int:
         alive_bitmap_bits=args.alive_bits,
         enable_hll="hll" in feats,
         enable_quantiles="quantiles" in feats,
-        use_pallas_counters=args.pallas,
-        wire_format={"v4": 4, "v5": 5}[args.wire_format],
-        alive_compaction=args.alive_compaction,
     )
     spec = SyntheticSpec(
         num_partitions=args.partitions,

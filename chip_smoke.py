@@ -10,11 +10,9 @@ published run), one record in ten a tombstone.  This process scans it with
 
 - the TPU report against the ``--backend cpu`` oracle: exact lines equal,
   HLL within 1 %, quantiles within 2α (BASELINE's budget);
-- a warm rerun and a ``--pallas`` rerun equal to the first TPU report;
+- a warm rerun equal to the first TPU report;
 - the device is a TPU, the native shim loaded, every record went through
-  the fused decode→pack path;
-- both Pallas kernels compile to a Mosaic kernel (``tpu_custom_call``) and
-  match the XLA path on the chip.
+  the fused decode→pack path.
 
 ``--chips 4`` runs only the mesh path: the same topic at ``--mesh 4`` and
 ``--mesh 2,2``, each report identical to the one-device report, with the
@@ -195,55 +193,6 @@ def compare_to_oracle(tpu: Scan, cpu: Scan) -> None:
         check(err <= budget, f"quantile {q} error {err} over 2α={budget}")
 
 
-def kernel_check(device) -> None:
-    """Both Pallas kernels compiled for this chip (no interpreter) and run
-    against the XLA path on seeded inputs."""
-    import functools
-
-    import jax
-    import numpy as np
-
-    from kafka_topic_analyzer_tpu.ops.counters import counters_update
-    from kafka_topic_analyzer_tpu.ops.pallas_counters import (
-        pallas_counters_merge,
-        pallas_counters_update,
-    )
-
-    rng = np.random.default_rng(21)
-    b = BATCH_SIZE
-    base = rng.integers(0, 1 << 40, size=(PARTITIONS, 7), dtype=np.int64)
-    cols = (
-        rng.integers(0, PARTITIONS, size=b, dtype=np.int32),
-        rng.integers(0, 60_000, size=b, dtype=np.int32),
-        rng.integers(0, (1 << 24) - 1, size=b, dtype=np.int32),
-        rng.random(b) < 0.1,
-        rng.random(b) < 0.1,
-        rng.random(b) < 0.9,
-    )
-    args = [jax.device_put(x, device) for x in (base, *cols)]
-    kernels = {
-        "pallas_counters_update": (
-            functools.partial(pallas_counters_update,
-                              num_partitions=PARTITIONS, interpret=False),
-            functools.partial(counters_update, num_partitions=PARTITIONS),
-            args,
-        ),
-        "pallas_counters_merge": (
-            functools.partial(pallas_counters_merge, interpret=False),
-            lambda a, d: a + d,
-            args[:1] + [jax.device_put(base[::-1].copy(), device)],
-        ),
-    }
-    for name, (kernel, reference, kargs) in kernels.items():
-        compiled = jax.jit(kernel).lower(*kargs).compile()
-        check("tpu_custom_call" in compiled.as_text(),
-              f"{name}: no tpu_custom_call in the compiled program")
-        got = np.asarray(compiled(*kargs))
-        want = np.asarray(jax.jit(reference)(*kargs))
-        check(np.array_equal(got, want), f"{name}: differs from the XLA path")
-        say(f"kernel {name}: Mosaic kernel compiled, equal to XLA on chip")
-
-
 def one_chip(port: int, device) -> None:
     cold = Scan(port, "tpu")
     check(cold.backend.device.platform == "tpu",
@@ -267,16 +216,6 @@ def one_chip(port: int, device) -> None:
         f"{warm.count / warm.seconds:,.0f} records/s")
     cpu = Scan(port, "cpu")
     compare_to_oracle(cold, cpu)
-    pallas = Scan(port, "tpu", "--pallas")
-    check(pallas.backend.config.use_pallas_counters
-          and not pallas.backend.config.pallas_interpret,
-          "--pallas scan did not run the compiled kernel")
-    del pallas.backend
-    gc.collect()
-    check(pallas.analysis() == cold.analysis(),
-          "--pallas report differs from the XLA report")
-    say("--pallas report identical to the XLA report")
-    kernel_check(device)
 
 
 def four_chips(port: int, devices) -> None:

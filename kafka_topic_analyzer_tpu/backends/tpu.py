@@ -4,10 +4,10 @@ Design (SURVEY.md §7 M3 + the transfer work in packing.py):
 - the accumulator state lives on device for the whole scan; each `update`
   dispatches one jitted step with the state buffers *donated*, so XLA updates
   them in place and the host never round-trips the state (hard part (e));
-- each batch crosses the host→device boundary as ONE packed uint8 buffer in
-  wire format v4 (packing.py's module docstring is the layout contract) —
-  minimal bytes per record, host-side pre-reduction for the bitmap/HLL
-  updates;
+- each batch crosses the host→device boundary as ONE packed uint8 buffer of
+  host-reduced fold tables (packing.py's module docstring is the layout
+  contract), and each dispatch of an alive-key scan one more buffer, its
+  merged alive table;
 - dispatch is asynchronous — the host thread returns immediately and keeps
   packing the next batch while the device works; `finalize` synchronizes;
 - at ``--superbatch K`` > 1, K packed buffers stack into one contiguous
@@ -23,7 +23,6 @@ Multi-device runs go through `kafka_topic_analyzer_tpu.parallel.sharded`
 
 from __future__ import annotations
 
-import dataclasses
 import os
 
 import jax
@@ -64,10 +63,10 @@ from kafka_topic_analyzer_tpu.utils.timefmt import utc_now_seconds
 def make_packed_step(config: AnalyzerConfig):
     """The jittable forward step: (state, packed uint8 buffer) → state.
 
-    Under alive-pair compaction (``config.compact_alive``) the step takes
-    a second buffer — the batch's packed pair table — applied after the
-    fold exactly like the superbatch path applies its merged table."""
-    if config.compact_alive:
+    Alive-key scans take a second buffer — the batch's packed alive table
+    — applied after the fold exactly like the superbatch path applies its
+    merged table."""
+    if config.count_alive_keys:
         cap = pair_table_capacity(config, config.batch_size, 1)
 
         def step_c(state: AnalyzerState, buf, pairbuf) -> AnalyzerState:
@@ -93,11 +92,10 @@ def make_packed_superstep(config: AnalyzerConfig, k: int = 1):
     per-batch valid counts) is a small non-donated output used by the
     bounded dispatch queue as a completion marker.
 
-    Under alive-pair compaction the superstep takes the dispatch's merged
-    pair table (capacity ``pair_table_capacity(config, B, k)``) and
-    applies it ONCE after the scan — this is the compaction win: the
-    O(W) bitmap mask apply leaves the scan body entirely."""
-    if config.compact_alive:
+    Alive-key scans take the dispatch's merged alive table (capacity
+    ``pair_table_capacity(config, B, k)``) and apply it ONCE after the
+    scan: the O(W) bitmap apply stays out of the scan body."""
+    if config.count_alive_keys:
         cap = pair_table_capacity(config, config.batch_size, k)
 
         def superstep_c(state: AnalyzerState, bufs, pairbuf):
@@ -146,11 +144,11 @@ class StagedBatch:
     Deliberately just a typed buffer: all bookkeeping (counts, bytes,
     offsets) stays with the decoded batch the engine already holds.
 
-    ``pairs`` rides the compacted alive path (DESIGN.md §19): at K=1 it
-    is the batch's PACKED pair-table buffer (device-put alongside the
+    ``pairs`` carries an alive-key scan's pairs (DESIGN.md §19): at K=1
+    it is the batch's PACKED alive-table buffer (device-put alongside the
     row on the producing thread); at K>1 the raw ``(slot u32[n], flag
-    u8[n])`` host arrays the dispatch-time merge consumes.  None when
-    compaction is off.
+    u8[n])`` host arrays the dispatch-time merge consumes.  None when the
+    scan counts no alive keys.
     """
 
     __slots__ = ("buf", "pairs")
@@ -163,38 +161,32 @@ class StagedBatch:
 def self_check_unpack(device=None) -> None:
     """One-time guard: pack a known batch on the host, unpack it on the
     device, and compare — catches any bitcast/byte-order mismatch before it
-    could corrupt results.  Runs BOTH wire formats: v4's per-record
-    columns and v5's combiner tables (including the quantile section)
-    cross the same bitcast boundary."""
+    could corrupt results.  The tables (quantile section included) and the
+    HLL pairs cross the same bitcast boundary every scan's rows do."""
     from kafka_topic_analyzer_tpu.io.synthetic import SyntheticSource, SyntheticSpec
 
     spec = SyntheticSpec(
         num_partitions=3, messages_per_partition=40, keys_per_partition=16, seed=11
     )
     batch = next(SyntheticSource(spec).batches(100))
-    for wire_format in (4, 5):
-        config = AnalyzerConfig(
-            num_partitions=3,
-            batch_size=128,
-            count_alive_keys=True,
-            alive_bitmap_bits=16,
-            enable_hll=True,
-            hll_p=8,
-            enable_quantiles=True,
-            wire_format=wire_format,
-        )
-        buf = pack_batch(batch, config, use_native=False)
-        expected = unpack_numpy(buf, config)
-        unpack = jax.jit(lambda b, c=config: unpack_device(b, c))
-        got = unpack(jax.device_put(buf, device))
-        for name, exp in expected.items():
-            g = np.asarray(got[name])
-            if not np.array_equal(g, np.asarray(exp)):
-                raise RuntimeError(
-                    f"packed-transfer self-check failed on wire-v"
-                    f"{wire_format} field {name!r}: device unpack disagrees "
-                    f"with host layout (byte order?)"
-                )
+    config = AnalyzerConfig(
+        num_partitions=3,
+        batch_size=128,
+        enable_hll=True,
+        hll_p=8,
+        enable_quantiles=True,
+    )
+    buf = pack_batch(batch, config, use_native=False)
+    expected = unpack_numpy(buf, config)
+    got = jax.jit(lambda b: unpack_device(b, config))(
+        jax.device_put(buf, device)
+    )
+    for name, exp in expected.items():
+        if not np.array_equal(np.asarray(got[name]), np.asarray(exp)):
+            raise RuntimeError(
+                f"packed-transfer self-check failed on field {name!r}: "
+                "device unpack disagrees with host layout (byte order?)"
+            )
 
 
 _checked_devices: "set[str]" = set()
@@ -212,10 +204,6 @@ class TpuBackend(MetricBackend):
     ):
         self.device = device if device is not None else jax.devices()[0]
         require_device_platform(self.device)
-        if config.use_pallas_counters:
-            config = dataclasses.replace(
-                config, pallas_interpret=self.device.platform != "tpu"
-            )
         super().__init__(config)
         self.init_now_s = utc_now_seconds() if init_now_s is None else init_now_s
         self.use_native = use_native
@@ -249,18 +237,18 @@ class TpuBackend(MetricBackend):
         # on the host that ``update`` places (each in-flight step holds
         # its input rows on the device).
         self._queue = DispatchQueue(self.dispatch_depth)
-        # Compacted alive path (DESIGN.md §19): per-dispatch pair-table
-        # capacities for the two dispatch shapes this backend compiles.
-        self._compact = config.compact_alive
+        # Alive tables (DESIGN.md §19): per-dispatch table capacities for
+        # the two dispatch shapes this backend compiles.
+        self._alive = config.count_alive_keys
         self._pair_cap1 = (
             pair_table_capacity(config, config.batch_size, 1)
-            if self._compact
+            if self._alive
             else 0
         )
         if self.superbatch_k > 1:
             self._pair_cap_k = (
                 pair_table_capacity(config, config.batch_size, self.superbatch_k)
-                if self._compact
+                if self._alive
                 else 0
             )
             self._superstep = jax.jit(
@@ -306,7 +294,7 @@ class TpuBackend(MetricBackend):
         staged = StagedBatch(
             buf,
             self._pack_pairs([pairs], self._pair_cap1)
-            if self._compact else None,
+            if self._alive else None,
         )
         return self.place(staged) if place else staged
 
@@ -329,8 +317,8 @@ class TpuBackend(MetricBackend):
         later.  Safe to call from a worker thread (jax dispatch is
         thread-safe; the packers are pure numpy/C++).  At K>1 the buffer
         stays on the host: it is copied into its superbatch row at fan-in
-        time and crosses in the stack's single large transfer.  Compacted
-        alive configs stage the batch's pairs alongside: packed + put at
+        time and crosses in the stack's single large transfer.  Alive-key
+        scans stage the batch's pairs alongside: packed + put at
         K=1 (the whole table is this batch's), raw host arrays at K>1
         (the dispatch-time merge spans the superbatch).  ``place=False``
         packs without the transfer: ``update`` starts it at dispatch,
@@ -338,7 +326,7 @@ class TpuBackend(MetricBackend):
         buf = pack_batch(batch, self.config, use_native=self.use_native)
         pairs = (
             batch_alive_pairs(batch, self.config, self.use_native)
-            if self._compact else None
+            if self._alive else None
         )
         return self._stage(buf, pairs, place)
 
@@ -369,7 +357,7 @@ class TpuBackend(MetricBackend):
                 self._queue.throttle()
                 batch = self.place(batch)
             obs_metrics.WIRE_BYTES.inc(int(batch.buf.nbytes))
-            if self._compact:
+            if self._alive:
                 obs_metrics.WIRE_BYTES.inc(int(batch.pairs.nbytes))
                 self.state, token = self._step(
                     self.state, batch.buf, batch.pairs
@@ -381,7 +369,7 @@ class TpuBackend(MetricBackend):
             return
         buf = pack_batch(batch, self.config, use_native=self.use_native)
         obs_metrics.WIRE_BYTES.inc(int(buf.nbytes))
-        if self._compact:
+        if self._alive:
             pairbuf = self._pack_pairs(
                 [batch_alive_pairs(batch, self.config, self.use_native)],
                 self._pair_cap1,
@@ -423,13 +411,13 @@ class TpuBackend(MetricBackend):
         for i, item in enumerate(staged):
             if isinstance(item, StagedBatch):
                 np.copyto(rows[i], np.asarray(item.buf))
-                if self._compact and item.pairs is not None:
+                if self._alive and item.pairs is not None:
                     pair_lists.append(item.pairs)
             else:
                 pack_batch(
                     item, self.config, use_native=self.use_native, out=rows[i]
                 )
-                if self._compact:
+                if self._alive:
                     pair_lists.append(
                         batch_alive_pairs(item, self.config, self.use_native)
                     )
@@ -437,11 +425,10 @@ class TpuBackend(MetricBackend):
             np.copyto(rows[i], self._empty_packed())
         obs_metrics.WIRE_BYTES.inc(int(rows.nbytes))
         bufs = jax.device_put(rows, self.device)
-        if self._compact:
-            # The compaction tentpole: LWW-merge the superbatch's pairs in
-            # fold order into ONE bounded table — the device applies it
-            # once after the scan instead of scattering inside every scan
-            # step (identity-padded tail rows contribute no pairs).
+        if self._alive:
+            # LWW-merge the superbatch's pairs in fold order into ONE
+            # bounded table — the device applies it once after the scan
+            # (identity-padded tail rows contribute no pairs).
             pairbuf = self._pack_pairs(pair_lists, self._pair_cap_k)
             obs_metrics.WIRE_BYTES.inc(int(pairbuf.nbytes))
             self.state, token = self._superstep(

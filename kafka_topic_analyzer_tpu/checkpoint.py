@@ -48,29 +48,10 @@ class StaleLeaseEpochError(ValueError):
     fenced whom and what to do about it."""
 
 
-#: Config fields that change neither state shapes nor fold semantics —
-#: pure execution strategy, safe to flip across a resume (the pallas and
-#: lax counter paths are bit-identical, tests/test_pallas_counters.py;
-#: wire v4 and v5 fold to byte-identical state, tests/test_wire_v5.py —
-#: a v4 snapshot resumes under v5 and vice versa; compacted and
-#: uncompacted alive-pair folds are byte-identical,
-#: tests/test_alive_compaction.py).  Excluding wire_format (and
-#: alive_compaction) also keeps pre-v5 snapshots' fingerprints valid
-#: unchanged.
-_EXECUTION_ONLY_FIELDS = (
-    "use_pallas_counters",
-    "pallas_interpret",
-    "wire_format",
-    "alive_compaction",
-)
-
-
 def _fingerprint_at(
     config: AnalyzerConfig, topic: str, version: int, mesh_free: bool = False
 ) -> str:
     fields = dataclasses.asdict(config)
-    for k in _EXECUTION_ONLY_FIELDS:
-        fields.pop(k, None)
     if mesh_free:
         # Mesh-free snapshots store the CANONICAL (single-device-layout)
         # state, which every mesh can adopt — so the mesh shape is pure

@@ -234,33 +234,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "loop blocks — backpressuring ingest — beyond it. "
                         "2 overlaps the next transfer with the current "
                         "fold. Default: 2")
-    p.add_argument("--pallas", action="store_true",
-                   help="Use the Pallas MXU counter kernel for the "
-                        "per-partition counters (tpu backend; requires "
-                        "batch-size %% 1024 == 0)")
     p.add_argument("--distributed", metavar="COORD:PORT,PID,NPROCS",
                    help="Multi-host mode: initialize jax.distributed with the "
                         "given coordinator address, process id and process "
                         "count before building the mesh (collectives then "
                         "span hosts over DCN)")
-    p.add_argument("--wire-format", choices=["auto", "v4", "v5"],
-                   default="auto", metavar="auto|v4|v5",
-                   help="Packed host→device wire format: v5 (combiner rows "
-                        "— host pre-reduced per-partition fold tables, the "
-                        "default) or v4 (per-record columns). 'auto' "
-                        "resolves to v5 unless KTA_WIRE_V4 is set. Results "
-                        "are byte-identical either way; snapshots resume "
-                        "across formats")
-    p.add_argument("--alive-compaction", choices=["auto", "off"],
-                   default="auto", metavar="auto|off",
-                   help="Host-side LWW compaction of the alive-key pairs "
-                        "into one bounded per-dispatch table (wire v5 "
-                        "only; DESIGN §19). 'auto' (default) compacts "
-                        "whenever -c runs under v5; 'off' keeps the "
-                        "per-row pair sections. Results are byte-identical "
-                        "either way; KTA_DISABLE_COMPACTION is the env "
-                        "kill switch, and a bypass is booked on "
-                        "kta_alive_compaction_off_total")
     p.add_argument("--native", choices=["auto", "on", "off"], default="auto",
                    help="Use the native C++ ingest shim when available")
     p.add_argument("--profile-dir", metavar="DIR",
@@ -673,15 +651,6 @@ def resolve_dispatch(args):
     return cfg
 
 
-def resolve_wire_format(args) -> int:
-    """--wire-format → AnalyzerConfig.wire_format (shared by the single-
-    and multi-topic paths): 'auto' = 0 (config resolves to v5 unless the
-    KTA_WIRE_V4 kill switch is set), 'v4'/'v5' pin the format.  Results
-    are byte-identical either way (DESIGN.md §16) and the format is
-    outside the checkpoint fingerprint, so snapshots resume across it."""
-    return {"auto": 0, "v4": 4, "v5": 5}[getattr(args, "wire_format", "auto")]
-
-
 def _diagnose(result):
     """Scan-doctor attribution for a finished scan: computed from the
     SAME merged snapshot ``--json`` embeds (fleet-wide under
@@ -852,9 +821,6 @@ def run_multi_topic(args, topics: "list[str]") -> int:
             enable_quantiles=args.quantiles,
             quantiles_per_partition=args.quantiles_per_partition,
             mesh_shape=mesh_shape,
-            use_pallas_counters=args.pallas,
-            wire_format=resolve_wire_format(args),
-            alive_compaction=getattr(args, "alive_compaction", "auto"),
         )
         ingest_workers = resolve_ingest_workers(
             args, mesh_shape, len(multi.partitions())
@@ -1203,9 +1169,6 @@ def run_fleet(args, topics: "list[str] | None" = None) -> int:
                 enable_quantiles=args.quantiles,
                 quantiles_per_partition=args.quantiles_per_partition,
                 mesh_shape=(1, 1),
-                use_pallas_counters=args.pallas,
-                wire_format=resolve_wire_format(args),
-                alive_compaction=getattr(args, "alive_compaction", "auto"),
             )
         topic_dispatch = None
         if dispatch is not None:
@@ -1481,9 +1444,6 @@ def _run(args) -> int:
             enable_quantiles=args.quantiles,
             quantiles_per_partition=args.quantiles_per_partition,
             mesh_shape=mesh_shape,
-            use_pallas_counters=args.pallas,
-            wire_format=resolve_wire_format(args),
-            alive_compaction=getattr(args, "alive_compaction", "auto"),
         )
         ingest_workers = resolve_ingest_workers(
             args, mesh_shape, len(source.partitions())

@@ -701,64 +701,16 @@ class AnalyzerConfig:
     #: Number of log-gamma buckets (covers sizes up to gamma^nbuckets).
     quantile_buckets: int = 2560
 
-    #: Use the Pallas MXU one-hot-matmul kernel for the per-partition counter
-    #: reduction (ops/pallas_counters.py) instead of the XLA scatter-add.
-    #: Requires batch_size a multiple of 1024 (validated in __post_init__)
-    #: and value lengths < 16 MiB (pack time); partitions beyond 128 tile
-    #: the kernel grid.  Off by default until benchmarked faster on the
-    #: target hardware.  (Under wire v5 the counter fold arrives as a
-    #: pre-reduced table and this flag routes the merge through
-    #: ops/pallas_counters.pallas_counters_merge instead.)
-    use_pallas_counters: bool = False
-    #: Run those Pallas kernels through the interpreter.  Not a user
-    #: option: the backends set it from the platform of their own device
-    #: (True off the TPU, where no Mosaic kernel can compile).
-    pallas_interpret: bool = False
-
-    #: Host-side alive-pair compaction (``--alive-compaction``; DESIGN §19):
-    #: ``auto`` (the default) compacts the last-writer-wins (slot, alive)
-    #: pairs out of the per-batch wire rows into ONE bounded per-dispatch
-    #: pair table — per-batch at K=1, per-SUPERBATCH at --superbatch K>1 —
-    #: that the device merges once per dispatch instead of running the
-    #: O(B) pair scatter (and its O(W) mask apply) inside every scan step.
-    #: LWW compaction is itself LWW-associative, so results are
-    #: byte-identical to the uncompacted fold.  Resolves ON only under the
-    #: v5 combiner format with the alive bitmap enabled; ``off`` (or the
-    #: ``KTA_DISABLE_COMPACTION`` env kill switch) keeps the v5 per-row
-    #: pair sections — the bypass is booked on
-    #: ``kta_alive_compaction_off_total{reason}``, never silent.  Pure
-    #: execution strategy: byte-identical results, outside the checkpoint
-    #: fingerprint (checkpoint.py), snapshots resume across the setting.
-    alive_compaction: str = "auto"
-
-    #: Packed host→device wire format (packing.py): ``0`` = auto (resolved
-    #: at construction — v5 unless the ``KTA_WIRE_V4`` kill switch is set),
-    #: ``4`` = per-record columns + host-pre-reduced extreme/alive/HLL
-    #: sections, ``5`` = the combiner format: the remaining per-record
-    #: columns are replaced by per-partition partial-fold tables (counter
-    #: deltas, DDSketch bucket counts), so the device merges O(P·H) table
-    #: rows instead of scattering O(B) records.  Results are byte-identical
-    #: across formats (every fold is an associative integer reduction —
-    #: DESIGN.md §2/§16), so this is pure execution strategy: it is
-    #: excluded from the checkpoint fingerprint and snapshots resume across
-    #: formats (checkpoint.py).
-    wire_format: int = 0
-
-    # --- host→device transfer ----------------------------------------------
-    #: Pre-reduce bitmap updates on the host: last-writer-wins dedupe of
-    #: (slot, alive) pairs per batch (C++ shim or numpy), so the device does
-    #: two scatter-adds instead of a 1M-element sort.  The device-sort path
-    #: remains available for reference (packing always dedupes on host; the
-    #: sort kernel is exercised by its own unit tests).
     # --- parallelism --------------------------------------------------------
     #: Device mesh shape (data, space).  'data' shards record batches by
     #: partition; 'space' shards BOTH the alive-bitmap slot space and the
     #: record stream: each data row's batch is split into space_shards
     #: contiguous chunks (one per space shard, batch_size/space_shards
     #: records each), so host→device bytes and per-device reduction work
-    #: scale down with the space axis; bitmap updates are redistributed
-    #: on-device over ICI (all_gather + in-source-order application —
-    #: backends/step.py).  (1, 1) runs single-device.
+    #: scale down with the space axis; each data row's alive table is
+    #: replicated over 'space' and every space shard applies its own slot
+    #: range (backends/step.py::apply_pair_table).  (1, 1) runs
+    #: single-device.
     #: See kafka_topic_analyzer_tpu/parallel/.
     mesh_shape: Tuple[int, int] = (1, 1)
 
@@ -779,99 +731,10 @@ class AnalyzerConfig:
             raise ValueError("hll_p must be in [4, 16]")
         if self.quantile_buckets < 8:
             raise ValueError("quantile_buckets must be >= 8")
-        if self.wire_format == 0:
-            import os
-
-            # Resolved (and the reason recorded) ONCE, here: the booking
-            # property below must describe how this config actually chose
-            # v4, not whatever the env says when the engine reads it.
-            forced = bool(os.environ.get("KTA_WIRE_V4"))
-            object.__setattr__(self, "wire_format", 4 if forced else 5)
-            object.__setattr__(
-                self, "_wire_v4_reason", "env-kill-switch" if forced else None
-            )
-        elif self.wire_format in (4, 5):
-            object.__setattr__(
-                self,
-                "_wire_v4_reason",
-                "explicit" if self.wire_format == 4 else None,
-            )
-        else:
-            raise ValueError(
-                f"wire_format {self.wire_format!r} invalid (0=auto, 4, or 5)"
-            )
-        if self.alive_compaction not in ("auto", "off"):
-            raise ValueError(
-                f"alive_compaction {self.alive_compaction!r} invalid "
-                "(auto or off)"
-            )
-        # Resolve alive-pair compaction ONCE, here, with the reason it is
-        # off recorded at resolution time (same discipline as the wire-v4
-        # reason above: the engine's fallback booking must describe the
-        # decision actually taken, not whatever the env says later).
-        compact = False
-        off_reason = None
-        if self.count_alive_keys:
-            import os
-
-            if self.alive_compaction == "off":
-                off_reason = "explicit"
-            elif os.environ.get("KTA_DISABLE_COMPACTION"):
-                off_reason = "env-kill-switch"
-            elif self.wire_format != 5:
-                # The compacted pair table is a v5 combiner section; the
-                # v4 layout keeps its per-row pairs.
-                off_reason = "wire-v4"
-            else:
-                compact = True
-        object.__setattr__(self, "_compact_alive", compact)
-        object.__setattr__(self, "_alive_compaction_off_reason", off_reason)
-        if (
-            self.use_pallas_counters
-            and self.wire_format == 4
-            and self.batch_size % 1024
-        ):
-            # A constraint of the v4 MXU one-hot-matmul kernel's 1024-record
-            # blocks only: under wire v5 the counter fold arrives as a
-            # pre-reduced table and pallas_counters_merge pads any shape.
-            raise ValueError(
-                "use_pallas_counters requires batch_size % 1024 == 0"
-            )
 
     @property
     def hll_m(self) -> int:
         return 1 << self.hll_p
-
-    @property
-    def wire_v4_reason(self) -> "str | None":
-        """Why this config runs wire v4 (None when it runs v5):
-        ``env-kill-switch`` (KTA_WIRE_V4 forced the fallback at
-        construction) or ``explicit`` (the caller pinned v4).  Recorded
-        AT RESOLUTION TIME in ``__post_init__`` — not re-read from the
-        env — so the engine's ``kta_wire_v4_fallback_total`` booking
-        describes the decision actually taken (a bypassed combiner format
-        is never silent, same discipline as ``kta_fused_fallback_total``;
-        a ``dataclasses.replace`` of an env-forced config re-labels as
-        ``explicit``, which is what the copy's pinned field now is)."""
-        return self._wire_v4_reason
-
-    @property
-    def compact_alive(self) -> bool:
-        """True when this config ships alive pairs as a compacted
-        per-dispatch pair table instead of per-row sections (resolved in
-        ``__post_init__`` — see ``alive_compaction``)."""
-        return self._compact_alive
-
-    @property
-    def alive_compaction_off_reason(self) -> "str | None":
-        """Why an alive-key scan runs WITHOUT pair compaction (None when
-        compaction is on, or when the config has no alive bitmap to
-        compact): ``explicit`` (--alive-compaction off),
-        ``env-kill-switch`` (KTA_DISABLE_COMPACTION), or ``wire-v4``.
-        Recorded at resolution time like ``wire_v4_reason`` so the
-        ``kta_alive_compaction_off_total`` booking can never drift from
-        the decision taken."""
-        return self._alive_compaction_off_reason
 
     @property
     def quantile_gamma(self) -> float:

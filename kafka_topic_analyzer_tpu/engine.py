@@ -121,7 +121,7 @@ class ScanResult:
     superbatch_k: int = 1
     #: Bound on in-flight superbatch dispatches (``--dispatch-depth``).
     dispatch_depth: int = 1
-    #: Packed wire-format accounting (results.WireStats): format (v4/v5),
+    #: Packed wire-format accounting (results.WireStats): format,
     #: per-record vs fold-table byte split, and the scan's actual wire
     #: bytes — None for backends without a packed transfer (cpu oracle).
     wire: "Optional[object]" = None
@@ -255,7 +255,7 @@ def run_scan(
     suppresses the per-pass scan_start/scan_end events and the per-pass
     spinner finish line — the service emits ONE lifecycle pair for its
     whole run; ``book_once=False`` suppresses the once-per-scan fallback
-    bookings (wire-v4 / fused reasons) on every follow pass after the
+    bookings (fused reasons) on every follow pass after the
     first, so the counters record one scan, not one per poll;
     ``final_snapshot`` forces
     a snapshot after the stream drains (at a superbatch boundary, by
@@ -558,14 +558,15 @@ def run_scan(
         """A fresh per-stream sink (sinks are single-threaded state)."""
         return _make_sink(_dense_map.__getitem__)
 
-    # Wire-format accounting + the v4 fallback booking (a bypassed v5
-    # combiner is never silent — same discipline as the fused gate above).
-    # Only packed backends have a wire; the cpu oracle folds decoded
-    # batches directly.
+    # Wire-format accounting.  Only packed backends have a wire; the cpu
+    # oracle folds decoded batches directly.
     wire_stats = None
     wire_bytes0 = 0.0
     if _make_sink is not None or hasattr(backend, "update_shards"):
-        from kafka_topic_analyzer_tpu.packing import section_byte_split
+        from kafka_topic_analyzer_tpu.packing import (
+            WIRE_FORMAT,
+            section_byte_split,
+        )
         from kafka_topic_analyzer_tpu.results import WireStats
 
         wire_cfg = backend.config
@@ -578,33 +579,12 @@ def run_scan(
         )
         per_rec, table = section_byte_split(wire_cfg, wire_b)
         wire_stats = WireStats(
-            format=wire_cfg.wire_format,
+            format=WIRE_FORMAT,
             batch_size=wire_b,
             per_record_bytes=per_rec,
             table_bytes=table,
-            alive_compaction=(
-                "on"
-                if wire_cfg.compact_alive
-                else (
-                    f"off ({wire_cfg.alive_compaction_off_reason})"
-                    if wire_cfg.count_alive_keys
-                    else "n/a"
-                )
-            ),
+            alive_compaction="on" if wire_cfg.count_alive_keys else "n/a",
         )
-        v4_reason = wire_cfg.wire_v4_reason
-        if v4_reason is not None and book_once:
-            # Once per scan — and once per follow SERVICE run, not per
-            # poll pass (book_once is False on passes after the first).
-            obs_metrics.WIRE_V4_FALLBACK.labels(reason=v4_reason).inc()
-        compaction_off = wire_cfg.alive_compaction_off_reason
-        if compaction_off is not None and book_once:
-            # An alive-key scan running WITHOUT pair compaction is booked
-            # with its resolved reason — the bypass is never silent (same
-            # discipline as the wire-v4 fallback above).
-            obs_metrics.ALIVE_COMPACTION_OFF.labels(
-                reason=compaction_off
-            ).inc()
         wire_bytes0 = obs_metrics.WIRE_BYTES.value
         pairs_raw0 = obs_metrics.ALIVE_PAIRS_RAW.value
         pairs_emitted0 = obs_metrics.ALIVE_PAIRS_EMITTED.value

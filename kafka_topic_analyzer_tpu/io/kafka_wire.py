@@ -1515,7 +1515,7 @@ class KafkaWireSource(RecordSource):
     # -- the read loop (src/kafka.rs:74-137, batched) ------------------------
 
     #: The engine may hand this source a packing.FusedPackSink: accepted
-    #: record sets then decode→pack straight into wire-v4 rows (yielded as
+    #: record sets then decode→pack straight into packed rows (yielded as
     #: packing.PackedRow) instead of materializing RecordBatch columns.
     supports_fused_sink = True
 
@@ -1589,7 +1589,7 @@ class KafkaWireSource(RecordSource):
         #
         # With a fused ``sink`` installed (and the native shim loaded) the
         # pend/resplit chain is replaced wholesale: accepted record sets
-        # decode→pack straight into the sink's wire-v4 rows
+        # decode→pack straight into the sink's packed rows
         # (sink.append_record_set — no SoA columns, no re-batching copy),
         # fallback chunks (compressed/legacy/salvaged/python-decoded
         # frames) enter the SAME rows through sink.append_batch so the
@@ -2215,7 +2215,7 @@ class KafkaWireSource(RecordSource):
                     pre = soas.get(p)
                     if sink is not None and use_native_decode and data:
                         # Fused fast path: the record set's native prefix
-                        # decodes→packs straight into the sink's wire-v4
+                        # decodes→packs straight into the sink's packed
                         # rows in ONE GIL-released C++ pass — the same
                         # acceptance window and next_offset rule as
                         # accept_records, with no SoA intermediate.  The

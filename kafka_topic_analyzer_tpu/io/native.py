@@ -27,7 +27,7 @@ _NATIVE_DIR = os.path.join(
 )
 #: ABI version baked into the filename (see native/Makefile): a rebuild can
 #: never be shadowed by a stale still-mapped library at the same path.
-_ABI = 14
+_ABI = 15
 _SO_NAME = f"libkta_ingest.v{_ABI}.so"
 
 #: Env knob that disables the native shim entirely (pure-Python chain
@@ -481,26 +481,12 @@ def decode_record_set_native(
     return out, int(consumed.value), int(covered.value)
 
 
-def _pallas_value_cap(config) -> int:
-    """The 16 MiB value-length cap exists for the v4 MXU kernel's 12-bit
-    digit decomposition only; under wire v5 no per-record value length
-    reaches a pallas kernel (the counter fold ships pre-reduced), so the
-    cap must not reject v5 scans."""
-    from kafka_topic_analyzer_tpu.packing import MAX_VALUE_LEN
-
-    return (
-        MAX_VALUE_LEN
-        if config.use_pallas_counters and config.wire_format == 4
-        else 0
-    )
-
-
 def _quant_section(config) -> "tuple[int, int, np.ndarray | None]":
-    """(q_rows, q_nbuckets, edges) for the wire-v5 DDSketch section —
-    (0, 0, None) when the config ships no quantile table.  The edge array
-    is the ddsketch_edges lru-cached singleton, so the pointer handed to
-    C++ stays alive for the process lifetime."""
-    if config.wire_format != 5 or not config.enable_quantiles:
+    """(q_rows, q_nbuckets, edges) for the DDSketch section — (0, 0,
+    None) when the config ships no quantile table.  The edge array is the
+    ddsketch_edges lru-cached singleton, so the pointer handed to C++
+    stays alive for the process lifetime."""
+    if not config.enable_quantiles:
         return 0, 0, None
     from kafka_topic_analyzer_tpu.ops.ddsketch import ddsketch_edges
 
@@ -521,9 +507,10 @@ def _edges_ptr(edges: "np.ndarray | None"):
 def pack_batch_native(
     batch, config, out: "np.ndarray | None" = None
 ) -> "np.ndarray | None":
-    """Fused SoA→wire-format packing in C++ (see packing.py for the v4/v5
-    layout contracts).  Returns None when the shim rejects the batch (out
-    of range values) so the numpy path can raise its descriptive error.
+    """Fused SoA→packed-row packing in C++ (packing.py's module docstring
+    is the layout contract).  Returns None when the shim rejects the batch
+    (out of range values) so the numpy path can raise its descriptive
+    error.
     ``out`` packs into a caller-provided contiguous ``uint8[packed_nbytes]``
     buffer (e.g. a SuperbatchStager row) instead of allocating one — note
     that a rejected batch may leave partial bytes in it (the numpy
@@ -559,25 +546,13 @@ def pack_batch_native(
         _as_ptr(c(batch.key_null).view(np.uint8), ctypes.c_uint8),
         _as_ptr(c(batch.value_null).view(np.uint8), ctypes.c_uint8),
         _as_ptr(c(batch.ts_s), ctypes.c_int64),
-        _as_ptr(c(batch.key_hash32), ctypes.c_uint32),
         _as_ptr(c(batch.key_hash64), ctypes.c_uint64),
         ctypes.c_int64(batch.num_valid),
         ctypes.c_int64(b),
         ctypes.c_int32(config.num_partitions),
-        # Under pair compaction the row carries no pair sections; the
-        # caller dedupes the columns separately (packing.batch_alive_pairs)
-        # so this whole-batch packer runs with alive OFF.
-        ctypes.c_int32(
-            0
-            if getattr(config, "compact_alive", False)
-            else (1 if config.count_alive_keys else 0)
-        ),
-        ctypes.c_int32(config.alive_bitmap_bits),
         ctypes.c_int32(hll_wire_mode(config, b)),
         ctypes.c_int32(config.hll_p),
         ctypes.c_int32(hll_rows),
-        ctypes.c_int32(_pallas_value_cap(config)),
-        ctypes.c_int32(1 if config.wire_format == 5 else 0),
         ctypes.c_int32(q_rows),
         ctypes.c_int32(q_nb),
         _edges_ptr(edges),
@@ -594,7 +569,7 @@ def pack_batch_native(
 # fused decode→pack (native/ingest.cpp fused entry points)
 #
 # One GIL-released C++ pass from raw record-set bytes (or already-decoded
-# SoA columns on the fallback half) straight into a wire-v4 packed row —
+# SoA columns on the fallback half) straight into a packed row —
 # the SoA materialization between kta_decode_record_set and kta_pack_batch
 # never happens.  packing.FusedPackSink owns row/scratch lifecycle; these
 # are the thin ctypes wrappers.  Every caller keeps a reachable
@@ -603,8 +578,8 @@ def pack_batch_native(
 
 
 def _fused_pack_params(config, batch_size: int) -> "tuple":
-    """The (b, P, with_alive, alive_bits, with_hll, hll_p, hll_rows, vcap,
-    wire_v5, q_rows, q_nbuckets, edges) tail shared by the fused entry
+    """The (b, P, with_alive, alive_bits, with_hll, hll_p, hll_rows,
+    q_rows, q_nbuckets, edges) tail shared by the fused entry
     points — derived through the same packing.py rules as
     pack_batch_native, so the fused row layout can never skew from the
     chained one."""
@@ -614,13 +589,11 @@ def _fused_pack_params(config, batch_size: int) -> "tuple":
     return (
         batch_size,
         config.num_partitions,
-        _with_alive_mode(config),
+        1 if config.count_alive_keys else 0,
         config.alive_bitmap_bits,
         hll_wire_mode(config, batch_size),
         config.hll_p,
         hll_table_rows(config, batch_size),
-        _pallas_value_cap(config),
-        1 if config.wire_format == 5 else 0,
         q_rows,
         q_nb,
         edges,
@@ -628,51 +601,36 @@ def _fused_pack_params(config, batch_size: int) -> "tuple":
 
 
 def _fused_ctail(params) -> "list":
-    b, P, wa, ab, wh, hp, hr, vc, v5, qr, qn, edges = params
+    b, P, wa, ab, wh, hp, hr, qr, qn, edges = params
     return [
         ctypes.c_int64(b), ctypes.c_int32(P), ctypes.c_int32(wa),
         ctypes.c_int32(ab), ctypes.c_int32(wh), ctypes.c_int32(hp),
-        ctypes.c_int32(hr), ctypes.c_int32(vc), ctypes.c_int32(v5),
-        ctypes.c_int32(qr), ctypes.c_int32(qn), _edges_ptr(edges),
+        ctypes.c_int32(hr), ctypes.c_int32(qr), ctypes.c_int32(qn),
+        _edges_ptr(edges),
     ]
 
 
-def _with_alive_mode(config) -> int:
-    """The fused pass's alive mode: 0 = off, 1 = per-row pair sections,
-    2 = compacted (pairs divert to the scratch emission region and the
-    dispatch ships one merged pair table — AnalyzerConfig.compact_alive)."""
-    if not config.count_alive_keys:
-        return 0
-    return 2 if getattr(config, "compact_alive", False) else 1
-
-
-def _raise_pack_range(field: int, value: int) -> None:
-    """Map the fused pass's pack-range error detail onto the SAME
-    ValueError messages packing.pack_batch raises, so a scan aborts
-    identically whichever path met the out-of-range record."""
-    from kafka_topic_analyzer_tpu.packing import MAX_KEY_LEN, MAX_VALUE_LEN
+def _raise_pack_range(value: int) -> None:
+    """Map the fused pass's pack-range error detail (the offending length)
+    onto the SAME ValueError messages packing.pack_batch raises, so a scan
+    aborts identically whichever path met the out-of-range record."""
+    from kafka_topic_analyzer_tpu.packing import MAX_KEY_LEN
 
     if value < 0:
         raise ValueError("negative key/value length in record batch")
-    if field == 0:
-        raise ValueError(
-            f"key length {int(value)} exceeds the packed "
-            f"transfer limit of {MAX_KEY_LEN} bytes"
-        )
     raise ValueError(
-        f"value length {int(value)} exceeds the Pallas "
-        f"counter kernel's limit of {MAX_VALUE_LEN} bytes — disable "
-        f"use_pallas_counters for such topics"
+        f"key length {int(value)} exceeds the packed "
+        f"transfer limit of {MAX_KEY_LEN} bytes"
     )
 
 
 def pack_scratch_len(config, batch_size: int) -> int:
     """int64 elements of append scratch one fused row needs (includes the
-    compacted-pair emission region under AnalyzerConfig.compact_alive)."""
+    alive-pair emission region for alive-key scans)."""
     lib = load_library()
     n = lib.kta_pack_scratch_len(
         ctypes.c_int64(batch_size),
-        ctypes.c_int32(_with_alive_mode(config)),
+        ctypes.c_int32(1 if config.count_alive_keys else 0),
         ctypes.c_int32(config.alive_bitmap_bits),
     )
     if n < 0:
@@ -681,24 +639,16 @@ def pack_scratch_len(config, batch_size: int) -> int:
 
 
 def pack_take_pairs(
-    scratch: np.ndarray, config, batch_size: int
+    scratch: np.ndarray, batch_size: int
 ) -> "tuple[np.ndarray, np.ndarray]":
-    """Copy the current row's compacted (slot, alive) pairs out of the
-    fused scratch's emission region (with_alive mode 2).  Callers harvest
-    BEFORE the next ``pack_row_init`` re-initializes the scratch; the
-    returned arrays are copies, safe past the row's lifetime.  The region
-    sits exactly ``kta_pack_scratch_len(b, 1, bits)`` int64 elements in —
-    the with_alive == 1 length, by the shim's layout contract."""
-    lib = load_library()
+    """Copy the current row's (slot, alive) pairs out of the fused
+    scratch's emission region.  Callers harvest BEFORE the next
+    ``pack_row_init`` re-initializes the scratch; the returned arrays are
+    copies, safe past the row's lifetime.  The region is the scratch's
+    tail — its last ``ceil(5·B / 8)`` int64 elements, by the shim's
+    layout contract (``pack_scratch_len`` sizes the scratch exactly)."""
     n = int(scratch[1])
-    off = int(
-        lib.kta_pack_scratch_len(
-            ctypes.c_int64(batch_size),
-            ctypes.c_int32(1),
-            ctypes.c_int32(config.alive_bitmap_bits),
-        )
-    )
-    region = scratch[off:].view(np.uint8)
+    region = scratch[len(scratch) - (5 * batch_size + 7) // 8 :].view(np.uint8)
     slots = region[: 4 * batch_size].view(np.uint32)[:n].copy()
     flags = region[4 * batch_size : 5 * batch_size][:n].copy()
     return slots, flags
@@ -706,7 +656,7 @@ def pack_take_pairs(
 
 def pack_row_init(out: np.ndarray, scratch: np.ndarray, config,
                   batch_size: int) -> None:
-    """Initialize a wire-v4 row for incremental fused appends.  The
+    """Initialize a packed row for incremental fused appends.  The
     initialized row is byte-identical to a packed empty batch, so it
     doubles as the partial-row / superbatch identity pad."""
     lib = load_library()
@@ -743,7 +693,7 @@ def decode_pack_record_set_native(
     row_full, resume_skip)`` — on ``row_full`` the caller rotates rows and
     re-calls with ``start_pos=consumed, skip=resume_skip``.  A malformed
     frame ends the walk at its boundary (the per-frame python chain
-    classifies it from ``consumed``); a record the wire-v4 layout cannot
+    classifies it from ``consumed``); a record the packed layout cannot
     carry raises the same ValueError the numpy packer would."""
     lib = load_library()
     st = np.zeros(8, dtype=np.int64)
@@ -763,7 +713,7 @@ def decode_pack_record_set_native(
         _as_ptr(st, ctypes.c_int64),
     )
     if rc == -2:
-        _raise_pack_range(int(st[6]), int(st[7]))
+        _raise_pack_range(int(st[7]))
     if rc < 0:
         raise RuntimeError(f"kta_decode_pack_record_set failed rc={rc}")
     return (
@@ -816,7 +766,7 @@ def pack_append_columns_native(
         _as_ptr(detail, ctypes.c_int64),
     )
     if rc == -2:
-        _raise_pack_range(int(detail[0]), int(detail[1]))
+        _raise_pack_range(int(detail[1]))
     if rc < 0:
         raise RuntimeError(f"kta_pack_append_columns failed rc={rc}")
     return int(rc)

@@ -267,9 +267,9 @@ class SegmentFile:
         in place); partition and valid slice per-file read-only constants.
         The only per-batch allocation is the ms→s timestamp division — the
         one column whose stored unit differs from the batch contract.  The
-        cold path packs straight from these views (wire v4 sections copy
-        from the mapped pages exactly once, pack_batch ``out=``), so a
-        segment scan's per-record byte traffic is file page → packed row.
+        cold path packs straight from these views (pack_batch reads the
+        mapped pages exactly once, ``out=``), so a segment scan's
+        per-record byte traffic is file page → packed row.
 
         ``copy=True`` detaches every column (the pre-catalog behavior) for
         callers that must outlive or mutate the mapping.
@@ -774,7 +774,7 @@ class SegmentFileSource(RecordSource):
         return self.catalog.record_counts()
 
     #: Cold chunks can feed the fused decode→pack sink: the memmap column
-    #: views go straight into wire-v4 rows (sink.append_columns — the
+    #: views go straight into packed rows (sink.append_columns — the
     #: ms→s divide happens inside the native appender), skipping both the
     #: RecordBatch view layer and the separate pack pass.
     supports_fused_sink = True

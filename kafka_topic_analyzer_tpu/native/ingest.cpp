@@ -16,7 +16,6 @@
 // Build: `make -C native` → libkta_ingest.so (g++ -O3, pthreads).
 
 #include <algorithm>
-#include <atomic>
 #include <cstdint>
 #include <cstring>
 #include <thread>
@@ -104,7 +103,7 @@ struct KtaSynthSpec {
   int64_t ts_step_ms;
 };
 
-int32_t kta_version() { return 14; }
+int32_t kta_version() { return 15; }
 
 // CRC32-C (Castagnoli) over a byte buffer — Kafka's record-batch checksum.
 // Table-driven; the Python fallback (kafka_codec._crc32c) is a per-byte
@@ -498,264 +497,38 @@ extern "C" int64_t kta_decode_record_set(
   return n;
 }
 
-namespace {
-// Wire-v5 full-batch packer (combiner rows) — defined after the fused
-// row-layout machinery it shares with the incremental packers.
-int64_t pack_batch_v5(
-    const int32_t* partition, const int32_t* key_len, const int32_t* value_len,
-    const uint8_t* key_null, const uint8_t* value_null, const int64_t* ts_s,
-    const uint32_t* h32, const uint64_t* h64,
-    int64_t n_valid, int64_t batch_size, int32_t num_partitions,
-    int32_t with_alive, int32_t alive_bits, int32_t with_hll, int32_t hll_p,
-    int32_t hll_rows, int32_t value_len_cap, int32_t q_rows,
-    int32_t q_nbuckets, const int64_t* q_edges, uint8_t* out,
-    int64_t out_cap);
-}  // namespace
-
-// Fused batch packing: RecordBatch SoA columns -> wire-format-v4 buffer
-// (kafka_topic_analyzer_tpu/packing.py), including the host pre-reductions
-// (per-partition ts min/max table, last-writer-wins bitmap dedupe via
-// kta_dedupe_slots' table, and the HLL reduction — global register table
-// in mode 2, per-record (bucket, rho) pairs in mode 1).  One C++ pass
-// replaces several numpy conversions on the per-batch hot path.  Layout
-// contract lives in packing.py; keep in sync (HEADER 16B; sections
-// p i16[B] | klen u16[B] | vlen u32[B] | flags u8[B] | ts_minmax i64[2P] |
-// sz_minmax i64[2P] | [slot u32[B] | alive u8[B]] |
-// [hll: regs u8[rows << p] (mode 2) OR idx u16[B] | rho u8[B] (mode 1)]).
-// wire_v5 selects the combiner layout instead (packing.py wire v5): the
-// four per-record columns are replaced by a per-partition counter-delta
-// table i64[P*7] (+ an optional DDSketch bucket table i64[q_rows*(nb+2)]
-// keyed by the shared integer edge table q_edges), with_hll gains mode 3
-// (flat u32 idx = partition << p | bucket, v5's per-partition pair form).
-// Returns total bytes written, or -1 on error (including key_len > u16 /
-// partition out of i16/num_partitions range — mirrors pack_batch's
-// validation).
-extern "C" int64_t kta_pack_batch(
-    const int32_t* partition, const int32_t* key_len, const int32_t* value_len,
-    const uint8_t* key_null, const uint8_t* value_null, const int64_t* ts_s,
-    const uint32_t* h32, const uint64_t* h64,
-    int64_t n_valid, int64_t batch_size, int32_t num_partitions,
-    int32_t with_alive, int32_t alive_bits, int32_t with_hll, int32_t hll_p,
-    int32_t hll_rows,
-    int32_t value_len_cap,
-    int32_t wire_v5, int32_t q_rows, int32_t q_nbuckets,
-    const int64_t* q_edges,
-    uint8_t* out, int64_t out_cap) {
-  if (n_valid < 0 || n_valid > batch_size) return -1;
-  if (num_partitions <= 0) return -1;
-  // with_alive == 2 (pairs-to-scratch compaction) is a fused-row mode:
-  // this whole-batch packer has no scratch to emit into — the Python
-  // caller packs with alive OFF and dedupes the columns separately
-  // (packing.batch_alive_pairs).
-  if (with_alive != 0 && with_alive != 1) return -1;
-  if (wire_v5)
-    return pack_batch_v5(
-        partition, key_len, value_len, key_null, value_null, ts_s, h32, h64,
-        n_valid, batch_size, num_partitions, with_alive, alive_bits, with_hll,
-        hll_p, hll_rows, value_len_cap, q_rows, q_nbuckets, q_edges, out,
-        out_cap);
-  const int64_t b = batch_size;
-  const int64_t P = num_partitions;
-  // Wire format v4: the per-record i64 ts column is replaced by TWO [2P]
-  // per-partition min/max tables — timestamps and (tombstone-excluded)
-  // message sizes (packing.py::_sections rationale).
-  int64_t need = 16 + b * (2 + 2 + 4 + 1) + 2 * (2 * P * 8);
-  if (with_alive) need += b * 5;
-  // with_hll: 0 = off, 1 = per-record pairs, 2 = host-reduced register
-  // table of hll_rows << hll_p bytes (wire v3; rows = 1 global or P
-  // per-partition — python's packing.hll_table_rows decides).
-  if (with_hll == 1) need += b * 3;
-  if (with_hll == 2) {
-    // Per-row tables index by partition id: rows must cover every id the
-    // (validated) partition column can carry, or tbl[row << p | idx]
-    // writes past the section.
-    if (hll_rows < 1 || (hll_rows > 1 && hll_rows < num_partitions))
-      return -1;
-    need += int64_t(hll_rows) << hll_p;
-  }
-  if (need > out_cap) return -1;
-
-  std::memset(out, 0, need);
-  int64_t pos = 16;
-  // Section base pointers stay uint8_t*; elements are stored via memcpy —
-  // sections are only naturally aligned when batch_size is a multiple of 8,
-  // and typed stores through misaligned pointers are UB.
-  uint8_t* p16 = out + pos;
-  pos += b * 2;
-  uint8_t* kl16 = out + pos;
-  pos += b * 2;
-  uint8_t* vl32 = out + pos;
-  pos += b * 4;
-  uint8_t* fl8 = out + pos;
-  pos += b;
-  uint8_t* tsmm64 = out + pos;
-  pos += 2 * P * 8;
-  uint8_t* szmm64 = out + pos;
-  pos += 2 * P * 8;
-
-  auto store = [](uint8_t* base, int64_t idx, auto v) {
-    std::memcpy(base + idx * static_cast<int64_t>(sizeof(v)), &v, sizeof(v));
-  };
-
-  const int32_t vcap =
-      value_len_cap > 0 ? value_len_cap : 0x7fffffff;
-  std::atomic<bool> bad{false};
-  parallel_for(n_valid, 8, [&](int64_t a, int64_t e) {
-    for (int64_t i = a; i < e; ++i) {
-      if (partition[i] < 0 || partition[i] > 0x7fff ||
-          partition[i] >= num_partitions ||
-          key_len[i] < 0 || key_len[i] > 0xffff ||
-          value_len[i] < 0 || value_len[i] > vcap) {
-        bad.store(true);
-        return;
-      }
-      store(p16, i, static_cast<int16_t>(partition[i]));
-      store(kl16, i, static_cast<uint16_t>(key_len[i]));
-      store(vl32, i, static_cast<uint32_t>(value_len[i]));
-      fl8[i] = (key_null[i] ? 1 : 0) | (value_null[i] ? 2 : 0);
-    }
-  });
-  if (bad.load()) return -1;
-
-  {
-    // Per-partition ts min/max AND (tombstone-excluded) message-size
-    // min/max over the valid prefix: identity-filled, single sequential
-    // pass (~1-2 ns/record; not worth the thread fan-out).  Size
-    // identities are I64_MAX / 0, matching the reference's `largest`
-    // starting at 0 (src/metric.rs:34, :249-251).
-    std::vector<int64_t> mm(2 * P), sz(2 * P);
-    for (int64_t r = 0; r < P; ++r) {
-      mm[r] = INT64_MAX;
-      mm[P + r] = INT64_MIN;
-      sz[r] = INT64_MAX;
-      sz[P + r] = 0;
-    }
-    for (int64_t i = 0; i < n_valid; ++i) {
-      const int64_t r = partition[i];
-      const int64_t t = ts_s[i];
-      if (t < mm[r]) mm[r] = t;
-      if (t > mm[P + r]) mm[P + r] = t;
-      if (!value_null[i]) {
-        const int64_t size =
-            (key_null[i] ? 0 : static_cast<int64_t>(key_len[i])) +
-            static_cast<int64_t>(value_len[i]);
-        if (size < sz[r]) sz[r] = size;
-        if (size > sz[P + r]) sz[P + r] = size;
-      }
-    }
-    std::memcpy(tsmm64, mm.data(), 2 * P * 8);
-    std::memcpy(szmm64, sz.data(), 2 * P * 8);
-  }
-
-  int64_t n_pairs = 0;
-  if (with_alive) {
-    uint8_t* slot32 = out + pos;
-    pos += b * 4;
-    uint8_t* alive8 = out + pos;
-    pos += b;
-    if (n_valid > 0) {
-      // active = valid & key non-null; alive = value non-null.  Dedupe into
-      // aligned temporaries, then memcpy into the (possibly unaligned)
-      // section.  (Empty batches skip this entirely — sharded scans pack
-      // empty shard batches every step.)
-      std::vector<uint8_t> active(n_valid), alive(n_valid);
-      for (int64_t i = 0; i < n_valid; ++i) {
-        active[i] = key_null[i] ? 0 : 1;
-        alive[i] = value_null[i] ? 0 : 1;
-      }
-      std::vector<uint32_t> slots(n_valid);
-      std::vector<uint8_t> flags(n_valid);
-      n_pairs = kta_dedupe_slots(h32, active.data(), alive.data(), n_valid,
-                                 alive_bits, slots.data(), flags.data());
-      if (n_pairs < 0) return -1;
-      std::memcpy(slot32, slots.data(), n_pairs * 4);
-      std::memcpy(alive8, flags.data(), n_pairs);
-    }
-  }
-  if (with_hll == 1) {
-    uint8_t* idx16 = out + pos;
-    pos += b * 2;
-    uint8_t* rho8 = out + pos;
-    pos += b;
-    const int p = hll_p;
-    parallel_for(n_valid, 8, [&](int64_t a, int64_t e) {
-      for (int64_t i = a; i < e; ++i) {
-        if (key_null[i]) {
-          store(idx16, i, static_cast<uint16_t>(0));
-          rho8[i] = 0;
-          continue;
-        }
-        const uint64_t h = splitmix64(h64[i]);
-        store(idx16, i, static_cast<uint16_t>(h >> (64 - p)));
-        const uint64_t rest = h << p;
-        rho8[i] = rest == 0
-                      ? static_cast<uint8_t>(64 - p + 1)
-                      : static_cast<uint8_t>(__builtin_clzll(rest) + 1);
-      }
-    });
-  } else if (with_hll == 2) {
-    // Register table: scatter-max on the host's cache-resident
-    // u8[rows << p] (64 KB at p=16 global), sequential single pass — the
-    // device then merges it elementwise.  Row 0 for the global sketch;
-    // the record's partition row when per-partition registers fit the
-    // table budget.  (The memset above already zeroed it.)
-    uint8_t* tbl = out + pos;
-    const int p = hll_p;
-    const bool per_row = hll_rows > 1;
-    pos += int64_t(hll_rows) << p;
-    for (int64_t i = 0; i < n_valid; ++i) {
-      if (key_null[i]) continue;
-      const uint64_t h = splitmix64(h64[i]);
-      const int64_t row = per_row ? partition[i] : 0;
-      const int64_t idx = (row << p) | static_cast<int64_t>(h >> (64 - p));
-      const uint64_t rest = h << p;
-      const uint8_t rho =
-          rest == 0 ? static_cast<uint8_t>(64 - p + 1)
-                    : static_cast<uint8_t>(__builtin_clzll(rest) + 1);
-      if (rho > tbl[idx]) tbl[idx] = rho;
-    }
-  }
-
-  // Header: n_valid i32 | n_pairs i32 | reserved.
-  const int32_t hv = static_cast<int32_t>(n_valid);
-  const int32_t hp = static_cast<int32_t>(n_pairs);
-  std::memcpy(out, &hv, 4);
-  std::memcpy(out + 4, &hp, 4);
-  return need;
-}
-
 // ---------------------------------------------------------------------------
-// Fused decode→pack: one pass from raw fetch bytes to wire-v4 packed rows.
+// Fused decode→pack: one pass from raw fetch bytes to packed rows.
 //
 // The chained hot path is kta_decode_record_set (wire bytes → eight SoA
-// columns) followed by kta_pack_batch (columns → wire-v4 buffer): every
+// columns) followed by kta_pack_batch (columns → packed row): every
 // record's metadata is written to memory once and read back once purely to
-// move between the two calls.  The fused entry points below append records
-// STRAIGHT into a caller-supplied wire-v4 row as they are decoded — the SoA
+// move between the two calls.  The fused entry points below fold records
+// STRAIGHT into a caller-supplied packed row as they are decoded — the SoA
 // intermediate never exists.  Because a row outlives a single call (record
 // sets are smaller or larger than one batch), append state persists in a
 // caller-owned int64 scratch:
 //
 //   scratch[0] = n        records appended to the row so far (the cursor;
 //                         becomes header n_valid)
-//   scratch[1] = n_pairs  alive-dedupe pairs emitted (header n_pairs)
+//   scratch[1] = n_pairs  alive-dedupe pairs emitted to the scratch's pair
+//                         emission region (alive scans only)
 //   scratch[2] = cap      dedupe table capacity (0 when alive is off)
 //   scratch[3..3+cap)     open-addressing LWW table (pair index + 1; 0 empty)
 //
 // The dedupe table persisting across appends is what makes incremental
 // packing exact: output pair ORDER is first-occurrence record order —
-// independent of table capacity — so a row built by many appends is
-// byte-identical to kta_pack_batch over the same records (asserted by
-// tests/test_fused.py).
+// independent of table capacity — so the pairs of a row built by many
+// appends equal kta_dedupe_slots over the same records, and the row
+// itself equals kta_pack_batch's (asserted by tests/test_fused.py).
 //
 // Error contract (mirrors the taxonomy io/native.py documents):
 //   >= 0  records appended
 //   -1    bad arguments / layout mismatch
-//   -2    pack-range violation (a decoded value the wire-v4 layout cannot
-//         carry: key_len > u16, value_len > cap, partition out of range) —
-//         detail[0] = field code (0 klen / 1 vlen / 2 partition),
-//         detail[1] = offending value.  The Python wrapper re-raises the
-//         same ValueError the numpy packer would.
+//   -2    pack-range violation (a decoded value the packed layout rejects:
+//         key_len > u16, a negative column length) — detail[0] = field
+//         code (0 klen / 1 vlen), detail[1] = offending value.  The Python
+//         wrapper re-raises the same ValueError the numpy packer would.
 // A *malformed frame* is NOT an error here: the walk stops at the frame
 // boundary exactly like kta_scan_record_set, and the caller's per-frame
 // Python chain classifies it precisely (CorruptFrameError taxonomy).
@@ -765,56 +538,47 @@ extern "C" int64_t kta_pack_batch(
 
 namespace {
 
+// Section layout of one packed row (packing.py module docstring; HEADER
+// 16B | counts i64[P*7] | ts_minmax i64[2P] | sz_minmax i64[2P] |
+// [hll: regs u8[rows << p] (mode 2) OR idx u16[B] | rho u8[B] (mode 1) OR
+// idx u32[B] | rho u8[B] (mode 3)] | [quant i64[q_rows * (nb + 2)]]).
 struct PackRowLayout {
   int64_t b;
   int64_t P;
-  int32_t with_alive;
+  int32_t with_alive;  // 1: LWW pairs to the scratch emission region
   int32_t alive_bits;
   int32_t with_hll;  // 0 off, 1 u16 pairs, 2 register table, 3 u32 flat
-                     // pairs (v5: partition << p | bucket)
+                     // pairs (partition << p | bucket)
   int32_t hll_p;
   int32_t hll_rows;
-  int32_t vcap;
-  int32_t wire_v5;   // combiner layout: counts table replaces the columns
-  int32_t q_rows;    // DDSketch rows (0 = no quant section; v5 only)
+  int32_t q_rows;    // DDSketch rows (0 = no quant section)
   int32_t q_nbuckets;            // log buckets (section adds +2)
   const int64_t* q_edges;        // shared integer bucket edge table
   int64_t need;
   // Section base pointers (uint8_t*: sections are only naturally aligned
   // when batch_size is a multiple of 8 — all element access via memcpy).
-  uint8_t *p16, *kl16, *vl32, *fl8, *tsmm, *szmm;
-  uint8_t *cnt64;          // v5: i64[P * 7] counter deltas
-  uint8_t *slot32, *alive8;
+  uint8_t *cnt64, *tsmm, *szmm;  // i64[P * 7] counter deltas, extremes
+  uint8_t *slot32, *alive8;      // scratch pair emission region
   uint8_t *hll_a, *hll_b;  // idx/rho (modes 1/3) or regs/- (mode 2)
-  uint8_t *q64;            // v5: i64[q_rows * (q_nbuckets + 2)]
+  uint8_t *q64;            // i64[q_rows * (q_nbuckets + 2)]
 };
 
 inline bool pack_row_layout(uint8_t* out, int64_t out_cap, int64_t b,
                             int32_t P, int32_t with_alive, int32_t alive_bits,
                             int32_t with_hll, int32_t hll_p, int32_t hll_rows,
-                            int32_t value_len_cap, int32_t wire_v5,
                             int32_t q_rows, int32_t q_nbuckets,
                             const int64_t* q_edges, PackRowLayout* r) {
   if (!out || b < 0 || P <= 0 || P > 0x7fff) return false;
-  if (with_alive < 0 || with_alive > 2) return false;
+  if (with_alive < 0 || with_alive > 1) return false;
   if (with_alive && (alive_bits < 1 || alive_bits > 32)) return false;
-  if (with_alive == 2 && !wire_v5) return false;  // compaction is v5-only
-  if (with_hll == 3 && !wire_v5) return false;  // flat pairs are v5-only
-  if (q_rows > 0 && (!wire_v5 || !q_edges || q_nbuckets < 1)) return false;
+  if (q_rows > 0 && (!q_edges || q_nbuckets < 1)) return false;
   if (q_rows > 1 && q_rows < P) return false;  // rows index by partition
-  int64_t need = 16 + 2 * (2 * int64_t(P) * 8);
-  if (wire_v5)
-    need += int64_t(P) * 7 * 8;
-  else
-    need += b * (2 + 2 + 4 + 1);
-  // with_alive == 2 (compaction): the dedupe table still runs, but the
-  // pairs divert to a caller-scratch region (attach_scratch_pairs) and
-  // the row carries NO pair sections — the dispatch-level merged pair
-  // table ships them instead (packing.pack_pair_table).
-  if (with_alive == 1) need += b * 5;
+  int64_t need = 16 + 2 * (2 * int64_t(P) * 8) + int64_t(P) * 7 * 8;
   if (with_hll == 1) need += b * 3;
   if (with_hll == 3) need += b * 5;
   if (with_hll == 2) {
+    // Per-row tables index by partition id: rows must cover every id, or
+    // tbl[row << p | idx] writes past the section.
     if (hll_rows < 1 || (hll_rows > 1 && hll_rows < P)) return false;
     need += int64_t(hll_rows) << hll_p;
   }
@@ -827,38 +591,18 @@ inline bool pack_row_layout(uint8_t* out, int64_t out_cap, int64_t b,
   r->with_hll = with_hll;
   r->hll_p = hll_p;
   r->hll_rows = hll_rows;
-  r->vcap = value_len_cap > 0 ? value_len_cap : 0x7fffffff;
-  r->wire_v5 = wire_v5;
   r->q_rows = q_rows;
   r->q_nbuckets = q_nbuckets;
   r->q_edges = q_edges;
   r->need = need;
   int64_t pos = 16;
-  r->p16 = r->kl16 = r->vl32 = r->fl8 = r->cnt64 = nullptr;
-  if (wire_v5) {
-    r->cnt64 = out + pos;
-    pos += int64_t(P) * 7 * 8;
-  } else {
-    r->p16 = out + pos;
-    pos += b * 2;
-    r->kl16 = out + pos;
-    pos += b * 2;
-    r->vl32 = out + pos;
-    pos += b * 4;
-    r->fl8 = out + pos;
-    pos += b;
-  }
+  r->cnt64 = out + pos;
+  pos += int64_t(P) * 7 * 8;
   r->tsmm = out + pos;
   pos += 2 * P * 8;
   r->szmm = out + pos;
   pos += 2 * P * 8;
   r->slot32 = r->alive8 = nullptr;
-  if (with_alive == 1) {
-    r->slot32 = out + pos;
-    pos += b * 4;
-    r->alive8 = out + pos;
-    pos += b;
-  }
   r->hll_a = r->hll_b = nullptr;
   if (with_hll == 1) {
     r->hll_a = out + pos;  // idx u16[B]
@@ -918,7 +662,7 @@ inline T load_at(const uint8_t* base, int64_t idx) {
 
 // Compact per-frame stash of the reduction inputs, carved out of the
 // caller scratch after the dedupe table: hashes + aliveness for ACTIVE
-// (non-null key) records, and — wire v5 with quantiles — the message
+// (non-null key) records, and — with quantiles — the message
 // sizes of SIZED (non-tombstone) records for the DDSketch bucket pass.
 struct FrameStash {
   uint64_t* h64;
@@ -952,10 +696,10 @@ inline int64_t pack_stash_len64(int64_t b, int32_t with_alive,
   return (21 * b + 7) / 8;
 }
 
-// Compacted-pair emission region (with_alive == 2): slots u32[b] + flags
-// u8[b] carved out of the caller scratch PAST the full (unconditional)
-// stash, so Python locates it as kta_pack_scratch_len(b, 1, bits) int64
-// elements in — independent of which stash sections the config uses.
+// Pair emission region (alive scans): slots u32[b] + flags u8[b] carved
+// out of the caller scratch PAST the full (unconditional) stash — the
+// scratch's last pairs_len64(b) int64 elements, which is where Python
+// reads it (io/native.py::pack_take_pairs).
 inline int64_t pairs_off64(int64_t b, int32_t alive_bits) {
   return 3 + pack_scratch_cap(b, 1, alive_bits) +
          pack_stash_len64(b, 1, 2, 1);
@@ -964,7 +708,7 @@ inline int64_t pairs_off64(int64_t b, int32_t alive_bits) {
 inline int64_t pairs_len64(int64_t b) { return (5 * b + 7) / 8; }
 
 inline void attach_scratch_pairs(PackRowLayout* r, int64_t* scratch) {
-  if (r->with_alive != 2) return;
+  if (!r->with_alive) return;
   uint8_t* pb =
       reinterpret_cast<uint8_t*>(scratch + pairs_off64(r->b, r->alive_bits));
   r->slot32 = pb;
@@ -1048,10 +792,10 @@ inline void hll_table_pass(const PackRowLayout& r, int32_t dense_p,
   }
 }
 
-// Wire v5: fold one single-partition frame's counter registers into the
-// row's i64[P, 7] delta table — ONE 7-entry RMW per frame/append, the
-// combiner's whole per-frame cost for the channels that used to ship as
-// four per-record columns.  Channel order = results.COUNTER_CHANNELS.
+// Fold one single-partition frame's counter registers into the row's
+// i64[P, 7] delta table — ONE 7-entry RMW per frame/append, the
+// combiner's whole per-frame cost for the counters.  Channel order =
+// results.COUNTER_CHANNELS.
 inline void commit_counts(const PackRowLayout& r, int32_t dense_p,
                           int64_t total, int64_t tomb, int64_t knull,
                           int64_t ksum, int64_t vsum) {
@@ -1063,7 +807,7 @@ inline void commit_counts(const PackRowLayout& r, int32_t dense_p,
                       load_at<int64_t>(r.cnt64, base + c) + vals[c]);
 }
 
-// Wire v5 DDSketch pass: bucket the stashed message sizes through the
+// DDSketch pass: bucket the stashed message sizes through the
 // shared integer edge table (ops/ddsketch.py::ddsketch_edges — binary
 // search == numpy searchsorted side='left') into the row's per-row
 // bucket-count table.  Runs after the frame parses, like every reduction.
@@ -1109,15 +853,8 @@ inline void rewind_appends(const PackRowLayout& r, int64_t* scr,
   const int64_t n = scr[0];
   if (n <= cursor0) return;
   const int64_t c = n - cursor0;
-  if (!r.wire_v5) {
-    // v5 has no per-record column sections — its counter/quantile
-    // reductions only commit after the frame parses, so the cursor reset
-    // below is the whole rewind for them.
-    std::memset(r.p16 + 2 * cursor0, 0, size_t(2 * c));
-    std::memset(r.kl16 + 2 * cursor0, 0, size_t(2 * c));
-    std::memset(r.vl32 + 4 * cursor0, 0, size_t(4 * c));
-    std::memset(r.fl8 + cursor0, 0, size_t(c));
-  }
+  // The counter/quantile tables only commit after the frame parses, so
+  // the HLL pair sections and the cursor are all there is to rewind.
   if (r.with_hll == 1) {
     std::memset(r.hll_a + 2 * cursor0, 0, size_t(2 * c));
     std::memset(r.hll_b + cursor0, 0, size_t(c));
@@ -1130,7 +867,7 @@ inline void rewind_appends(const PackRowLayout& r, int64_t* scr,
 
 // Store-free validation of one v2 frame's records: every record must parse
 // inside its bounds, and every record IN the acceptance window must fit
-// the wire-v4 ranges, so the append pass can never fail mid-frame.
+// the packed key-length range, so the append pass can never fail mid-frame.
 // (Out-of-window records are never packed — the chained path filters them
 // before pack_batch ever sees them, so a range violation there must not
 // abort the fused scan either.)  Returns 0 ok, 1 malformed (caller stops
@@ -1138,8 +875,7 @@ inline void rewind_appends(const PackRowLayout& r, int64_t* scr,
 // violation (detail filled; the whole call errors like the numpy packer
 // would).
 inline int validate_frame_records(const uint8_t* payload, int64_t plen,
-                                  int32_t nrec, int32_t vcap,
-                                  int64_t base_offset, int64_t min_off,
+                                  int32_t nrec, int64_t base_offset, int64_t min_off,
                                   int64_t max_off, int64_t* detail) {
   int64_t pos = 0;
   for (int32_t i = 0; i < nrec; ++i) {
@@ -1163,17 +899,10 @@ inline int validate_frame_records(const uint8_t* payload, int64_t plen,
       pos += vlen;
     }
     const int64_t off = base_offset + off_delta;
-    if (off >= min_off && off < max_off) {
-      if (klen > 0xffff) {
-        detail[0] = 0;
-        detail[1] = klen;
-        return 2;
-      }
-      if (vlen > vcap) {
-        detail[0] = 1;
-        detail[1] = vlen;
-        return 2;
-      }
+    if (off >= min_off && off < max_off && klen > 0xffff) {
+      detail[0] = 0;
+      detail[1] = klen;
+      return 2;
     }
     int64_t nheaders;
     if (!read_zigzag(payload, rec_end, pos, nheaders)) return 1;
@@ -1194,25 +923,34 @@ inline int validate_frame_records(const uint8_t* payload, int64_t plen,
   return 0;
 }
 
-// Wire-v5 full-batch packer (the chained path's combiner form): one
-// sequential pass folds the SoA columns into the per-partition tables —
-// counter deltas, ts/size extremes, DDSketch buckets — with the same
-// validation kta_pack_batch's v4 branch applies.  Multi-partition batches
-// are fine here (unlike the fused single-partition appends): every table
-// indexes by the record's own partition.
-int64_t pack_batch_v5(
+}  // namespace
+
+extern "C" {
+
+// Whole-batch packing (the chained path): RecordBatch SoA columns -> one
+// packed row (packing.py module docstring).  One sequential pass folds the
+// columns into the per-partition tables — counter deltas, ts/size extremes,
+// DDSketch buckets keyed by the shared integer edge table q_edges — and the
+// HLL section (with_hll: 1 u16 pairs, 2 register table of hll_rows << p
+// bytes, 3 u32 flat pairs; python's packing.hll_wire_mode decides).
+// Multi-partition batches are fine here (unlike the fused single-partition
+// appends): every table indexes by the record's own partition.  Alive
+// pairs are not part of the row (packing.batch_alive_pairs dedupes them).
+// Returns total bytes written, or -1 on error (including key_len > u16 /
+// partition out of i16/num_partitions range — mirrors pack_batch's
+// validation).
+int64_t kta_pack_batch(
     const int32_t* partition, const int32_t* key_len, const int32_t* value_len,
     const uint8_t* key_null, const uint8_t* value_null, const int64_t* ts_s,
-    const uint32_t* h32, const uint64_t* h64,
-    int64_t n_valid, int64_t batch_size, int32_t num_partitions,
-    int32_t with_alive, int32_t alive_bits, int32_t with_hll, int32_t hll_p,
-    int32_t hll_rows, int32_t value_len_cap, int32_t q_rows,
-    int32_t q_nbuckets, const int64_t* q_edges, uint8_t* out,
-    int64_t out_cap) {
+    const uint64_t* h64, int64_t n_valid, int64_t batch_size,
+    int32_t num_partitions, int32_t with_hll, int32_t hll_p,
+    int32_t hll_rows, int32_t q_rows, int32_t q_nbuckets,
+    const int64_t* q_edges, uint8_t* out, int64_t out_cap) {
   PackRowLayout r;
-  if (!pack_row_layout(out, out_cap, batch_size, num_partitions, with_alive,
-                       alive_bits, with_hll, hll_p, hll_rows, value_len_cap,
-                       1, q_rows, q_nbuckets, q_edges, &r))
+  if (n_valid < 0 || n_valid > batch_size ||
+      !pack_row_layout(out, out_cap, batch_size, num_partitions, 0, 0,
+                       with_hll, hll_p, hll_rows, q_rows, q_nbuckets,
+                       q_edges, &r))
     return -1;
   const int64_t P = num_partitions;
   std::memset(out, 0, r.need);
@@ -1231,8 +969,7 @@ int64_t pack_batch_v5(
   for (int64_t i = 0; i < n_valid; ++i) {
     const int32_t p = partition[i];
     if (p < 0 || p > 0x7fff || p >= num_partitions ||
-        key_len[i] < 0 || key_len[i] > 0xffff ||
-        value_len[i] < 0 || value_len[i] > r.vcap)
+        key_len[i] < 0 || key_len[i] > 0xffff || value_len[i] < 0)
       return -1;
     const bool kn = !key_null[i];
     const bool vn = !value_null[i];
@@ -1266,23 +1003,6 @@ int64_t pack_batch_v5(
   std::memcpy(r.szmm, sz.data(), size_t(2 * P) * 8);
   if (q_rows > 0) std::memcpy(r.q64, qt.data(), qt.size() * 8);
 
-  int64_t n_pairs = 0;
-  if (with_alive && n_valid > 0) {
-    // Same pre-reduction as the v4 branch: LWW dedupe into aligned
-    // temporaries, then memcpy into the (possibly unaligned) section.
-    std::vector<uint8_t> active(n_valid), alive(n_valid);
-    for (int64_t i = 0; i < n_valid; ++i) {
-      active[i] = key_null[i] ? 0 : 1;
-      alive[i] = value_null[i] ? 0 : 1;
-    }
-    std::vector<uint32_t> slots(n_valid);
-    std::vector<uint8_t> flags(n_valid);
-    n_pairs = kta_dedupe_slots(h32, active.data(), alive.data(), n_valid,
-                               alive_bits, slots.data(), flags.data());
-    if (n_pairs < 0) return -1;
-    std::memcpy(r.slot32, slots.data(), size_t(n_pairs) * 4);
-    std::memcpy(r.alive8, flags.data(), size_t(n_pairs));
-  }
   if (with_hll == 1 || with_hll == 3) {
     for (int64_t i = 0; i < n_valid; ++i) {
       if (key_null[i]) {
@@ -1323,15 +1043,9 @@ int64_t pack_batch_v5(
   }
 
   const int32_t hv = static_cast<int32_t>(n_valid);
-  const int32_t hp = static_cast<int32_t>(n_pairs);
   std::memcpy(out, &hv, 4);
-  std::memcpy(out + 4, &hp, 4);
   return r.need;
 }
-
-}  // namespace
-
-extern "C" {
 
 // Scratch length (int64 elements) a pack row needs: counters + the
 // persistent dedupe table + the per-frame reduction stash.
@@ -1339,42 +1053,39 @@ int64_t kta_pack_scratch_len(int64_t batch_size, int32_t with_alive,
                              int32_t alive_bits) {
   if (batch_size < 0) return -1;
   // The stash region is sized unconditionally (it also serves HLL table
-  // mode with alive off, and wire v5's size stash) — a few MB at worst,
-  // allocated once per sink.  with_alive == 2 (pair compaction) appends
-  // the pair emission region; its offset is exactly the with_alive == 1
-  // return value, which is how the Python side locates it.
+  // mode with alive off, and the quantile size stash) — a few MB at worst,
+  // allocated once per sink.  Alive scans append the pair emission region
+  // as the scratch's tail.
   int64_t n = 3 + pack_scratch_cap(batch_size, with_alive, alive_bits) +
               pack_stash_len64(batch_size, 1, 2, 1);
-  if (with_alive == 2) n += pairs_len64(batch_size);
+  if (with_alive) n += pairs_len64(batch_size);
   return n;
 }
 
-// Initialize one wire row (v4 or v5) for incremental appends: zero the
-// buffer, identity-fill the extreme tables, reset the scratch.  An
-// initialized, never-appended row is byte-identical to a packed EMPTY
-// batch (the superbatch identity pad), so partial-row padding is just
-// init — under v5 the zeroed counter/quantile tables ARE the fold
-// identity.  Returns the row's total bytes (== packing.packed_nbytes)
-// or -1.
+// Initialize one packed row for incremental appends: zero the buffer,
+// identity-fill the extreme tables, reset the scratch.  An initialized,
+// never-appended row is byte-identical to a packed EMPTY batch (the
+// superbatch identity pad), so partial-row padding is just init — the
+// zeroed counter/quantile tables ARE the fold identity.  Returns the row's
+// total bytes (== packing.packed_nbytes) or -1.
 int64_t kta_pack_row_init(uint8_t* out, int64_t out_cap, int64_t* scratch,
                           int64_t scratch_len, int64_t batch_size,
                           int32_t num_partitions, int32_t with_alive,
                           int32_t alive_bits, int32_t with_hll,
                           int32_t hll_p, int32_t hll_rows,
-                          int32_t value_len_cap, int32_t wire_v5,
                           int32_t q_rows, int32_t q_nbuckets,
                           const int64_t* q_edges) {
   PackRowLayout r;
   if (!scratch ||
       !pack_row_layout(out, out_cap, batch_size, num_partitions, with_alive,
-                       alive_bits, with_hll, hll_p, hll_rows, value_len_cap,
-                       wire_v5, q_rows, q_nbuckets, q_edges, &r))
+                       alive_bits, with_hll, hll_p, hll_rows, q_rows,
+                       q_nbuckets, q_edges, &r))
     return -1;
   const int64_t cap = pack_scratch_cap(batch_size, with_alive, alive_bits);
   if (scratch_len < 3 + cap + pack_stash_len64(batch_size, with_alive,
                                                with_hll, q_rows))
     return -1;
-  if (with_alive == 2 &&
+  if (with_alive &&
       scratch_len < pairs_off64(batch_size, alive_bits) +
                         pairs_len64(batch_size))
     return -1;
@@ -1454,16 +1165,15 @@ int64_t kta_decode_pack_record_set(
     int64_t min_off, int64_t max_off, int32_t dense_partition,
     int64_t batch_size, int32_t num_partitions, int32_t with_alive,
     int32_t alive_bits, int32_t with_hll, int32_t hll_p, int32_t hll_rows,
-    int32_t value_len_cap, int32_t wire_v5, int32_t q_rows,
-    int32_t q_nbuckets, const int64_t* q_edges, uint8_t* out,
+    int32_t q_rows, int32_t q_nbuckets, const int64_t* q_edges, uint8_t* out,
     int64_t out_cap, int64_t* scratch, int64_t* st) {
   PackRowLayout r;
   if (!buf || len < 0 || !st || !scratch || start_pos < 0 ||
       start_pos > len || dense_partition < 0 ||
       dense_partition >= num_partitions ||
       !pack_row_layout(out, out_cap, batch_size, num_partitions, with_alive,
-                       alive_bits, with_hll, hll_p, hll_rows, value_len_cap,
-                       wire_v5, q_rows, q_nbuckets, q_edges, &r))
+                       alive_bits, with_hll, hll_p, hll_rows, q_rows,
+                       q_nbuckets, q_edges, &r))
     return -1;
   attach_scratch_pairs(&r, scratch);
   const bool need_stash = with_alive || with_hll == 2;
@@ -1490,8 +1200,8 @@ int64_t kta_decode_pack_record_set(
       // leave a committed partial frame behind.  Boundary-only cost —
       // at most one frame per row takes this double walk.
       const int v = validate_frame_records(payload, plen, fh.num_records,
-                                           r.vcap, fh.base_offset, min_off,
-                                           max_off, st + 6);
+                                           fh.base_offset, min_off, max_off,
+                                           st + 6);
       if (v == 2) return -2;
       if (v != 0) break;
     }
@@ -1503,8 +1213,8 @@ int64_t kta_decode_pack_record_set(
     stash.nsz = 0;
     int64_t ts_min = INT64_MAX, ts_max = INT64_MIN;
     int64_t sz_min = INT64_MAX, sz_max = 0;
-    // Wire v5: per-frame counter registers (single-partition frames fold
-    // to ONE 7-entry table commit — commit_counts).
+    // Per-frame counter registers (single-partition frames fold to ONE
+    // 7-entry table commit — commit_counts).
     int64_t f_tomb = 0, f_knull = 0, f_ksum = 0, f_vsum = 0;
     int64_t f_last_off = -1, f_last_ts = 0, f_appended = 0;
     int64_t rpos = 0;
@@ -1588,12 +1298,6 @@ int64_t kta_decode_pack_record_set(
         st[7] = klen;
         return -2;
       }
-      if (vlen > r.vcap) {
-        rewind_appends(r, scratch, cursor0);
-        st[6] = 1;
-        st[7] = vlen;
-        return -2;
-      }
       if (scratch[0] >= r.b) {
         full = true;
         break;
@@ -1601,21 +1305,12 @@ int64_t kta_decode_pack_record_set(
       const bool key_null = klen < 0;
       const bool value_null = vlen < 0;
       const int64_t n = scratch[0];
-      if (r.wire_v5) {
-        // Combiner rows: no per-record columns — accumulate the frame's
-        // counter registers instead (committed once per frame below).
-        if (value_null) ++f_tomb;
-        if (key_null) ++f_knull;
-        if (!key_null) f_ksum += klen;
-        if (!value_null) f_vsum += vlen;
-      } else {
-        store_at<int16_t>(r.p16, n, static_cast<int16_t>(dense_partition));
-        store_at<uint16_t>(r.kl16, n,
-                           static_cast<uint16_t>(key_null ? 0 : klen));
-        store_at<uint32_t>(r.vl32, n,
-                           static_cast<uint32_t>(value_null ? 0 : vlen));
-        r.fl8[n] = (key_null ? 1 : 0) | (value_null ? 2 : 0);
-      }
+      // No per-record columns: accumulate the frame's counter registers
+      // (committed once per frame below).
+      if (value_null) ++f_tomb;
+      if (key_null) ++f_knull;
+      if (!key_null) f_ksum += klen;
+      if (!value_null) f_vsum += vlen;
       const int64_t ts_ms = fh.first_ts + ts_delta;
       const int64_t ts_s = ts_ms < 0 ? 0 : ts_ms / 1000;
       if (ts_s < ts_min) ts_min = ts_s;
@@ -1651,7 +1346,7 @@ int64_t kta_decode_pack_record_set(
           if (r.with_hll == 1)
             store_at<uint16_t>(r.hll_a, n, static_cast<uint16_t>(bucket));
           else
-            // v5 flat pairs: the register row rides inside the index.
+            // Flat pairs: the register row rides inside the index.
             store_at<uint32_t>(
                 r.hll_a, n,
                 (static_cast<uint32_t>(dense_partition) << r.hll_p) | bucket);
@@ -1682,11 +1377,9 @@ int64_t kta_decode_pack_record_set(
                                   stash.n);
       if (r.with_hll == 2) hll_table_pass(r, dense_partition, stash.h64,
                                           stash.n);
-      if (r.wire_v5) {
-        commit_counts(r, dense_partition, f_appended, f_tomb, f_knull,
-                      f_ksum, f_vsum);
-        if (r.q64) quant_pass(r, dense_partition, stash.size, stash.nsz);
-      }
+      commit_counts(r, dense_partition, f_appended, f_tomb, f_knull,
+                    f_ksum, f_vsum);
+      if (r.q64) quant_pass(r, dense_partition, stash.size, stash.nsz);
       appended += f_appended;
       last_off = f_last_off;
       last_ts = f_last_ts;
@@ -1705,14 +1398,10 @@ int64_t kta_decode_pack_record_set(
   st[2] = last_off;
   st[3] = last_ts;
   if (!st[5]) st[4] = 0;
-  // Live header: the row is a valid packed batch after every call.
-  // Under pair compaction the row has no pair sections — its header says
-  // n_pairs 0 (the pairs ride the dispatch-level merged table instead).
+  // Live header: the row is a valid packed batch after every call (its
+  // n_pairs stays 0 from init — the pairs ride the dispatch's table).
   const int32_t hv = static_cast<int32_t>(scratch[0]);
-  const int32_t hp =
-      with_alive == 2 ? 0 : static_cast<int32_t>(scratch[1]);
   std::memcpy(out, &hv, 4);
-  std::memcpy(out + 4, &hp, 4);
   return appended;
 }
 
@@ -1734,16 +1423,16 @@ int64_t kta_pack_append_columns(
     const uint32_t* h32, const uint64_t* h64, int64_t start, int64_t n,
     int64_t batch_size, int32_t num_partitions, int32_t with_alive,
     int32_t alive_bits, int32_t with_hll, int32_t hll_p, int32_t hll_rows,
-    int32_t value_len_cap, int32_t wire_v5, int32_t q_rows,
-    int32_t q_nbuckets, const int64_t* q_edges, int64_t* detail) {
+    int32_t q_rows, int32_t q_nbuckets, const int64_t* q_edges,
+    int64_t* detail) {
   PackRowLayout r;
   if (!key_len || !value_len || !key_null || !value_null || !ts || !h32 ||
       !h64 || !scratch || !detail || start < 0 || n < 0 || start > n ||
       dense_partition < 0 || dense_partition >= num_partitions ||
       dense_partition > 0x7fff || ts_mode < 0 || ts_mode > 2 ||
       !pack_row_layout(out, out_cap, batch_size, num_partitions, with_alive,
-                       alive_bits, with_hll, hll_p, hll_rows, value_len_cap,
-                       wire_v5, q_rows, q_nbuckets, q_edges, &r))
+                       alive_bits, with_hll, hll_p, hll_rows, q_rows,
+                       q_nbuckets, q_edges, &r))
     return -1;
   attach_scratch_pairs(&r, scratch);
   int64_t take = n - start;
@@ -1760,45 +1449,27 @@ int64_t kta_pack_append_columns(
       detail[1] = key_len[i];
       return -2;
     }
-    if (value_len[i] < 0 || value_len[i] > r.vcap) {
+    if (value_len[i] < 0) {
       detail[0] = 1;
       detail[1] = value_len[i];
       return -2;
     }
   }
   const int64_t c0 = scratch[0];
-  if (r.wire_v5) {
-    // Combiner rows: fold the columns straight into the frame registers
-    // (one commit_counts below) — no per-record column sections exist.
-    int64_t f_tomb = 0, f_knull = 0, f_ksum = 0, f_vsum = 0;
-    for (int64_t i = lo; i < hi; ++i) {
-      if (value_null[i]) ++f_tomb;
-      if (key_null[i]) ++f_knull;
-      if (!key_null[i]) f_ksum += key_len[i];
-      if (!value_null[i]) f_vsum += value_len[i];
-    }
-    if (take)
-      commit_counts(r, dense_partition, take, f_tomb, f_knull, f_ksum,
-                    f_vsum);
-  } else {
-    // Columnar section stores (klen/vlen stored VERBATIM, like
-    // kta_pack_batch — sources write 0 for null keys/tombstones but the
-    // layout carries whatever the column said).
-    for (int64_t i = lo; i < hi; ++i)
-      store_at<int16_t>(r.p16, c0 + (i - lo),
-                        static_cast<int16_t>(dense_partition));
-    for (int64_t i = lo; i < hi; ++i)
-      store_at<uint16_t>(r.kl16, c0 + (i - lo),
-                         static_cast<uint16_t>(key_len[i]));
-    for (int64_t i = lo; i < hi; ++i)
-      store_at<uint32_t>(r.vl32, c0 + (i - lo),
-                         static_cast<uint32_t>(value_len[i]));
-    for (int64_t i = lo; i < hi; ++i)
-      r.fl8[c0 + (i - lo)] =
-          (key_null[i] ? 1 : 0) | (value_null[i] ? 2 : 0);
+  // Fold the columns straight into the frame registers (one
+  // commit_counts) — no per-record column sections exist.
+  int64_t f_tomb = 0, f_knull = 0, f_ksum = 0, f_vsum = 0;
+  for (int64_t i = lo; i < hi; ++i) {
+    if (value_null[i]) ++f_tomb;
+    if (key_null[i]) ++f_knull;
+    if (!key_null[i]) f_ksum += key_len[i];
+    if (!value_null[i]) f_vsum += value_len[i];
   }
-  // Extremes: scalar reduction, ONE table RMW.  The wire-v5 quantile
-  // pass stashes the same tombstone-excluded sizes this loop derives.
+  if (take)
+    commit_counts(r, dense_partition, take, f_tomb, f_knull, f_ksum,
+                  f_vsum);
+  // Extremes: scalar reduction, ONE table RMW.  The quantile pass stashes
+  // the same tombstone-excluded sizes this loop derives.
   FrameStash qstash = stash_of(
       scratch, r.b, pack_scratch_cap(r.b, with_alive, alive_bits));
   int64_t ts_min = INT64_MAX, ts_max = INT64_MIN;
@@ -1872,10 +1543,7 @@ int64_t kta_pack_append_columns(
   }
   scratch[0] = c0 + take;
   const int32_t hv = static_cast<int32_t>(scratch[0]);
-  const int32_t hp =
-      with_alive == 2 ? 0 : static_cast<int32_t>(scratch[1]);
   std::memcpy(out, &hv, 4);
-  std::memcpy(out + 4, &hp, 4);
   return take;
 }
 

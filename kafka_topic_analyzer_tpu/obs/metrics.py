@@ -249,7 +249,7 @@ FETCH_SCHED_CANCELLED = _REG.counter(
 
 FUSED_BATCHES = _REG.counter(
     "kta_fused_batches_total",
-    "Wire-v4 rows completed by the fused native decode→pack path")
+    "Packed rows completed by the fused native decode→pack path")
 FUSED_RECORDS = _REG.counter(
     "kta_fused_records_total",
     "Records packed by the fused path without a decoded-column "
@@ -262,7 +262,7 @@ FUSED_FALLBACK = _REG.counter(
     "disabled/failed, source or backend without fused support)",
     labelnames=("reason",))
 
-# -- packed wire format (packing.py v4/v5; backends book the transfers) -------
+# -- packed wire format (packing.py; backends book the transfers) -------------
 
 WIRE_BYTES = _REG.counter(
     "kta_wire_bytes_total",
@@ -271,31 +271,17 @@ WIRE_BYTES = _REG.counter(
 WIRE_BYTES_PER_RECORD = _REG.gauge(
     "kta_wire_packed_bytes_per_record",
     "Packed wire bytes per scanned record for the finished scan "
-    "(kta_wire_bytes_total delta / records) — the observable v4→v5 "
-    "wire saving",
+    "(kta_wire_bytes_total delta / records)",
     merge="max")
-WIRE_V4_FALLBACK = _REG.counter(
-    "kta_wire_v4_fallback_total",
-    "Scans that ran the v4 per-record wire format instead of the v5 "
-    "combiner rows (reason: env-kill-switch = KTA_WIRE_V4, explicit = "
-    "caller pinned v4) — a bypassed combiner is never silent",
-    labelnames=("reason",))
 ALIVE_PAIRS_RAW = _REG.counter(
     "kta_alive_pairs_raw_total",
     "Per-batch LWW alive-pairs entering the dispatch-level compaction "
-    "merge (the compacted path's input side; DESIGN §19)")
+    "merge (its input side; DESIGN §19)")
 ALIVE_PAIRS_EMITTED = _REG.counter(
     "kta_alive_pairs_emitted_total",
-    "Merged alive-pairs actually shipped in compacted per-dispatch pair "
+    "Merged alive-pairs actually shipped in per-dispatch alive "
     "tables — emitted/raw is the measured compaction ratio the --stats "
     "wire digest reports")
-ALIVE_COMPACTION_OFF = _REG.counter(
-    "kta_alive_compaction_off_total",
-    "Alive-key scans that ran WITHOUT pair compaction (reason: "
-    "env-kill-switch = KTA_DISABLE_COMPACTION, explicit = "
-    "--alive-compaction off, wire-v4 = the v4 layout keeps per-row "
-    "pairs) — a bypassed compaction is never silent",
-    labelnames=("reason",))
 
 # -- io/kafka_wire ------------------------------------------------------------
 

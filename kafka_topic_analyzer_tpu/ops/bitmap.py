@@ -1,24 +1,19 @@
-"""Packed-bit alive-key bitmap with sort-based last-writer-wins updates.
+"""Packed-bit alive-key bitmap with last-writer-wins table applies.
 
 TPU-native replacement for the reference's alive-key ``BitSet`` indexed by
 fnv32 hashes (src/metric.rs:256-305): same observable semantics — a key is
 alive iff its *latest* record (per key, in offset order) has a non-null
 value; collisions conflate keys exactly as the reference's 32-bit hash does —
-but updated a batch at a time on device:
+but updated a dispatch at a time on device.  The host resolves
+last-writer-wins per slot (packing.py's alive table), so the device only
+applies the result: ``words = (words & ~clear) | set``, with the set/clear
+masks shipped as words or built from deduped (slot, aliveness) pairs.
 
-1. within the batch, records are sorted by ``(slot, position)`` and only the
-   last record per slot survives (last-writer-wins compaction — the batch
-   analog of replaying updates in order);
-2. the surviving (slot, aliveness) pairs become two word-level masks built by
-   scatter-add (each surviving slot contributes a distinct bit of its word,
-   so integer add == bitwise OR);
-3. ``words = (words & ~clear) | set`` applies deletes-then-inserts; ordering
-   between the two is already resolved per slot by step 1.
-
-Correctness across batches relies on batches arriving in per-partition offset
-order, and across devices on every partition being pinned to one data shard
-(a Kafka key lives in exactly one partition, so shard-local last-writer-wins
-composes into an exact OR-merge; records.py ordering contract).
+Correctness across dispatches relies on batches arriving in per-partition
+offset order, and across devices on every partition being pinned to one
+data shard (a Kafka key lives in exactly one partition, so shard-local
+last-writer-wins composes into an exact OR-merge; records.py ordering
+contract).
 
 The slot space can additionally be sharded over the mesh's 'space' axis: each
 space shard masks updates to its slot range, so no collective is needed per
@@ -37,46 +32,6 @@ def bitmap_num_words(bits: int, space_shards: int = 1) -> int:
     return total_words // space_shards
 
 
-def bitmap_update(
-    words,        # uint32[W] — this shard's packed bits
-    key_hash32,   # uint32[B]
-    alive,        # bool[B] — value non-null
-    active,       # bool[B] — valid & key non-null
-    bits: int,
-    space_index=0,       # scalar int — which slot-range shard this is
-    space_shards: int = 1,
-):
-    """Apply one batch to the packed bitmap, last-writer-wins per slot."""
-    B = key_hash32.shape[0]
-    W = bitmap_num_words(bits, space_shards)
-    num_slots = jnp.int64(1) << bits
-    slot = (key_hash32.astype(jnp.int64)) & (num_slots - 1)
-    shard_base = jnp.int64(W * 32) * space_index
-    in_shard = active & (slot >= shard_base) & (slot < shard_base + W * 32)
-    local = slot - shard_base
-    # Inactive / out-of-shard records route to a sentinel past every real slot
-    # so they sort to the end and land in the scratch word.
-    local = jnp.where(in_shard, local, jnp.int64(W) * 32)
-    # Sort by (slot, batch position): stable last-occurrence-per-slot select.
-    order_key = local * B + jnp.arange(B, dtype=jnp.int64)
-    perm = jnp.argsort(order_key)
-    slot_sorted = local[perm]
-    alive_sorted = alive[perm]
-    is_last = jnp.concatenate(
-        [slot_sorted[:-1] != slot_sorted[1:], jnp.ones((1,), dtype=bool)]
-    )
-    real = is_last & (slot_sorted < jnp.int64(W) * 32)
-    word = jnp.where(real, slot_sorted >> 5, W).astype(jnp.int32)
-    bit = (jnp.uint32(1) << (slot_sorted & 31).astype(jnp.uint32))
-    set_mask = jnp.where(real & alive_sorted, bit, jnp.uint32(0))
-    clear_mask = jnp.where(real & ~alive_sorted, bit, jnp.uint32(0))
-    scatter = jnp.zeros((W + 1,), dtype=jnp.uint32)
-    # Distinct surviving slots in one word own distinct bits → add == OR.
-    set_words = scatter.at[word].add(set_mask)[:W]
-    clear_words = scatter.at[word].add(clear_mask)[:W]
-    return (words & ~clear_words) | set_words
-
-
 def bitmap_apply_pairs(
     words,        # uint32[W] — this shard's packed bits
     slot,         # uint32[B] — deduped slots (prefix of n_pairs is live)
@@ -86,13 +41,13 @@ def bitmap_apply_pairs(
     space_index=0,
     space_shards: int = 1,
 ):
-    """Apply host-deduped (slot, aliveness) pairs: the fast path.
+    """Apply host-deduped (slot, aliveness) pairs: the alive table's PAIR
+    form (packing.alive_table_mode == 1).
 
-    The host ingest already performed last-writer-wins per slot
+    The host already performed last-writer-wins per slot
     (packing.py::dedupe_slots_*), so each live slot appears exactly once —
     distinct slots in a word own distinct bits, making scatter-add equal to
-    bitwise OR, and no device-side sort is needed (that 1M-element sort was
-    the measured hot spot of the all-device path, ops/bitmap.py::bitmap_update).
+    bitwise OR, and no device-side sort is needed.
     """
     B = slot.shape[0]
     W = bitmap_num_words(bits, space_shards)
@@ -145,8 +100,3 @@ def bitmap_popcount(words):
     from kafka_topic_analyzer_tpu.jax_support import lax
 
     return jnp.sum(lax.population_count(words).astype(jnp.int64))
-
-
-def bitmap_merge(words_a, words_b):
-    """OR-merge of key-disjoint shards (associative, commutative)."""
-    return words_a | words_b

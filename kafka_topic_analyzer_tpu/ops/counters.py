@@ -1,9 +1,10 @@
-"""Exact per-partition counter reduction (the 7 buckets + globals), in JAX.
+"""Exact per-partition extremes merge, in JAX, and the state sentinels.
 
-This is the device-side replacement for ``MessageMetrics::handle_message``
-(src/metric.rs:207-252): instead of 7 HashMap increments per message, one
-batched masked scatter-add produces the whole ``[P, 7]`` counter delta, and
-masked min/max reductions update the global timestamp/size extremes.
+This is the device half of ``MessageMetrics::handle_message``
+(src/metric.rs:207-252): the host packers reduce each batch to a ``[P, 7]``
+counter-delta table and per-partition timestamp/size extremes (packing.py),
+and the device merges those tables — the counters by a plain add in the
+step (backends/step.py), the extremes by the elementwise min/max below.
 
 Semantics preserved exactly (SURVEY.md §3.4):
 - "alive" = record with non-null value, counted per record;
@@ -24,48 +25,6 @@ I64_MAX = jnp.iinfo(jnp.int64).max
 I64_MIN = jnp.iinfo(jnp.int64).min
 
 
-def counters_update(
-    per_partition,  # int64[P, 7]
-    partition,      # int32[B]
-    key_len,        # int32[B]
-    value_len,      # int32[B]
-    key_null,       # bool[B]
-    value_null,     # bool[B]
-    valid,          # bool[B]
-    num_partitions: int,
-):
-    """Add one batch's contribution to the ``[P, 7]`` counter matrix.
-
-    Channel order follows ``results.COUNTER_CHANNELS``:
-    total, tombstones, alive, key_null, key_non_null, key_size_sum,
-    value_size_sum.
-    """
-    kn = valid & ~key_null
-    vn = valid & ~value_null
-    tomb = valid & value_null
-    knull = valid & key_null
-    k_bytes = jnp.where(kn, key_len, 0)
-    v_bytes = jnp.where(vn, value_len, 0)
-    contrib = jnp.stack(
-        [
-            valid.astype(jnp.int32),
-            tomb.astype(jnp.int32),
-            vn.astype(jnp.int32),
-            knull.astype(jnp.int32),
-            kn.astype(jnp.int32),
-            k_bytes,
-            v_bytes,
-        ],
-        axis=1,
-    ).astype(jnp.int64)
-    # Route padded records to a scratch row that is sliced off: keeps the
-    # scatter free of a second mask and the shapes static.
-    idx = jnp.where(valid, partition, num_partitions)
-    scratch = jnp.zeros((num_partitions + 1, 7), dtype=jnp.int64)
-    delta = scratch.at[idx].add(contrib)[:num_partitions]
-    return per_partition + delta
-
-
 def extremes_update(
     earliest_s,     # int64[P], I64_MAX sentinel when unset
     latest_s,       # int64[P], I64_MIN sentinel
@@ -78,11 +37,10 @@ def extremes_update(
 ):
     """Merge per-partition timestamp and message-size extremes.
 
-    Both arrive already reduced per partition by the host (wire v2
-    dropped the per-record ts column, v4 the size-extremes scatter —
-    min/max is associative, so elementwise-merging batch tables is
-    exact; tombstone exclusion for sizes happens at table build,
-    src/metric.rs:249-251).  No per-record work remains here.
+    Both arrive already reduced per partition by the host — min/max is
+    associative, so elementwise-merging batch tables is exact; tombstone
+    exclusion for sizes happens at table build (src/metric.rs:249-251).
+    No per-record work remains here.
     """
     return (
         jnp.minimum(earliest_s, ts_min),

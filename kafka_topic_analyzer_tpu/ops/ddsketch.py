@@ -1,11 +1,12 @@
-"""DDSketch message-size quantiles (device update, host estimate).
+"""DDSketch message-size quantiles (host bucket rule, host estimate).
 
 New capability (BASELINE.json config 4: p50/p99 payload-size percentiles over
 1B mixed-size messages).  The classic t-digest keeps a variable-length list
 of centroids — hostile to XLA's static shapes — so the TPU-native choice is
 DDSketch (Masson et al., VLDB'19): fixed log-γ buckets, a guaranteed relative
-error α, updates that are a single bincount scatter-add, and a merge that is
-plain vector addition (``psum`` over ICI).  The independent referee is the
+error α, updates that are a bincount, and a merge that is plain vector
+addition (the device adds the packers' bucket-count tables; ``psum`` over
+ICI).  The independent referee is the
 CPU oracle's exact size histogram (backends/cpu.py), which parity tests
 compare against within 2α.
 
@@ -24,8 +25,6 @@ import functools
 
 import numpy as np
 
-from kafka_topic_analyzer_tpu.jax_support import jnp
-
 
 def ddsketch_num_buckets(nbuckets: int) -> int:
     return nbuckets + 2  # zero bucket + log buckets + overflow
@@ -41,13 +40,11 @@ def ddsketch_edges(gamma: float, nbuckets: int) -> np.ndarray:
     ``min k such that s <= gamma^(k-1)`` (``s <= gamma^i`` iff
     ``s <= floor(gamma^i)`` for integer ``s``), saturating naturally at
     the overflow bucket ``nbuckets + 1``.  This table is the ONE bucket
-    rule shared by the device update below, the numpy wire-v5 packer, and
-    the native C++ packers (packing.py / native/ingest.cpp): an integer
-    comparison is exact on every backend, where the previous float32
-    ``ceil(log(s)/log(gamma))`` could round differently between numpy's
-    libm and XLA's vectorized log — a one-ULP disagreement the v4↔v5
-    byte-identity bar cannot tolerate.  Cached per (gamma, nbuckets); the
-    array is frozen because the native packers hold raw pointers into it.
+    rule shared by the numpy packer and the native C++ packers (packing.py
+    / native/ingest.cpp): an integer comparison is exact everywhere, where
+    a float ``ceil(log(s)/log(gamma))`` could round differently between
+    two libms.  Cached per (gamma, nbuckets); the array is frozen because
+    the native packers hold raw pointers into it.
     """
     powers = np.power(np.float64(gamma), np.arange(nbuckets, dtype=np.float64))
     # Clip before the int cast: an operator-supplied (alpha, nbuckets) pair
@@ -61,47 +58,12 @@ def ddsketch_edges(gamma: float, nbuckets: int) -> np.ndarray:
 def ddsketch_bucket_numpy(
     sizes: np.ndarray, gamma: float, nbuckets: int
 ) -> np.ndarray:
-    """Host-side bucket index per size (the wire-v5 packer's reduction):
+    """Host-side bucket index per size (the numpy packer's reduction):
     0 for size 0, the shared edge-table bucket otherwise."""
     idx = np.searchsorted(
         ddsketch_edges(gamma, nbuckets), sizes, side="left"
     ).astype(np.int64) + 1
     return np.where(sizes == 0, 0, idx)
-
-
-def ddsketch_update(
-    counts, sizes, active, gamma: float, nbuckets: int, partition=None
-):
-    """Scatter-add one batch of sizes into the bucket counts.
-
-    ``counts`` is ``int64[R, nbuckets+2]`` — one row per partition when
-    per-partition histograms are enabled (``partition`` given), else a
-    single row.  Rows merge by addition, so global quantiles over any row
-    subset are exact.
-
-    Buckets come from the shared integer edge table (``ddsketch_edges``),
-    not a per-record float log: integer ``searchsorted`` is bit-exact
-    across numpy and every XLA backend, which is what lets wire v5
-    pre-reduce this histogram on the host byte-identically.
-    """
-    nb = nbuckets + 2
-    rows = counts.shape[0]
-    edges = jnp.asarray(ddsketch_edges(gamma, nbuckets))
-    idx = (
-        jnp.searchsorted(edges, sizes.astype(jnp.int64), side="left")
-        .astype(jnp.int32) + 1
-    )
-    idx = jnp.where(sizes == 0, 0, idx)
-    row = partition if partition is not None else jnp.int32(0)
-    flat = row * nb + idx
-    flat = jnp.where(active, flat, rows * nb)  # scratch slot for masked
-    scratch = jnp.zeros((rows * nb + 1,), dtype=jnp.int64)
-    delta = scratch.at[flat].add(jnp.int64(1))[: rows * nb]
-    return counts + delta.reshape(rows, nb)
-
-
-def ddsketch_merge(a, b):
-    return a + b
 
 
 def ddsketch_quantiles(counts: np.ndarray, probs, gamma: float) -> "list[float]":

@@ -21,58 +21,26 @@ from __future__ import annotations
 
 import numpy as np
 
-from kafka_topic_analyzer_tpu.jax_support import jnp, lax
+from kafka_topic_analyzer_tpu.jax_support import jnp
 
 
-def _splitmix64_jnp(x):
-    """Bijective SplitMix64 finalizer: FNV-1a avalanches poorly in its high
-    bits on short inputs, and HLL takes its bucket index from the top p bits —
-    without this mix, thousands of short keys collapse into a few buckets.
-    Being a bijection it cannot change distinct-count semantics."""
-    x = x.astype(jnp.uint64)
-    x = x + jnp.uint64(0x9E3779B97F4A7C15)
-    x = (x ^ (x >> jnp.uint64(30))) * jnp.uint64(0xBF58476D1CE4E5B9)
-    x = (x ^ (x >> jnp.uint64(27))) * jnp.uint64(0x94D049BB133111EB)
-    return x ^ (x >> jnp.uint64(31))
-
-
-def hll_split(key_hash64, active, p: int):
-    """All-device (bucket index, rho) derivation from 64-bit hashes — the
-    on-device twin of packing.py::hll_idx_rho_numpy, for callers that skip
-    host pre-reduction.  Feed the result to `hll_apply`."""
-    h = _splitmix64_jnp(key_hash64)
-    idx = (h >> (64 - p)).astype(jnp.int32)
-    rest = h << p
-    # rho = leading-zero count of the remaining bits + 1, capped when zero.
-    rho = jnp.where(
-        rest == 0,
-        jnp.int32(64 - p + 1),
-        lax.clz(rest).astype(jnp.int32) + 1,
-    )
-    rho = jnp.where(active, rho, 0)  # rho 0 is a no-op under scatter-max
-    return idx, rho
-
-
-def hll_apply(regs, idx, rho, partition=None):
-    """Apply host pre-split HLL updates (packing.py::hll_idx_rho_numpy):
-    one scatter-max of rho into the register file ``int32[R, m]``.  With
-    ``partition`` given, each record updates its partition's row (R = P);
-    otherwise the single global row.  Masked records carry rho=0, a no-op
-    under max."""
+def hll_apply(regs, idx, rho):
+    """Apply host pre-split HLL updates (packing.py::hll_idx_rho_numpy) to
+    the single global register row ``int32[1, m]``: one scatter-max of rho.
+    Masked records carry rho=0, a no-op under max."""
     rows, m = regs.shape
-    row = partition if partition is not None else jnp.int32(0)
-    flat = row * m + idx.astype(jnp.int32)
     return (
-        regs.reshape(-1).at[flat].max(rho.astype(jnp.int32)).reshape(rows, m)
+        regs.reshape(-1).at[idx.astype(jnp.int32)]
+        .max(rho.astype(jnp.int32))
+        .reshape(rows, m)
     )
 
 
 def hll_apply_flat(regs, idx32, rho):
-    """Apply wire-v5 flat HLL pairs: ``idx32`` already encodes
-    ``row << p | bucket`` (packing.py's v5 flat pair mode — the partition
-    column no longer ships, so the register row rides inside the index).
-    One scatter-max into the flattened register file; masked records
-    carry (0, 0), a no-op under max."""
+    """Apply flat HLL pairs: ``idx32`` already encodes ``row << p |
+    bucket`` (packing.py's per-partition pair mode — the register row
+    rides inside the index).  One scatter-max into the flattened register
+    file; masked records carry (0, 0), a no-op under max."""
     rows, m = regs.shape
     return (
         regs.reshape(-1)
@@ -80,10 +48,6 @@ def hll_apply_flat(regs, idx32, rho):
         .max(rho.astype(jnp.int32))
         .reshape(rows, m)
     )
-
-
-def hll_merge(regs_a, regs_b):
-    return jnp.maximum(regs_a, regs_b)
 
 
 def _sigma(x: float) -> float:
@@ -128,7 +92,7 @@ def hll_estimate(regs: np.ndarray) -> float:
     if m & (m - 1):
         raise ValueError("register count must be a power of two")
     p = int(m).bit_length() - 1
-    q = 64 - p  # max rho is q + 1 (hll_split caps at 64 - p + 1)
+    q = 64 - p  # max rho is q + 1 (the packers cap it at 64 - p + 1)
     counts = np.bincount(regs.astype(np.int64), minlength=q + 2)
     z = m * _tau(1.0 - counts[q + 1] / m)
     for k in range(q, 0, -1):

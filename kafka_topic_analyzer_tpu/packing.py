@@ -1,76 +1,50 @@
-"""Packed host→device batch transfer (wire format v4).
+"""Packed host→device batch transfer (wire format v5).
 
 The expected bottleneck of the streaming path is host→device bandwidth
-(SURVEY.md §7 hard part (a)).  Three levers, all here:
+(SURVEY.md §7 hard part (a)).  Every metric is an associative
+per-partition fold, so the host ships the fold's partial RESULT instead
+of the records it was computed from — the MapReduce-combiner move — and
+the device merges tables elementwise: O(P·H) work per dispatch instead of
+an O(B) scatter.  A batch crosses as ONE contiguous ``uint8[N]`` buffer
+(one transfer), sections in order (B = static batch size, P =
+num_partitions):
 
-1. **One buffer, one transfer** — all per-record columns packed into a single
-   contiguous ``uint8[N]`` section layout instead of nine separate arrays.
-2. **Minimal bytes per record** — 9 B for the exact counters (vs 37 B naive;
-   17 B in v1): partition i16, key_len u16, value_len u32, flags u8;
-   padding is expressed as a single ``n_valid`` prefix length in the header
-   instead of a bool per record.
-3. **Host pre-reduction** — anything the device would only reduce anyway is
-   reduced on the host: v2 replaced v1's per-record ``ts_s i64[B]`` column
-   with a per-partition min/max table ``i64[2P]`` (the device only ever
-   min/maxes timestamps); the alive bitmap's last-writer-wins dedupe
-   happens on the host (C++ shim / numpy): the device receives at most one
-   (slot, aliveness) pair per touched slot (+5 B) and applies two scatter-ORs
-   instead of sorting a million int64 keys; HLL ships as ONE host-reduced
-   u8[R << p] register table per batch whenever that is smaller than the
-   per-record pairs (v3 — register max is commutative, so the device
-   merges elementwise, no scatter), else as pre-split (bucket index u16,
-   rho u8) pairs (+3 B) instead of a full 64-bit hash.
-
-Layout (sections in order; B = static batch size, P = num_partitions):
-
-    header   u8[16]   n_valid i32 | n_pairs i32 | reserved
-    partition i16[B]
-    key_len   u16[B]  (keys > 64 KiB are rejected at pack time)
-    value_len u32[B]
-    flags     u8[B]   bit0 = key_null, bit1 = value_null
-    ts_minmax i64[2P] per-partition ts min then max, identity-filled
-    sz_minmax i64[2P] per-partition message-size min then max (v4;
-             tombstone-excluded, identities I64_MAX / 0)
-    [alive]  slot u32[B] + alive u8[B]          iff count_alive_keys
-    [hll]    regs u8[R << p] host-reduced table (R = 1 global, P per-
-             partition) WHEN R·2^p ≤ 3·B, else idx u16[B] + rho u8[B]
-             pairs — one size rule, ``hll_table_rows``, decides for the
-             packers and (via section presence) the device step
-
-**Wire format v5 — the combiner** (``AnalyzerConfig.wire_format == 5``,
-the default; DESIGN.md §16).  Every metric is an associative per-partition
-fold, so the third lever (host pre-reduction) extends to the LAST
-per-record columns: the four columns above exist only so the device can
-scatter-add them, and v5 replaces them with the scatter's *result* — the
-MapReduce-combiner move.  Sections in order:
-
-    header    u8[16]      n_valid i32 | n_pairs i32 | reserved
+    header    u8[16]      n_valid i32 | n_pairs i32 (0 in rows) | reserved
     counts    i64[7P]     per-partition counter deltas, row-major [P, 7]
                           in results.COUNTER_CHANNELS order (total,
                           tombstones, alive, key_null, key_non_null,
                           key_size_sum, value_size_sum)
-    ts_minmax i64[2P]     unchanged from v4
-    sz_minmax i64[2P]     unchanged from v4
-    [alive]   slot u32[B] + alive u8[B]          unchanged from v4
-    [hll]     regs u8[R << p] table mode unchanged; PAIR mode ships
-              idx u16[B] + rho u8[B] globally, but idx32 u32[B]
-              (= partition << p | bucket) + rho u8[B] when per-partition
-              registers need the row — the one sub-case that cannot ride
-              unchanged because the partition column is gone
+    ts_minmax i64[2P]     per-partition ts min then max, identity-filled
+                          (I64_MAX / I64_MIN)
+    sz_minmax i64[2P]     per-partition message-size min then max
+                          (tombstone-excluded, identities I64_MAX / 0)
+    [hll]     regs u8[R << p]  host-reduced register table (R = 1 global,
+              P per-partition) WHEN R·2^p ≤ 3·B; else pairs — idx u16[B]
+              + rho u8[B] globally, idx32 u32[B] (= partition << p |
+              bucket) + rho u8[B] per-partition.  One size rule,
+              ``hll_wire_mode``, decides for the packers and (via section
+              names) the device step
     [quant]   i64[R·(nbuckets+2)]  iff enable_quantiles: per-row DDSketch
               bucket-count deltas (R = P per-partition else 1), buckets
               from the shared integer edge table (ops/ddsketch.py)
 
-The device fold becomes an elementwise table merge — O(P·H) per dispatch
-instead of an O(B) scatter — and wire bytes per record collapse when
-P ≪ B (the counts table is 56 B/partition vs 9 B/record).  v4 and v5 scan
-results are byte-identical: every replaced fold is an integer sum or
-min/max, associative and commutative, and the DDSketch bucket rule is the
-same integer edge table on host and device (no float reassociation).
+Every fold above is an integer sum or min/max (associative and
+commutative), and the DDSketch bucket rule is one integer edge table on
+every packer, so the device state equals the CPU oracle's fold exactly.
+
+**The alive table.**  With ``count_alive_keys`` the rows carry no alive
+sections: each device DISPATCH ships one more buffer, the last-writer-wins
+merge — in stream order — of the per-batch deduped (slot, aliveness)
+pairs of every batch it folds (DESIGN.md §19), applied once after the
+dispatch's scan.  Header as above (n_valid 0 | n_pairs), then one of two
+forms, picked by ``alive_table_mode`` from the slot-space size:
+
+    pairs     slot u32[T] | flag u8[T]     T = pair_table_capacity
+    masks     set u32[W] | clear u32[W]    W = 2^bits / 32 bitmap words
 
 Device-side unpacking is pure ``lax.bitcast_convert_type`` on reshaped slices
 (both host and TPU are little-endian; the TPU backend runs a one-time
-pack→unpack self-check at init — both formats — to guarantee it).
+pack→unpack self-check at init to guarantee it).
 """
 
 from __future__ import annotations
@@ -85,50 +59,31 @@ from kafka_topic_analyzer_tpu.records import RecordBatch
 
 HEADER_BYTES = 16
 MAX_KEY_LEN = 0xFFFF
-#: Dense partition indices ride an i16 section.
+#: Dense partition indices fit 15 bits: the flat HLL pair index packs
+#: ``partition << p`` (p ≤ 16) into a u32.
 MAX_PARTITIONS = 0x7FFF
-#: 16 MiB - 1: lets byte sums decompose into two 12-bit MXU-exact digits
-#: (ops/pallas_counters.py); comfortably above Kafka's practical max
-#: message size.
-MAX_VALUE_LEN = (1 << 24) - 1
+#: The layout version this module packs (the ``--stats`` wire line and the
+#: ``--json`` ``wire`` block report it).
+WIRE_FORMAT = 5
 
 
 def _sections(config: AnalyzerConfig, batch_size: int,
               pair_table: bool = False):
-    """(name, dtype, count) section list, in buffer order.
+    """(name, dtype, count) section list, in buffer order — the one layout
+    source (module docstring): the packers, the unpackers and the device
+    step all derive from it, so they cannot skew (lint rule 7).
 
-    ``pair_table=True`` returns the layout of ONE compacted alive-pair
-    TABLE buffer instead (wire v5 + ``config.compact_alive`` — DESIGN
-    §19): the per-dispatch LWW-merged pair table the device applies once
-    per dispatch, with ``batch_size`` then meaning the table CAPACITY
-    (``pair_table_capacity``).  Same single-source discipline: the pair
-    packers (`pack_pair_table`) and unpackers (`unpack_pair_table_*`)
-    derive from this list, so they cannot skew (lint rule 7).
+    ``pair_table=True`` returns the layout of ONE alive TABLE buffer
+    instead (DESIGN §19): the per-dispatch LWW-merged table the device
+    applies once per dispatch, with ``batch_size`` then meaning the table
+    CAPACITY (``pair_table_capacity``); the pair packers
+    (`pack_pair_table`) and unpackers (`unpack_pair_table_*`) derive from
+    this list too.
 
-    The layout contract lives in ONE place — the module docstring above
-    (wire format v4); this builder, the packers, the unpackers, and the
-    device step all derive from this list, so they cannot skew from it.
-
-    v2 removed the 8 B/record ``ts_s`` column: the device only ever
-    reduces timestamps to per-partition min/max (ops/counters.py
-    ``extremes_update``), so the HOST pre-reduces each batch to a
-    ``[2P]`` int64 table (mins then maxes; I64_MAX/I64_MIN where the
-    batch has no record for that partition — the identity elements, so
-    merging on device is exact).  That is 8 B/record off the wire — the
-    dominant column at 17-25 B/record — lifting the transfer-bound
-    msgs/s ceiling ~1.5-1.9x (round-1 host estimate).
-    Min/max associativity keeps the sharded chunk path exact too.
-
-    Known trade-off: sharded scans pack each of the S space chunks with
-    its own [2P] table, so per-step ts bytes are S*2P*8 instead of B*8 —
-    a net INCREASE only when 2*P*S > B, i.e. partition counts within ~2x
-    of MAX_PARTITIONS combined with small chunked batches; every realistic
-    config (P ≤ thousands, B ≥ 2^17) is a large net win.
-
-    v5 (the combiner format — module docstring) drops the four per-record
-    columns for a per-partition counter-delta table and, with quantiles
-    on, a DDSketch bucket-count table: the same trade-off taken to its
-    end state, O(P·H) table bytes replacing O(B) column bytes.
+    Known trade-off: a sharded scan packs each of its S space chunks with
+    tables of its own, so table bytes per step grow S-fold — a cost only
+    when the tables approach the chunk's record bytes, which no realistic
+    config (P ≤ thousands, B ≥ 2^17) reaches.
     """
     b = batch_size
     p = config.num_partitions
@@ -143,59 +98,30 @@ def _sections(config: AnalyzerConfig, batch_size: int,
             ("alive_slot", np.uint32, b),
             ("alive_flag", np.uint8, b),
         ]
-    if config.wire_format == 5:
-        sec = [
-            # Pre-reduced counter deltas in results.COUNTER_CHANNELS
-            # order: what counters_update's scatter-add would have
-            # produced from the four dropped columns.
-            ("counts", np.int64, 7 * p),
-            ("ts_minmax", np.int64, 2 * p),
-            ("sz_minmax", np.int64, 2 * p),
-        ]
-    else:
-        sec = [
-            ("partition", np.int16, b),
-            ("key_len", np.uint16, b),
-            ("value_len", np.uint32, b),
-            ("flags", np.uint8, b),
-            ("ts_minmax", np.int64, 2 * p),
-            # v4: per-partition message-size min/max (tombstone-excluded,
-            # src/metric.rs:249-251) — integer min/max is associative, so the
-            # host pre-reduces it exactly like the ts table and the device
-            # drops its last extremes scatter.  Sizes still ship per record
-            # (the counter sums need them), so this adds 16 B/partition and
-            # removes a B-record scatter-min + scatter-max from the step.
-            ("sz_minmax", np.int64, 2 * p),
-        ]
-    if config.count_alive_keys and not getattr(config, "compact_alive", False):
-        # Compacted configs (wire v5 --alive-compaction auto) ship the
-        # pairs as ONE per-dispatch merged table (pair_table=True above)
-        # instead of 5 B/record of per-row sections.
-        sec.append(("alive_slot", np.uint32, b))
-        sec.append(("alive_flag", np.uint8, b))
+    sec = [
+        ("counts", np.int64, 7 * p),
+        ("ts_minmax", np.int64, 2 * p),
+        ("sz_minmax", np.int64, 2 * p),
+    ]
     mode = hll_wire_mode(config, b)
     if mode == 2:
-        # Table mode (v3): register max is fully commutative, so the
-        # host pre-reduces the whole batch to a u8[R, 2^p] register
-        # table (R = 1 global, R = P per-partition) and the device
+        # Register max is fully commutative, so the host pre-reduces the
+        # whole batch to a u8[R, 2^p] register table and the device
         # merges it ELEMENTWISE — no scatter on the hot path.
         sec.append(
             ("hll_regs", np.uint8, hll_table_rows(config, b) << config.hll_p)
         )
     elif mode == 3:
-        # v5 flat pair mode: the partition column is gone, so the
-        # register ROW rides inside the index — idx32 = partition <<
-        # p | bucket (15 + 16 bits fit u32).  Costs 2 B/record over
-        # v4's u16 pairs, only in the rare huge-P-small-B regime
-        # where pair mode wins the table-size rule at all.
+        # Per-partition pairs: the register ROW rides inside the index
+        # (partition << p | bucket — 15 + 16 bits fit u32).
         sec.append(("hll_idx32", np.uint32, b))
         sec.append(("hll_rho", np.uint8, b))
     elif mode == 1:
-        # Pair mode: per-record (register index, rho) — cheaper on
-        # the wire than a table whenever R·2^p > 3·B.
+        # Global pairs: per-record (register index, rho) — cheaper on the
+        # wire than a table whenever 2^p > 3·B.
         sec.append(("hll_idx", np.uint16, b))
         sec.append(("hll_rho", np.uint8, b))
-    if config.wire_format == 5 and config.enable_quantiles:
+    if config.enable_quantiles:
         from kafka_topic_analyzer_tpu.ops.ddsketch import ddsketch_num_buckets
 
         q_rows = p if config.quantiles_per_partition else 1
@@ -207,13 +133,10 @@ def _sections(config: AnalyzerConfig, batch_size: int,
 
 
 #: Sections whose byte count scales with the batch size — the per-record
-#: share of a packed buffer.  Everything else (header included) is a
-#: fold-table constant per batch.  Drives ``section_byte_split`` and the
-#: ``--stats`` wire line, so the v4→v5 saving is observable, not inferred.
-PER_RECORD_SECTIONS = frozenset(
-    {"partition", "key_len", "value_len", "flags",
-     "alive_slot", "alive_flag", "hll_idx", "hll_idx32", "hll_rho"}
-)
+#: share of a packed buffer (the HLL pair forms).  Everything
+#: else (header included) is a fold-table constant per batch.  Drives
+#: ``section_byte_split`` and the ``--stats`` wire line.
+PER_RECORD_SECTIONS = frozenset({"hll_idx", "hll_idx32", "hll_rho"})
 
 
 def section_byte_split(
@@ -253,17 +176,15 @@ def hll_wire_mode(config: AnalyzerConfig, batch_size: int) -> int:
     decides the table half of this rule):
 
     - ``0`` — HLL off;
-    - ``1`` — u16 (bucket, rho) pairs;
+    - ``1`` — global u16 (bucket, rho) pairs;
     - ``2`` — host-reduced register table (``hll_table_rows`` rows);
-    - ``3`` — wire-v5 flat u32 pairs (``partition << p | bucket``): the
-      per-partition pair form, which cannot ship a bare bucket index once
-      the v5 layout drops the partition column.
+    - ``3`` — per-partition flat u32 pairs (``partition << p | bucket``).
     """
     if not config.enable_hll:
         return 0
     if hll_table_rows(config, batch_size):
         return 2
-    if config.wire_format == 5 and config.distinct_keys_per_partition:
+    if config.distinct_keys_per_partition:
         return 3
     return 1
 
@@ -339,16 +260,14 @@ def _dedupe_slots(h32, active, alive, bits, use_native=True):
 
 
 # ---------------------------------------------------------------------------
-# compacted alive-pair table (wire v5 + AnalyzerConfig.compact_alive;
-# DESIGN.md §19)
+# the alive table (DESIGN.md §19)
 #
-# With compaction on, the per-row pair sections disappear and every device
-# DISPATCH carries ONE pair-table buffer: the LWW merge — in stream order —
-# of the per-batch deduped pairs of all K batches the dispatch folds.  LWW
-# compaction is LWW-associative (compact(a,b) then merge with compact(c,d)
-# in order equals the uncompacted replay), so applying the merged table once
-# AFTER the superbatch scan is byte-identical to the per-batch scatter the
-# scan body used to run — and the O(W) bitmap mask apply is paid once per
+# Rows carry no alive sections: every device DISPATCH carries ONE table
+# buffer, the LWW merge — in stream order — of the per-batch deduped pairs
+# of all K batches the dispatch folds.  LWW compaction is LWW-associative
+# (compact(a,b) then merge with compact(c,d) in order equals the per-record
+# replay), so applying the merged table once AFTER the superbatch scan
+# equals the per-record fold — and the O(W) bitmap apply is paid once per
 # dispatch instead of K times.
 
 
@@ -374,9 +293,8 @@ ALIVE_MASK_CAP_BYTES = 64 << 20
 #: device applies elementwise mask words ~80-180x cheaper per byte than
 #: scatter elements (0.7 ms per 16 MB of masks vs ~21-60 ms per 2.6 MB
 #: of pair scatter at B=2^16 on the host-CPU jit), so trading up to 32x
-#: the bytes for the elementwise merge wins on the host-CPU jit; where
-#: transfer is priced higher, ``--alive-compaction off`` (or a bitmap
-#: past the caps) keeps the pair forms.
+#: the bytes for the elementwise merge wins on the host-CPU jit; a bitmap
+#: past the caps keeps the pair form.
 ALIVE_MASK_TRADE_FACTOR = 32
 
 
@@ -394,7 +312,7 @@ def alive_table_mode(config: AnalyzerConfig, capacity: int) -> int:
       spaces);
     - ``2`` — set/clear word masks ``u32[W] | u32[W]``: the host resolves
       LWW straight into bitmask form and the device merges ELEMENTWISE
-      (``(words & ~clear) | set``) like any other wire-v5 table — no
+      (``(words & ~clear) | set``) like any other fold table — no
       scatter at all.
     """
     mask_nbytes = 2 * _alive_mask_words(config) * 4
@@ -415,9 +333,8 @@ def pair_table_nbytes(config: AnalyzerConfig, capacity: int) -> int:
 def batch_alive_pairs(
     batch: RecordBatch, config: AnalyzerConfig, use_native: bool = True
 ) -> Tuple[np.ndarray, np.ndarray]:
-    """One batch's LWW-deduped (slot, alive) pairs for the compacted path
-    — exactly the pre-reduction the per-row sections used to carry, but
-    returned host-side so the dispatch can merge across batches."""
+    """One batch's LWW-deduped (slot, alive) pairs, returned host-side so
+    the dispatch can merge them across its batches (`pack_pair_table`)."""
     active = batch.valid & ~batch.key_null
     alive = batch.valid & ~batch.value_null
     return _dedupe_slots(
@@ -659,8 +576,9 @@ def pack_batch(
     use_native: bool = True,
     out: "np.ndarray | None" = None,
 ) -> np.ndarray:
-    """RecordBatch → one contiguous uint8 buffer (wire format v4 — the
-    module docstring is the layout's single source of truth).
+    """RecordBatch → one contiguous uint8 buffer (the module docstring is
+    the layout's single source of truth).  Alive pairs are not part of the
+    row: the dispatch packs them (`batch_alive_pairs`, `pack_pair_table`).
 
     The batch's valid records must be a prefix (all sources produce
     prefix-valid batches; padding lives at the tail).
@@ -691,35 +609,22 @@ def pack_batch(
             f"partition index out of packed-transfer range [0, {MAX_PARTITIONS}]"
         )
     if n_valid and batch.partition[:n_valid].max() >= config.num_partitions:
-        # The v2 ts table is [2P]; a stray dense index past P would scatter
-        # out of bounds (the reducers would mis-bucket it anyway).
+        # The tables are [P]-indexed; a stray dense index past P would
+        # scatter out of bounds (the reducers would mis-bucket it anyway).
         raise ValueError(
             f"partition index {int(batch.partition[:n_valid].max())} >= "
             f"num_partitions {config.num_partitions}"
         )
     if n and (batch.value_len.min() < 0 or batch.key_len.min() < 0):
-        # astype(uint) would silently wrap a negative length into gigabytes.
+        # A negative length would corrupt the byte sums silently.
         raise ValueError("negative key/value length in record batch")
-    if (
-        config.use_pallas_counters
-        and config.wire_format == 4
-        and batch.value_len.max(initial=0) > MAX_VALUE_LEN
-    ):
-        # Only the v4 MXU kernel's 12-bit digit decomposition needs this
-        # cap; the default scatter path handles full u32 lengths exactly,
-        # and the v5 table merge never sees a per-record length at all.
-        raise ValueError(
-            f"value length {int(batch.value_len.max())} exceeds the Pallas "
-            f"counter kernel's limit of {MAX_VALUE_LEN} bytes — disable "
-            f"use_pallas_counters for such topics"
-        )
 
     if use_native:
-        # Fused C++ pack (columns + dedupe + HLL split in one pass),
-        # writing straight into ``out`` when given (superbatch rows take
-        # the packed bytes with no intermediate buffer).  A None return
-        # means the shim rejected the batch; the numpy path below
-        # re-derives the descriptive error.
+        # Fused C++ pack (tables + HLL split in one pass), writing straight
+        # into ``out`` when given (superbatch rows take the packed bytes
+        # with no intermediate buffer).  A None return means the shim
+        # rejected the batch; the numpy path below re-derives the
+        # descriptive error.
         try:
             from kafka_topic_analyzer_tpu.io.native import (
                 native_available,
@@ -740,95 +645,57 @@ def pack_batch(
     header = np.zeros(4, dtype=np.int32)
     header[0] = n_valid
 
-    pos = HEADER_BYTES
-    # Integer columns go in uncast: the section write below assigns through
-    # a typed view, which narrows exactly like the astype it replaces —
-    # minus one intermediate array per column (range checks above already
-    # guarantee the narrowing is lossless).
+    # The combiner reduction: the per-partition delta tables the device
+    # merges — the exact contrib stack of the CPU oracle, pre-added by
+    # partition on the host.
+    part = batch.partition[:n_valid]
+    kn = ~batch.key_null[:n_valid]
+    vn = ~batch.value_null[:n_valid]
+    k_bytes = np.where(kn, batch.key_len[:n_valid], 0).astype(np.int64)
+    v_bytes = np.where(vn, batch.value_len[:n_valid], 0).astype(np.int64)
+    counts = np.zeros((config.num_partitions, 7), dtype=np.int64)
+    if n_valid:
+        contrib = np.stack(
+            [
+                np.ones(n_valid, dtype=np.int64),
+                (~vn).astype(np.int64),  # tombstones
+                vn.astype(np.int64),     # alive
+                (~kn).astype(np.int64),  # key_null
+                kn.astype(np.int64),     # key_non_null
+                k_bytes,
+                v_bytes,
+            ],
+            axis=1,
+        )
+        np.add.at(counts, part, contrib)
     fields: Dict[str, np.ndarray] = {
+        "counts": counts.reshape(-1),
         "ts_minmax": ts_minmax_table(
-            batch.partition[:n_valid], batch.ts_s[:n_valid],
-            config.num_partitions,
+            part, batch.ts_s[:n_valid], config.num_partitions
         ),
         "sz_minmax": sz_minmax_table(batch, n_valid, config.num_partitions),
     }
-    if config.wire_format == 5:
-        # The combiner reduction: fold the four per-record columns down to
-        # the per-partition delta tables the device would have scattered
-        # them into — the exact contrib stack of ops/counters.py (and the
-        # CPU oracle), pre-added by partition on the host.
-        part = batch.partition[:n_valid]
-        kn = ~batch.key_null[:n_valid]
-        vn = ~batch.value_null[:n_valid]
-        k_bytes = np.where(kn, batch.key_len[:n_valid], 0).astype(np.int64)
-        v_bytes = np.where(vn, batch.value_len[:n_valid], 0).astype(np.int64)
-        counts = np.zeros((config.num_partitions, 7), dtype=np.int64)
-        if n_valid:
-            contrib = np.stack(
-                [
-                    np.ones(n_valid, dtype=np.int64),
-                    (~vn).astype(np.int64),  # tombstones
-                    vn.astype(np.int64),     # alive
-                    (~kn).astype(np.int64),  # key_null
-                    kn.astype(np.int64),     # key_non_null
-                    k_bytes,
-                    v_bytes,
-                ],
-                axis=1,
-            )
-            np.add.at(counts, part, contrib)
-        fields["counts"] = counts.reshape(-1)
-        if config.enable_quantiles:
-            from kafka_topic_analyzer_tpu.ops.ddsketch import (
-                ddsketch_bucket_numpy,
-                ddsketch_num_buckets,
-            )
+    if config.enable_quantiles:
+        from kafka_topic_analyzer_tpu.ops.ddsketch import (
+            ddsketch_bucket_numpy,
+            ddsketch_num_buckets,
+        )
 
-            nb = ddsketch_num_buckets(config.quantile_buckets)
-            q_rows = (
-                config.num_partitions if config.quantiles_per_partition else 1
+        nb = ddsketch_num_buckets(config.quantile_buckets)
+        q_rows = config.num_partitions if config.quantiles_per_partition else 1
+        qtable = np.zeros(q_rows * nb, dtype=np.int64)
+        if n_valid and vn.any():
+            # Quantiles run over sized (non-tombstone) messages, like the
+            # size extremes; buckets come from the shared integer edge
+            # table so every packer agrees.
+            sizes = (k_bytes + v_bytes)[vn]
+            idx = ddsketch_bucket_numpy(
+                sizes, config.quantile_gamma, config.quantile_buckets
             )
-            qtable = np.zeros(q_rows * nb, dtype=np.int64)
-            if n_valid and vn.any():
-                # Quantiles run over sized (non-tombstone) messages, like
-                # the size extremes; buckets come from the shared integer
-                # edge table so host and device can never disagree.
-                sizes = (k_bytes + v_bytes)[vn]
-                idx = ddsketch_bucket_numpy(
-                    sizes, config.quantile_gamma, config.quantile_buckets
-                )
-                if q_rows > 1:
-                    idx = part[vn].astype(np.int64) * nb + idx
-                np.add.at(qtable, idx, 1)
-            fields["qcounts"] = qtable
-    else:
-        fields.update(
-            {
-                "partition": batch.partition,
-                "key_len": batch.key_len,
-                "value_len": batch.value_len,
-                "flags": (
-                    batch.key_null.astype(np.uint8)
-                    | (batch.value_null.astype(np.uint8) << 1)
-                ),
-            }
-        )
-    if config.count_alive_keys and not config.compact_alive:
-        active = batch.valid & ~batch.key_null
-        alive = batch.valid & ~batch.value_null
-        slots, flags = _dedupe_slots(
-            batch.key_hash32, active, alive, config.alive_bitmap_bits, use_native
-        )
-        n_pairs = len(slots)
-        if n_pairs > b:
-            raise AssertionError("dedupe produced more pairs than records")
-        header[1] = n_pairs
-        slot_arr = np.zeros(b, dtype=np.uint32)
-        flag_arr = np.zeros(b, dtype=np.uint8)
-        slot_arr[:n_pairs] = slots
-        flag_arr[:n_pairs] = flags
-        fields["alive_slot"] = slot_arr
-        fields["alive_flag"] = flag_arr
+            if q_rows > 1:
+                idx = part[vn].astype(np.int64) * nb + idx
+            np.add.at(qtable, idx, 1)
+        fields["qcounts"] = qtable
     if config.enable_hll:
         active = batch.valid & ~batch.key_null
         idx, rho = hll_idx_rho_numpy(batch.key_hash64, active, config.hll_p)
@@ -840,17 +707,13 @@ def pack_batch(
                 # rho is 0 for masked/null-key records — a no-op under max.
                 flat = idx[:n_valid].astype(np.int64)
                 if rows > 1:
-                    flat = flat + (
-                        batch.partition[:n_valid].astype(np.int64)
-                        << config.hll_p
-                    )
+                    flat = flat + (part.astype(np.int64) << config.hll_p)
                 np.maximum.at(table, flat, rho[:n_valid])
             fields["hll_regs"] = table
         elif mode == 3:
-            # v5 flat pairs: the register row travels inside the index
-            # (partition << p | bucket) because the partition column no
-            # longer ships.  Inactive records stay (0, 0) — a no-op
-            # under the flat scatter-max exactly like v4's pair rule.
+            # Flat pairs: the register row travels inside the index
+            # (partition << p | bucket).  Inactive records stay (0, 0) — a
+            # no-op under the flat scatter-max.
             idx32 = np.where(
                 active,
                 (batch.partition.astype(np.int64) << config.hll_p)
@@ -864,11 +727,10 @@ def pack_batch(
             fields["hll_rho"] = rho
 
     out[:HEADER_BYTES] = header.view(np.uint8)
+    pos = HEADER_BYTES
     for name, dtype, count in _sections(config, b):
         # Write each section directly through a typed view of the output
         # buffer — no staging array, so the bytes move source→buffer once.
-        # With memmap-backed columns (SegmentFile.read_batch) that makes
-        # the whole numpy pack a single file-page→wire-row copy per column.
         nbytes = np.dtype(dtype).itemsize * count
         src = fields[name]
         sec = out[pos : pos + nbytes].view(dtype)
@@ -884,7 +746,7 @@ def pack_batch(
 # The seam between the wire/segment readers and the packed device backends:
 # a sink accepts raw record-set bytes (fused native decode→pack, no SoA
 # intermediate) or already-decoded columns (the python-chain fallback for
-# compressed/legacy/salvaged frames), fills wire-v4 rows incrementally, and
+# compressed/legacy/salvaged frames), fills packed rows incrementally, and
 # hands completed rows — staged for the backend — back to the stream.
 
 
@@ -909,7 +771,7 @@ def fused_ingest_enabled() -> bool:
 
 
 class PackedRow:
-    """One completed wire-v4 row from the fused ingest path, plus the
+    """One completed packed row from the fused ingest path, plus the
     bookkeeping the engine would otherwise read off the decoded batch:
     per-partition progress (offsets or counts), the last record's
     identity for the spinner, and the decoded-equivalent byte size for
@@ -931,10 +793,10 @@ class PackedRow:
         self.buf = buf
         self.staged = staged
         self.seq = seq
-        #: Compacted-path alive pairs of THIS row — (slot u32[n], flag
-        #: u8[n]) host arrays in row stream order, None when the config
-        #: ships per-row pair sections instead (the staged form carries
-        #: the same pairs for the backends' dispatch merge).
+        #: Alive pairs of THIS row — (slot u32[n], flag u8[n]) host
+        #: arrays in row stream order, None when the scan counts no alive
+        #: keys (the staged form carries the same pairs for the backends'
+        #: dispatch merge).
         self.pairs = pairs
         self.n_valid = n_valid
         #: true partition id -> one past the last appended offset (sources
@@ -956,7 +818,7 @@ class PackedRow:
 
 
 class FusedPackSink:
-    """Incremental wire-v4 row assembly for one ingest stream.
+    """Incremental packed-row assembly for one ingest stream.
 
     Single-device form (``space_shards=1``): rows are flat
     ``uint8[packed_nbytes]`` buffers of ``chunk_records`` records — the
@@ -1011,11 +873,11 @@ class FusedPackSink:
         self._counts: "dict[int, int]" = {}
         self._last = (-1, -1, 0)
         self._done: "list[PackedRow]" = []
-        #: Compacted alive path (pack_config.compact_alive): the native
-        #: pass diverts each chunk's LWW pairs to the scratch emission
-        #: region; they are harvested — copied out — before every scratch
-        #: re-init and accumulate here until the row completes.
-        self._compact = getattr(pack_config, "compact_alive", False)
+        #: Alive-key scans: the native pass emits each chunk's LWW pairs
+        #: to the scratch emission region; they are harvested — copied
+        #: out — before every scratch re-init and accumulate here until
+        #: the row completes.
+        self._alive = pack_config.count_alive_keys
         self._row_pairs: "list[tuple[np.ndarray, np.ndarray]]" = []
         #: Batch sequence number of the row being filled, or of the next
         #: row where none is open (obs/trace.py::next_batch).
@@ -1044,18 +906,16 @@ class FusedPackSink:
             )
 
     def _harvest_pairs(self) -> None:
-        """Copy the current chunk's compacted pairs out of the scratch
+        """Copy the current chunk's alive pairs out of the scratch
         emission region — MUST run before any ``pack_row_init`` resets the
         scratch (chunk rotation, row completion, flush padding)."""
-        if self._compact and int(self._scratch[1]):
+        if self._alive and int(self._scratch[1]):
             self._row_pairs.append(
-                self._native.pack_take_pairs(
-                    self._scratch, self.pack_config, self.chunk_records
-                )
+                self._native.pack_take_pairs(self._scratch, self.chunk_records)
             )
 
     def _take_row_pairs(self) -> "tuple[np.ndarray, np.ndarray] | None":
-        if not self._compact:
+        if not self._alive:
             return None
         pairs = self._row_pairs
         self._row_pairs = []
@@ -1096,14 +956,9 @@ class FusedPackSink:
             staged = None
         else:
             with obs_trace.maybe_span("stage", cat="io", batch=seq):
-                if self._compact:
-                    # Compacted path: the stage callback carries the row's
-                    # pairs into the staged form so the backend's dispatch
-                    # merge sees them without re-reading the
-                    # (sectionless) row.
-                    staged = self._stage(row, pairs)
-                else:
-                    staged = self._stage(row)
+                # The stage callback carries the row's alive pairs into
+                # the staged form for the backend's dispatch merge.
+                staged = self._stage(row, pairs)
         self._done.append(
             PackedRow(
                 row,
@@ -1143,7 +998,7 @@ class FusedPackSink:
         Returns ``(accepted, consumed_bytes, covered_end, last_offset)``
         — the same contract the chained whole-set decode + accept_records
         pair implements.  Raises the packer's ValueError on records the
-        wire-v4 layout cannot carry; a malformed frame just ends the
+        packed layout cannot carry; a malformed frame just ends the
         prefix (the caller's per-frame chain classifies it)."""
         buf = np.frombuffer(data, dtype=np.uint8)
         dense = self._dense_of(partition)
@@ -1378,44 +1233,34 @@ def pack_chunks(
     return out
 
 
+def _shape_tables(out: dict, config: AnalyzerConfig) -> dict:
+    """Reshape the flat fold tables of an unpacked row (host or device)."""
+    from kafka_topic_analyzer_tpu.ops.ddsketch import ddsketch_num_buckets
+
+    p = config.num_partitions
+    out["counts"] = out["counts"].reshape(p, 7)
+    if "qcounts" in out:
+        out["qcounts"] = out["qcounts"].reshape(
+            -1, ddsketch_num_buckets(config.quantile_buckets)
+        )
+    tm = out.pop("ts_minmax")
+    out["ts_min"], out["ts_max"] = tm[:p], tm[p:]
+    sm = out.pop("sz_minmax")
+    out["sz_min"], out["sz_max"] = sm[:p], sm[p:]
+    return out
+
+
 def unpack_numpy(buf: np.ndarray, config: AnalyzerConfig) -> Dict[str, np.ndarray]:
     """Host-side reference unpack (tests + the device self-check oracle)."""
-    b = config.batch_size
-    header = buf[:HEADER_BYTES].view(np.int32)
     out: Dict[str, np.ndarray] = {
-        "n_valid": header[0],
-        "n_pairs": header[1],
+        "n_valid": buf[:HEADER_BYTES].view(np.int32)[0]
     }
     pos = HEADER_BYTES
-    for name, dtype, count in _sections(config, b):
+    for name, dtype, count in _sections(config, config.batch_size):
         nbytes = np.dtype(dtype).itemsize * count
         out[name] = buf[pos : pos + nbytes].view(dtype)
         pos += nbytes
-    if config.wire_format == 5:
-        out["counts"] = out["counts"].reshape(config.num_partitions, 7)
-        if "qcounts" in out:
-            from kafka_topic_analyzer_tpu.ops.ddsketch import (
-                ddsketch_num_buckets,
-            )
-
-            out["qcounts"] = out["qcounts"].reshape(
-                -1, ddsketch_num_buckets(config.quantile_buckets)
-            )
-    else:
-        flags = out.pop("flags")
-        out["key_null"] = (flags & 1).astype(bool)
-        out["value_null"] = (flags & 2).astype(bool)
-        out["valid"] = np.arange(b, dtype=np.int32) < out["n_valid"]
-        out["partition"] = out["partition"].astype(np.int32)
-        out["key_len"] = out["key_len"].astype(np.int32)
-        out["value_len"] = out["value_len"].astype(np.int32)
-    tm = out.pop("ts_minmax")
-    out["ts_min"] = tm[: config.num_partitions]
-    out["ts_max"] = tm[config.num_partitions :]
-    sm = out.pop("sz_minmax")
-    out["sz_min"] = sm[: config.num_partitions]
-    out["sz_max"] = sm[config.num_partitions :]
-    return out
+    return _shape_tables(out, config)
 
 
 # ---------------------------------------------------------------------------
@@ -1431,55 +1276,18 @@ def unpack_device(buf, config: AnalyzerConfig):
     """uint8[N] → dict of typed device arrays (runs under jit)."""
     from kafka_topic_analyzer_tpu.jax_support import jnp, lax
 
-    b = config.batch_size
-
-    def cast(section, dtype):
-        itemsize = np.dtype(dtype).itemsize
-        if itemsize == 1:
-            return section.astype(dtype) if dtype != jnp.uint8 else section
-        return lax.bitcast_convert_type(
-            section.reshape(-1, itemsize), jnp.dtype(dtype)
-        )
-
     header = lax.bitcast_convert_type(buf[:HEADER_BYTES].reshape(4, 4), jnp.int32)
-    out = {"n_valid": header[0], "n_pairs": header[1]}
+    out = {"n_valid": header[0]}
     pos = HEADER_BYTES
-    for name, dtype, count in _sections(config, b):
-        nbytes = np.dtype(dtype).itemsize * count
-        out[name] = cast(buf[pos : pos + nbytes], dtype)
-        pos += nbytes
-
-    if config.wire_format == 5:
-        out["counts"] = out["counts"].reshape(config.num_partitions, 7)
-        if "qcounts" in out:
-            from kafka_topic_analyzer_tpu.ops.ddsketch import (
-                ddsketch_num_buckets,
+    for name, dtype, count in _sections(config, config.batch_size):
+        itemsize = np.dtype(dtype).itemsize
+        sec = buf[pos : pos + itemsize * count]
+        out[name] = (
+            sec
+            if itemsize == 1
+            else lax.bitcast_convert_type(
+                sec.reshape(-1, itemsize), jnp.dtype(dtype)
             )
-
-            out["qcounts"] = out["qcounts"].reshape(
-                -1, ddsketch_num_buckets(config.quantile_buckets)
-            )
-        tm = out.pop("ts_minmax")
-        out["ts_min"] = tm[: config.num_partitions]
-        out["ts_max"] = tm[config.num_partitions :]
-        sm = out.pop("sz_minmax")
-        out["sz_min"] = sm[: config.num_partitions]
-        out["sz_max"] = sm[config.num_partitions :]
-        return out
-
-    iota = jnp.arange(b, dtype=jnp.int32)
-    valid = iota < out["n_valid"]
-    flags = out.pop("flags")
-    out["key_null"] = (flags & 1).astype(bool)
-    out["value_null"] = (flags & 2).astype(bool)
-    out["valid"] = valid
-    out["partition"] = out["partition"].astype(jnp.int32)
-    out["key_len"] = out["key_len"].astype(jnp.int32)
-    out["value_len"] = out["value_len"].astype(jnp.int32)
-    tm = out.pop("ts_minmax")
-    out["ts_min"] = tm[: config.num_partitions]
-    out["ts_max"] = tm[config.num_partitions :]
-    sm = out.pop("sz_minmax")
-    out["sz_min"] = sm[: config.num_partitions]
-    out["sz_max"] = sm[config.num_partitions :]
-    return out
+        )
+        pos += itemsize * count
+    return _shape_tables(out, config)

@@ -9,17 +9,12 @@ count, not just the data axis (with the batch replicated over 'space',
 the old layout, every added space shard re-transferred and re-reduced
 the whole batch).
 
-Per-step communication is a single small ICI collective: the alive
-bitmap's host-deduped (slot, aliveness) pairs are all_gathered over
-'space' and applied in source-chunk order, because a slot's updates may
-straddle chunk boundaries and last-writer-wins is order-sensitive
-(backends/step.py).  Under alive-pair COMPACTION (the wire-v5 default —
-``AnalyzerConfig.compact_alive``, DESIGN §19) even that disappears: the
-host LWW-merges each data row's pairs per DISPATCH into one bounded
-table whose ``P(data, None)`` spec replicates it over 'space' at
-transfer time, and each space shard applies its slot range once after
-the (scanned) step — no per-step collective remains.  Everything else
-is chunk-local per step; the remaining axes reduce once, in finalize:
+No step runs a collective.  The host LWW-merges each data row's alive
+pairs per DISPATCH into one bounded table (DESIGN §19) whose
+``P(data, None)`` spec replicates it over 'space' at transfer time, and
+each space shard applies its slot range once after the (scanned) step.
+Everything else is chunk-local per step; the axes reduce once, in
+finalize:
 
 - counters / byte sums / counts : ``psum``   over ('data', 'space')
 - timestamp & size extremes     : ``pmin`` / ``pmax`` over ('data', 'space')
@@ -197,10 +192,10 @@ class PackedShard:
     sharded counterpart of ``backends.tpu.StagedBatch``.  Just a typed
     array: all bookkeeping stays with the decoded batch the engine holds.
 
-    ``pairs`` rides the compacted alive path: the row batch's LWW
+    ``pairs`` carries an alive-key scan's pairs: the row batch's LWW
     ``(slot u32[n], flag u8[n])`` host arrays in stream order (per-chunk
     deduped on the fused path — the dispatch merge resolves cross-chunk
-    duplicates), None when compaction is off."""
+    duplicates), None when the scan counts no alive keys."""
 
     __slots__ = ("chunks", "pairs")
 
@@ -230,10 +225,6 @@ class ShardedTpuBackend(MetricBackend):
         self.mesh = mesh if mesh is not None else make_mesh(*config.mesh_shape)
         device = self.mesh.devices.flat[0]
         require_device_platform(device)
-        if config.use_pallas_counters:
-            config = _dc.replace(
-                config, pallas_interpret=device.platform != "tpu"
-            )
         super().__init__(config)
         self.init_now_s = utc_now_seconds() if init_now_s is None else init_now_s
         if dict(zip(self.mesh.axis_names, self.mesh.devices.shape)) != {
@@ -251,17 +242,6 @@ class ShardedTpuBackend(MetricBackend):
         if config.batch_size % config.space_shards:
             raise ValueError(
                 "batch_size must divide evenly into space_shards chunks"
-            )
-        if (
-            config.use_pallas_counters
-            and config.wire_format == 4
-            and config.chunk_size % 1024
-        ):
-            # v4 MXU-kernel block constraint only; the v5 table merge
-            # (pallas_counters_merge) pads any shape internally.
-            raise ValueError(
-                "use_pallas_counters requires a per-space-shard chunk "
-                "(batch_size / space_shards) that is a multiple of 1024"
             )
         self._chunk_config = (
             _dc.replace(config, batch_size=config.chunk_size)
@@ -286,15 +266,14 @@ class ShardedTpuBackend(MetricBackend):
         self.snapshot_capable = not self._multiprocess or self._rows_contiguous
 
         chunk_config = self._chunk_config
-        # Compacted alive path (DESIGN.md §19): each data row ships ONE
-        # LWW-merged pair table per dispatch, replicated over the space
-        # axis by its P(data, None) spec — each space shard applies its
-        # slot range AFTER the scan, so the per-step pair all_gather over
-        # 'space' disappears from the compacted step entirely.
-        self._compact = config.compact_alive
+        # Alive tables (DESIGN.md §19): each data row ships ONE
+        # LWW-merged table per dispatch, replicated over the space axis by
+        # its P(data, None) spec — each space shard applies its slot range
+        # AFTER the scan.
+        self._alive = config.count_alive_keys
         self._pair_cap1 = (
             pair_table_capacity(config, config.batch_size, 1)
-            if self._compact
+            if self._alive
             else 0
         )
         self._pair_sharding = NamedSharding(self.mesh, P(DATA_AXIS, None))
@@ -308,13 +287,7 @@ class ShardedTpuBackend(MetricBackend):
             local = jax.tree.map(lambda x: x[0], state)
             arrays = unpack_device(bufs[0, 0], chunk_config)
             space_idx = lax.axis_index(SPACE_AXIS)
-            new = analyzer_step(
-                local,
-                arrays,
-                chunk_config,
-                space_index=space_idx,
-                space_axis=SPACE_AXIS,
-            )
+            new = analyzer_step(local, arrays, chunk_config)
             if ptabs is not None:
                 new = apply_pair_table(
                     new,
@@ -326,23 +299,15 @@ class ShardedTpuBackend(MetricBackend):
                 )
             return jax.tree.map(lambda x: x[None], new)
 
-        # The Pallas counter kernel declares its varying axes (vma) so the
-        # static checker stays on for compiled TPU runs; jax's pallas
-        # INTERPRETER (what a mesh off the TPU runs) internally builds
-        # replicated constants that trip the checker — a jax-side
-        # limitation its own error message acknowledges — so only that
-        # combination relaxes it.
-        relax_vma = config.use_pallas_counters and config.pallas_interpret
         step = jax.shard_map(
             _step_body,
             mesh=self.mesh,
             in_specs=(
                 (self._specs, P(DATA_AXIS, SPACE_AXIS), P(DATA_AXIS, None))
-                if self._compact
+                if self._alive
                 else (self._specs, P(DATA_AXIS, SPACE_AXIS))
             ),
             out_specs=self._specs,
-            check_vma=not relax_vma,
         )
         self._step = jax.jit(step, donate_argnums=(0,))
         self._merge = jax.jit(self._build_merge())
@@ -350,11 +315,9 @@ class ShardedTpuBackend(MetricBackend):
         # Superbatch dispatch layer: K rounds of per-row chunk stacks
         # folded by ONE scanned collective dispatch (state donated once
         # per superbatch).  The scanned axis is the ROUND axis: scan step
-        # k replays exactly what the per-round collective step would have
-        # done at round k — including the alive-pair all_gather over
-        # 'space', which runs once per scan step in step order, so
-        # last-writer-wins application order is preserved across the
-        # scanned axis and results stay byte-identical.
+        # k replays exactly what the per-round step would have done at
+        # round k, and each row's alive table — the LWW merge of its K
+        # rounds — applies once after the scan.
         self.dispatch_config = dispatch if dispatch is not None else DispatchConfig()
         self.superbatch_k = self.dispatch_config.resolve(config.batch_size)
         self.dispatch_depth = self.dispatch_config.depth
@@ -363,7 +326,7 @@ class ShardedTpuBackend(MetricBackend):
                 pair_table_capacity(
                     config, config.batch_size, self.superbatch_k
                 )
-                if self._compact
+                if self._alive
                 else 0
             )
 
@@ -378,10 +341,8 @@ class ShardedTpuBackend(MetricBackend):
                     lambda buf: unpack_device(buf[0, 0], chunk_config),
                     chunk_config,
                     space_index=space_idx,
-                    space_axis=SPACE_AXIS,
-                    # Compacted path: the row's K rounds merged into one
-                    # table, applied once after the scanned rounds — the
-                    # per-scan-step pair all_gather is gone.
+                    # The row's K rounds merged into one table, applied
+                    # once after the scanned rounds.
                     pairs=(
                         unpack_pair_table_device(
                             ptabs[0], config, pair_cap_k
@@ -404,11 +365,10 @@ class ShardedTpuBackend(MetricBackend):
                         P(None, DATA_AXIS, SPACE_AXIS),
                         P(DATA_AXIS, None),
                     )
-                    if self._compact
+                    if self._alive
                     else (self._specs, P(None, DATA_AXIS, SPACE_AXIS))
                 ),
                 out_specs=(self._specs, P(DATA_AXIS, SPACE_AXIS)),
-                check_vma=not relax_vma,
             )
             self._superstep = jax.jit(superstep, donate_argnums=(0,))
             self._superbuf_sharding = NamedSharding(
@@ -514,7 +474,7 @@ class ShardedTpuBackend(MetricBackend):
         )
 
     def _row_pairs(self, batch: "Optional[RecordBatch]"):
-        """One data row's LWW pairs for the compacted path (None rows —
+        """One data row's LWW alive pairs (None rows —
         another process's, or identity pads — contribute none)."""
         if batch is None or len(batch) == 0:
             return (np.empty(0, np.uint32), np.empty(0, np.uint8))
@@ -547,10 +507,10 @@ class ShardedTpuBackend(MetricBackend):
         """Pack one data row's batch ahead of its collective step — safe on
         a prefetch worker thread (pure numpy/C++), so the S-way chunk
         packing of every row overlaps the device's current step instead of
-        serializing in front of update_shards (engine staging).  Compacted
-        alive configs dedupe the row's pairs here too (the dispatch merges
-        them per row)."""
-        if self._compact:
+        serializing in front of update_shards (engine staging).  Alive-key
+        scans dedupe the row's pairs here too (the dispatch merges them per
+        row)."""
+        if self._alive:
             return PackedShard(
                 self._pack_chunks(batch), self._row_pairs(batch)
             )
@@ -561,9 +521,9 @@ class ShardedTpuBackend(MetricBackend):
         ``[S, chunk_nbytes]`` chunk stacks — records fill chunk 0..S-1 at
         chunk_size each, the exact ``pack_chunks`` rule, so a fused row
         is byte-for-byte what ``prepare_shard`` would have staged.  One
-        sink per fed data row's ingest stream (engine.run_scan).  Under
-        compaction the sink hands the row's harvested pairs to the staged
-        form (PackedShard.pairs)."""
+        sink per fed data row's ingest stream (engine.run_scan).  Alive-key
+        scans' sinks hand the row's harvested pairs to the staged form
+        (PackedShard.pairs)."""
         from kafka_topic_analyzer_tpu.packing import FusedPackSink
 
         return FusedPackSink(
@@ -603,7 +563,7 @@ class ShardedTpuBackend(MetricBackend):
             )
         else:
             bufs = jax.device_put(per_shard, self._buf_sharding)
-        if self._compact:
+        if self._alive:
             tables = self._pack_row_pair_tables(
                 [
                     [
@@ -673,7 +633,7 @@ class ShardedTpuBackend(MetricBackend):
             )
         else:
             bufs = jax.device_put(stacked, self._superbuf_sharding)
-        if self._compact:
+        if self._alive:
             # Per-row LWW merge across the superbatch's K rounds, in round
             # order — the scanned steps then fold pair-free and each row's
             # table applies once after the scan (identity-pad rounds

@@ -419,8 +419,7 @@ def render_telemetry_stats(
         lines.append(line)
     # Packed wire-format digest (results.WireStats, engine-built): which
     # format the scan's device buffers used, the actual bytes/record, and
-    # the fold-table vs per-record split — the v4→v5 combiner saving as a
-    # measured number, not a layout inference.
+    # the fold-table vs per-record split, as measured numbers.
     if wire is not None:
         lines.append(
             f"  wire-format: v{wire.format}, "
@@ -431,16 +430,13 @@ def render_telemetry_stats(
             f"-record buffer"
         )
         # Alive-pair compaction line (DESIGN §19): the measured
-        # raw→emitted dedupe of the per-dispatch pair tables, or — never
-        # silently — why an alive-key scan ran uncompacted.
+        # raw→emitted dedupe of the per-dispatch alive tables.
         if wire.alive_compaction == "on":
             lines.append(
                 f"  alive-compaction: on — {wire.pairs_raw:,} raw pairs "
                 f"→ {wire.pairs_emitted:,} emitted "
                 f"(ratio {wire.compaction_ratio:.3f})"
             )
-        elif wire.alive_compaction != "n/a":
-            lines.append(f"  alive-compaction: {wire.alive_compaction}")
     # Fused ingest digest: rows/records through the one-pass native
     # decode→pack, and — never silently — everything that bypassed it,
     # by reason (compressed/legacy frames, salvage, missing shim).

@@ -136,10 +136,10 @@ class WireStats:
     wire line and the ``--json`` ``wire`` block).  Built by the engine
     from the backend's config (``packing.section_byte_split`` — the byte
     split derives from the one layout source, lint rule 7) plus the scan's
-    ``kta_wire_bytes_total`` delta, so the v4→v5 saving is observable per
+    ``kta_wire_bytes_total`` delta, so bytes per record are measured per
     scan, not inferred from the layout."""
 
-    #: Wire format the scan's packed buffers used (4 or 5).
+    #: Wire format the scan's packed buffers used (packing.WIRE_FORMAT).
     format: int
     #: Records per packed buffer (batch or chunk size).
     batch_size: int
@@ -152,9 +152,8 @@ class WireStats:
     bytes_total: int = 0
     #: Valid records the scan folded (denominator for bytes/record).
     records: int = 0
-    #: Alive-pair compaction state (DESIGN §19): ``on``, ``off`` (with the
-    #: resolved reason — explicit / env-kill-switch / wire-v4), or ``n/a``
-    #: for scans without the alive bitmap.
+    #: Alive-pair compaction (DESIGN §19): ``on`` for alive-key scans,
+    #: ``n/a`` for scans without the alive bitmap.
     alive_compaction: str = "n/a"
     #: Per-batch LWW pairs entering the dispatch-level compaction merge
     #: (``kta_alive_pairs_raw_total`` delta for this scan).

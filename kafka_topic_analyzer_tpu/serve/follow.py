@@ -9,7 +9,7 @@ per-partition offset-ordered (DESIGN.md §2), so a chain of passes over
 ``[cursor, head)`` windows folds to byte-identical state as one batch
 scan stopped at the same offsets — and every composition the engine
 already knows (superbatch dispatch, parallel ingest fan-ins, the sharded
-mesh, wire-v5 combiner rows) rides along untouched, because the service
+mesh, the combiner rows) rides along untouched, because the service
 never re-implements the drive loop, it just re-enters it.
 
 Pass mechanics (the engine's follow hooks, engine.run_scan docstring):

@@ -419,14 +419,6 @@ def run(argv: "list[str] | None" = None) -> int:
                     help="advertised broker nodes (partition p led by "
                          "p %% N) — exercises leader-parallel fetching")
     ap.add_argument("--alive-bits", type=int, default=26)
-    ap.add_argument("--wire-format", choices=["v4", "v5"], default="v5",
-                    help="Packed wire format referee (BENCH round 11): v5 "
-                         "combiner rows vs v4 per-record columns")
-    ap.add_argument("--alive-compaction", choices=["auto", "off"],
-                    default="auto",
-                    help="alive-pair compaction referee (BENCH round 13): "
-                         "'auto' = one bounded per-dispatch pair table, "
-                         "'off' = per-row pair sections + in-scan scatter")
     ap.add_argument("--superbatch", default="1", metavar="K|auto",
                     help="stack K packed batches per jitted scan dispatch "
                          "(tpu backend; 'auto' targets 2^20 records per "
@@ -511,8 +503,6 @@ def run(argv: "list[str] | None" = None) -> int:
         enable_hll="hll" in feats,
         enable_quantiles="quantiles" in feats,
         mesh_shape=mesh_shape,
-        wire_format={"v4": 4, "v5": 5}[args.wire_format],
-        alive_compaction=args.alive_compaction,
     )
     # Same validation as the CLI (cli.resolve_dispatch): an explicit
     # --superbatch K>1 on the cpu backend is rejected, never silently

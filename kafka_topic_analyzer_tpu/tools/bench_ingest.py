@@ -211,7 +211,7 @@ def measure_pipeline_fused(record_sets: "list[bytes]", total_records: int,
                            config) -> "tuple[int, float]":
     """The FUSED referee: the same record sets through
     FusedPackSink.append_record_set — one native pass from set bytes to
-    wire-v4 rows, no SoA columns, no re-batching copy."""
+    packed rows, no SoA columns, no re-batching copy."""
     from kafka_topic_analyzer_tpu.io.native import scan_record_set_native
     from kafka_topic_analyzer_tpu.packing import FusedPackSink
 
@@ -342,7 +342,7 @@ def main(argv: "list[str] | None" = None) -> int:
 
     # --- 3a: fused vs chained decode→pack referee ------------------------
     # The ISSUE-8 headline: one native pass from record-set bytes to
-    # wire-v4 rows vs decode→SoA columns→re-batch→pack.  Same buffers,
+    # packed rows vs decode→SoA columns→re-batch→pack.  Same buffers,
     # same acceptance window, same pack config (alive bitmap + HLL).
     if args.fused and native_ok:
         pcfg = _bench_pack_config(args.partitions, args.batch_size)

@@ -42,13 +42,12 @@ def timed_step_loop(config, feed, *, steps: int) -> dict:
     state = AnalyzerState.init(config)
     step = jax.jit(make_packed_step(config), donate_argnums=(0,))
 
-    # Compacted alive configs take a pair-table buffer per step.  The
-    # feed is already-packed rows (no decoded batch to dedupe), so the
-    # loop ships identity (empty) tables — the device cost is shape-
-    # static under jit, so the timed rate still includes the full
-    # per-dispatch pair-apply work.
+    # Alive-key configs take an alive-table buffer per step.  The feed is
+    # already-packed rows (no decoded batch to dedupe), so the loop ships
+    # identity (empty) tables — the device cost is shape-static under jit,
+    # so the timed rate still includes the full per-dispatch apply work.
     pair_feed = None
-    if config.compact_alive:
+    if config.count_alive_keys:
         from kafka_topic_analyzer_tpu.packing import (
             pack_pair_table,
             pair_table_capacity,

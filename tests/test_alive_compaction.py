@@ -1,15 +1,14 @@
-"""Host-side LWW alive-pair compaction (ISSUE 12, DESIGN §19).
+"""Host-side LWW alive-pair compaction (DESIGN §19): alive pairs leave
+the rows and ship as ONE LWW-merged per-dispatch table applied after the
+scan.
 
-The byte-identity bar: a compacted scan (``--alive-compaction auto``, the
-wire-v5 default — pairs leave the per-row sections and ship as ONE
-LWW-merged per-dispatch table applied after the scan) must equal the
-uncompacted scan byte-for-byte across (wire, segfile) × workers × K ×
-mesh, under corruption/quarantine rewind, across resume, and across
-follow passes.  The algebra bar: host compaction ∘ device merge must
-equal the uncompacted per-record fold over generated update streams —
-duplicate slots within and across frames, tombstone↔set flips, arbitrary
-batch and superbatch splits — for BOTH the native and numpy packers
-(the hypothesis property test).
+The identity bar: a scan's full document must equal the CPU oracle's
+(tests/oracle.py) across (wire, segfile) × workers × K × mesh, under
+corruption/quarantine rewind, and across follow passes.  The algebra bar:
+host compaction ∘ device merge must equal the per-record fold over
+generated update streams — duplicate slots within and across frames,
+tombstone↔set flips, arbitrary batch and superbatch splits — for BOTH the
+native and numpy packers (the hypothesis property test).
 """
 
 from __future__ import annotations
@@ -22,6 +21,7 @@ import time
 import numpy as np
 import pytest
 
+from kafka_topic_analyzer_tpu.backends.cpu import CpuExactBackend
 from kafka_topic_analyzer_tpu.backends.tpu import TpuBackend
 from kafka_topic_analyzer_tpu.config import (
     AnalyzerConfig,
@@ -35,17 +35,16 @@ from kafka_topic_analyzer_tpu.io.synthetic import SyntheticSource, SyntheticSpec
 from kafka_topic_analyzer_tpu.obs.registry import default_registry
 from kafka_topic_analyzer_tpu.packing import (
     batch_alive_pairs,
-    pack_batch,
     pack_pair_table,
     packed_nbytes,
     pair_table_capacity,
     pair_table_nbytes,
-    unpack_numpy,
     unpack_pair_table_numpy,
 )
 from kafka_topic_analyzer_tpu.records import RecordBatch
 
 from fake_broker import CorruptionInjector, FakeBroker
+from oracle import sketched
 
 pytestmark = pytest.mark.alivecompact
 
@@ -77,7 +76,7 @@ N_REC = 300
 RECORDS = {p: _mk_records(p, N_REC) for p in range(N_PARTS)}
 
 
-def _cfg(compaction: str, **kw) -> AnalyzerConfig:
+def _cfg(**kw) -> AnalyzerConfig:
     base = dict(
         num_partitions=N_PARTS,
         batch_size=128,
@@ -86,15 +85,14 @@ def _cfg(compaction: str, **kw) -> AnalyzerConfig:
         enable_hll=True,
         hll_p=8,
         enable_quantiles=True,
-        wire_format=5,
     )
     base.update(kw)
-    return AnalyzerConfig(alive_compaction=compaction, **base)
+    return AnalyzerConfig(**base)
 
 
-def _full_doc(result) -> dict:
+def _full_doc(result, metrics=None) -> dict:
     return {
-        "metrics": result.metrics.to_dict(
+        "metrics": (metrics or result.metrics).to_dict(
             result.start_offsets, result.end_offsets
         ),
         "start": result.start_offsets,
@@ -104,30 +102,43 @@ def _full_doc(result) -> dict:
     }
 
 
-def _wire_scan(compaction, workers=1, superbatch=1, backend_cls=TpuBackend,
+def _backend(cfg, backend_cls, superbatch=1):
+    if backend_cls is CpuExactBackend:
+        return CpuExactBackend(cfg, init_now_s=10**10)
+    return backend_cls(
+        cfg, init_now_s=10**10, dispatch=DispatchConfig(superbatch=superbatch)
+    )
+
+
+def _doc(result, backend) -> dict:
+    """The scan's document; the oracle's with its sketches taken."""
+    if isinstance(backend, CpuExactBackend):
+        return _full_doc(result, sketched(backend, result.metrics))
+    return _full_doc(result)
+
+
+def _wire_scan(workers=1, superbatch=1, backend_cls=TpuBackend,
                mesh=None, **cfg_kw):
-    cfg = _cfg(compaction, **cfg_kw)
+    """(result, document) of one scan of the fake broker's topic."""
+    cfg = _cfg(**cfg_kw)
     if mesh is not None:
         cfg = dataclasses.replace(cfg, mesh_shape=mesh)
     with FakeBroker(TOPIC, RECORDS, max_records_per_fetch=60) as broker:
         src = KafkaWireSource(
             f"127.0.0.1:{broker.port}", TOPIC, overrides=dict(FAST_RETRY)
         )
-        backend = backend_cls(
-            cfg, init_now_s=10**10,
-            dispatch=DispatchConfig(superbatch=superbatch),
-        )
+        backend = _backend(cfg, backend_cls, superbatch)
         result = run_scan(
             TOPIC, src, backend, cfg.batch_size, ingest_workers=workers
         )
         src.close()
-    return result
+    return result, _doc(result, backend)
 
 
 @pytest.fixture(scope="module")
-def uncompacted_baseline():
-    """The --alive-compaction off scan — the byte-exact referee."""
-    return _full_doc(_wire_scan("off"))
+def alive_oracle():
+    """The CPU oracle's document — the referee for every device scan."""
+    return _wire_scan(backend_cls=CpuExactBackend)[1]
 
 
 # ---------------------------------------------------------------------------
@@ -137,11 +148,9 @@ def uncompacted_baseline():
 @pytest.mark.parametrize("workers,superbatch", [
     (1, 1), (4, 1), (1, 4), (4, 4),
 ])
-def test_compacted_wire_scan_identical(
-    uncompacted_baseline, workers, superbatch
-):
-    result = _wire_scan("auto", workers=workers, superbatch=superbatch)
-    assert _full_doc(result) == uncompacted_baseline
+def test_compacted_wire_scan_identical(alive_oracle, workers, superbatch):
+    result, doc = _wire_scan(workers=workers, superbatch=superbatch)
+    assert doc == alive_oracle
     assert result.wire is not None
     assert result.wire.alive_compaction == "on"
     assert result.wire.pairs_emitted > 0
@@ -158,37 +167,43 @@ def test_compacted_wire_scan_identical(
 @pytest.mark.parametrize("mesh,superbatch", [
     ((2, 1), 1), ((2, 1), 4), ((2, 2), 1), ((2, 2), 4),
 ])
-def test_compacted_sharded_scan_identical(
-    uncompacted_baseline, mesh, superbatch
-):
+def test_compacted_sharded_scan_identical(alive_oracle, mesh, superbatch):
     from kafka_topic_analyzer_tpu.parallel.sharded import ShardedTpuBackend
 
-    for compaction in ("off", "auto"):
-        result = _wire_scan(
-            compaction, mesh=mesh, superbatch=superbatch,
-            backend_cls=ShardedTpuBackend,
-        )
-        assert _full_doc(result) == uncompacted_baseline, (mesh, compaction)
+    _, doc = _wire_scan(mesh=mesh, superbatch=superbatch,
+                        backend_cls=ShardedTpuBackend)
+    assert doc == alive_oracle, mesh
+
+
+def _lww_map(batch, bits: int) -> dict:
+    """Per-slot last writer of one batch, replayed record by record."""
+    out = {}
+    for i in range(batch.num_valid):
+        if not batch.key_null[i]:
+            slot = int(batch.key_hash32[i]) & ((1 << bits) - 1)
+            out[slot] = 0 if batch.value_null[i] else 1
+    return out
 
 
 def test_compacted_rows_drop_pair_sections():
-    """The wire saving is structural: compacted v5 rows carry NO alive
-    sections (5 B/record gone), and the per-dispatch pair table is the
-    only place pairs travel — with identical LWW content."""
+    """The rows carry NO alive sections — counting alive keys adds no row
+    bytes — and the per-dispatch table is the only place pairs travel,
+    holding exactly the batch's per-slot last writers."""
     from kafka_topic_analyzer_tpu.packing import _sections
 
-    cfg_on = _cfg("auto")
-    cfg_off = _cfg("off")
+    cfg_on = _cfg()
     names_on = {n for n, _, _ in _sections(cfg_on, 128)}
-    assert "alive_slot" not in names_on and "alive_flag" not in names_on
-    assert (packed_nbytes(cfg_off, 128) - packed_nbytes(cfg_on, 128)
-            == 128 * 5)
+    assert not {n for n in names_on if n.startswith("alive")}
+    assert packed_nbytes(cfg_on, 128) == packed_nbytes(
+        _cfg(count_alive_keys=False), 128
+    )
 
     spec = SyntheticSpec(
         num_partitions=2, messages_per_partition=200,
         keys_per_partition=11, tombstone_permille=300, seed=9,
     )
     batch = next(SyntheticSource(spec).batches(128))
+    want = _lww_map(batch, cfg_on.alive_bitmap_bits)
     for use_native in (False, True):
         if use_native:
             native = pytest.importorskip(
@@ -196,12 +211,6 @@ def test_compacted_rows_drop_pair_sections():
             )
             if not native.native_available():
                 pytest.skip("native shim unavailable")
-        row = pack_batch(batch, cfg_on, use_native=use_native)
-        assert int(unpack_numpy(row.copy(), cfg_on)["n_pairs"]) == 0
-        off_row = unpack_numpy(
-            pack_batch(batch, cfg_off, use_native=use_native).copy(), cfg_off
-        )
-        n_off = int(off_row["n_pairs"])
         cap = pair_table_capacity(cfg_on, 128, 1)
         tbl, raw, emitted = pack_pair_table(
             [batch_alive_pairs(batch, cfg_on, use_native)],
@@ -209,9 +218,9 @@ def test_compacted_rows_drop_pair_sections():
         )
         assert tbl.nbytes == pair_table_nbytes(cfg_on, cap)
         ut = unpack_pair_table_numpy(tbl, cfg_on, cap)
-        assert int(ut["n_pairs"]) == n_off == emitted
+        assert int(ut["n_pairs"]) == len(want) == emitted
         # bits=16 picks the MASK form: reconstruct the per-slot LWW map
-        # from the set/clear words and compare against the off-path pairs.
+        # from the set/clear words and compare against the replay.
         assert "alive_set" in ut
         got = {}
         for w, (sw, cw) in enumerate(zip(
@@ -223,9 +232,7 @@ def test_compacted_rows_drop_pair_sections():
                     got[w * 32 + bit] = 1
                 elif cw & (1 << bit):
                     got[w * 32 + bit] = 0
-        assert got == dict(
-            zip(off_row["alive_slot"][:n_off].tolist(),
-                off_row["alive_flag"][:n_off].tolist()))
+        assert got == want
 
 
 # ---------------------------------------------------------------------------
@@ -250,25 +257,20 @@ def test_compacted_segfile_scan_identical(tmp_path):
         writer.append(b)
     writer.close()
 
-    def scan(compaction, workers=1, superbatch=1):
+    def scan(backend_cls=TpuBackend, workers=1, superbatch=1):
         cfg = AnalyzerConfig(
             num_partitions=3, batch_size=128, count_alive_keys=True,
             alive_bitmap_bits=14, enable_hll=True, hll_p=8,
-            wire_format=5, alive_compaction=compaction,
         )
         s = SegmentFileSource(d, "seg.topic")
-        r = run_scan(
-            "seg.topic", s,
-            TpuBackend(cfg, init_now_s=10**10,
-                       dispatch=DispatchConfig(superbatch=superbatch)),
-            128, ingest_workers=workers,
-        )
-        return _full_doc(r)
+        backend = _backend(cfg, backend_cls, superbatch)
+        r = run_scan("seg.topic", s, backend, 128, ingest_workers=workers)
+        return _doc(r, backend)
 
-    base = scan("off")
-    assert scan("auto") == base
-    assert scan("auto", workers=2) == base
-    assert scan("auto", superbatch=4) == base
+    base = scan(CpuExactBackend)
+    assert scan() == base
+    assert scan(workers=2) == base
+    assert scan(superbatch=4) == base
 
 
 # ---------------------------------------------------------------------------
@@ -276,10 +278,10 @@ def test_compacted_segfile_scan_identical(tmp_path):
 
 
 def test_compacted_corruption_quarantine_parity(tmp_path):
-    """Deterministic poison under --on-corruption=quarantine: the
-    compacted scan classifies, accounts, and quarantines EXACTLY like the
-    uncompacted one — frame rewind must leave the pair emission region as
-    atomic as the row sections (pairs only emit after a frame validates)."""
+    """Deterministic poison under --on-corruption=quarantine: the device
+    scan classifies, accounts, and quarantines EXACTLY like the oracle —
+    frame rewind must leave the pair emission region as atomic as the row
+    sections (pairs only emit after a frame validates)."""
     def poisoned():
         inj = (
             CorruptionInjector()
@@ -291,8 +293,8 @@ def test_compacted_corruption_quarantine_parity(tmp_path):
             honor_partition_max_bytes=True,
         )
 
-    def run(compaction, qdir, superbatch=1):
-        cfg = _cfg(compaction)
+    def run(backend_cls, qdir, superbatch=1):
+        cfg = _cfg()
         with poisoned() as broker:
             src = KafkaWireSource(
                 f"127.0.0.1:{broker.port}", TOPIC,
@@ -301,88 +303,27 @@ def test_compacted_corruption_quarantine_parity(tmp_path):
                     policy="quarantine", quarantine_dir=qdir
                 ),
             )
-            r = run_scan(
-                TOPIC, src,
-                TpuBackend(cfg, init_now_s=10**10,
-                           dispatch=DispatchConfig(superbatch=superbatch)),
-                128,
-            )
+            backend = _backend(cfg, backend_cls, superbatch)
+            r = run_scan(TOPIC, src, backend, 128)
             spans = src.corruption_spans()
             src.close()
-        return _full_doc(r), spans
+        return _doc(r, backend), spans
 
-    doc_off, spans_off = run("off", str(tmp_path / "qoff"))
-    doc_on, spans_on = run("auto", str(tmp_path / "qon"))
-    doc_on_k, spans_on_k = run("auto", str(tmp_path / "qonk"), superbatch=4)
-    assert doc_on == doc_off
-    assert doc_on_k == doc_off
+    doc_ref, spans_ref = run(CpuExactBackend, str(tmp_path / "qref"))
+    doc_on, spans_on = run(TpuBackend, str(tmp_path / "qon"))
+    doc_on_k, spans_on_k = run(TpuBackend, str(tmp_path / "qonk"),
+                               superbatch=4)
+    assert doc_on == doc_ref
+    assert doc_on_k == doc_ref
     assert sorted(doc_on["corrupt"]) == [1, 2]
-    assert spans_on == spans_off == spans_on_k
+    assert spans_on == spans_ref == spans_on_k
     assert sorted(os.listdir(tmp_path / "qon")) == sorted(
-        os.listdir(tmp_path / "qoff")
+        os.listdir(tmp_path / "qref")
     )
 
 
 # ---------------------------------------------------------------------------
-# cross-config resume (compaction is execution strategy)
-
-
-class _Interrupt(Exception):
-    pass
-
-
-class _InterruptingSource(SyntheticSource):
-    def __init__(self, spec, limit):
-        super().__init__(spec)
-        self.limit = limit
-
-    def batches(self, batch_size, partitions=None, start_at=None):
-        it = super().batches(batch_size, partitions, start_at)
-        for i, b in enumerate(it):
-            if start_at is None and i >= self.limit:
-                raise _Interrupt()
-            yield b
-
-
-RESUME_SPEC = SyntheticSpec(
-    num_partitions=3, messages_per_partition=2_000, keys_per_partition=80,
-    tombstone_permille=250, seed=31,
-)
-
-
-@pytest.mark.parametrize("first,second", [("auto", "off"), ("off", "auto")])
-def test_cross_compaction_resume(tmp_path, first, second):
-    """A snapshot taken mid-scan with compaction one way resumes the
-    other way, reproducing the uninterrupted scan exactly — the setting
-    is execution strategy, outside the checkpoint fingerprint."""
-    cfg_first = AnalyzerConfig(
-        num_partitions=3, batch_size=512, count_alive_keys=True,
-        alive_bitmap_bits=18, enable_hll=True, hll_p=10,
-        wire_format=5, alive_compaction=first,
-    )
-    cfg_second = dataclasses.replace(cfg_first, alive_compaction=second)
-    full = run_scan(
-        "t", SyntheticSource(RESUME_SPEC),
-        TpuBackend(cfg_second, init_now_s=10**10), 512,
-    ).metrics.to_dict(None, None)
-
-    with pytest.raises(_Interrupt):
-        run_scan(
-            "t", _InterruptingSource(RESUME_SPEC, limit=5),
-            TpuBackend(cfg_first, init_now_s=10**10,
-                       dispatch=DispatchConfig(superbatch=2)), 512,
-            snapshot_dir=str(tmp_path), snapshot_every_s=0.0,
-        )
-    resumed = run_scan(
-        "t", SyntheticSource(RESUME_SPEC),
-        TpuBackend(cfg_second, init_now_s=0), 512,
-        snapshot_dir=str(tmp_path), resume=True,
-    )
-    assert resumed.metrics.to_dict(None, None) == full
-
-
-# ---------------------------------------------------------------------------
-# follow mode: pass-chained folds with compaction on == batch scan
+# follow mode: pass-chained folds == batch scan
 
 
 def _wait_for(predicate, timeout_s=30.0, interval_s=0.01, what="condition"):
@@ -401,8 +342,8 @@ def test_follow_compacted_matches_batch():
     phase2 = {p: RECORDS[p][200:] for p in range(N_PARTS)}
     total = N_PARTS * N_REC
 
-    def followed(compaction):
-        cfg = _cfg(compaction, batch_size=64)
+    def followed():
+        cfg = _cfg(batch_size=64)
         follow = FollowConfig(
             poll_interval_s=0.02, idle_backoff_max_s=0.05, window_count=0
         )
@@ -447,9 +388,10 @@ def test_follow_compacted_matches_batch():
                 raise errors[0]
         return result.metrics.to_dict(None, None)
 
-    batch = _wire_scan("off").metrics.to_dict(None, None)
-    assert followed("auto") == batch
-    assert followed("off") == batch
+    want = _wire_scan(backend_cls=CpuExactBackend)[1]["metrics"]
+    for row in want["partitions"].values():
+        del row["start_offset"], row["end_offset"]
+    assert followed() == want
 
 
 # ---------------------------------------------------------------------------
@@ -564,7 +506,7 @@ def test_compaction_algebra_matches_per_record_fold():
             bits = 26
         cfg = AnalyzerConfig(
             num_partitions=1, batch_size=128, count_alive_keys=True,
-            alive_bitmap_bits=bits, wire_format=5,
+            alive_bitmap_bits=bits,
         )
         ref = _reference_alive_count(batches, bits)
         for nat in ([False, True] if use_native else [False]):
@@ -616,7 +558,7 @@ def test_compaction_algebra_seeded_sweep():
             bits = 26  # the bounded PAIR form (masks past the trade cap)
         cfg = AnalyzerConfig(
             num_partitions=1, batch_size=128, count_alive_keys=True,
-            alive_bitmap_bits=bits, wire_format=5,
+            alive_bitmap_bits=bits,
         )
         from kafka_topic_analyzer_tpu.packing import alive_table_mode
         assert alive_table_mode(cfg, pair_table_capacity(cfg, 128, k)) == (
@@ -648,98 +590,74 @@ def _metric_total(name: str) -> float:
 
 
 def test_compaction_resolution_and_kill_switches(monkeypatch):
-    on = AnalyzerConfig(num_partitions=2, batch_size=64,
-                        count_alive_keys=True)
-    assert on.compact_alive and on.alive_compaction_off_reason is None
+    """Compaction runs iff the scan counts alive keys (-c): no option or
+    env variable selects anything else."""
+    def scan(**kw):
+        spec = SyntheticSpec(num_partitions=2, messages_per_partition=100,
+                             keys_per_partition=9, seed=4)
+        cfg = AnalyzerConfig(num_partitions=2, batch_size=64, **kw)
+        return run_scan("t", SyntheticSource(spec),
+                        TpuBackend(cfg, init_now_s=10**10), 64)
 
-    off = AnalyzerConfig(num_partitions=2, batch_size=64,
-                         count_alive_keys=True, alive_compaction="off")
-    assert not off.compact_alive
-    assert off.alive_compaction_off_reason == "explicit"
-
-    v4 = AnalyzerConfig(num_partitions=2, batch_size=64,
-                        count_alive_keys=True, wire_format=4)
-    assert not v4.compact_alive
-    assert v4.alive_compaction_off_reason == "wire-v4"
-
-    monkeypatch.setenv("KTA_DISABLE_COMPACTION", "1")
-    env = AnalyzerConfig(num_partitions=2, batch_size=64,
-                         count_alive_keys=True)
-    assert not env.compact_alive
-    assert env.alive_compaction_off_reason == "env-kill-switch"
-    monkeypatch.delenv("KTA_DISABLE_COMPACTION")
-
-    no_alive = AnalyzerConfig(num_partitions=2, batch_size=64)
-    assert not no_alive.compact_alive
-    assert no_alive.alive_compaction_off_reason is None
-
-    with pytest.raises(ValueError, match="alive_compaction"):
-        AnalyzerConfig(num_partitions=2, batch_size=64,
-                       alive_compaction="maybe")
+    monkeypatch.setenv("KTA_DISABLE_COMPACTION", "1")  # ignored
+    assert scan(count_alive_keys=True).wire.alive_compaction == "on"
+    assert scan().wire.alive_compaction == "n/a"
+    with pytest.raises(TypeError):
+        AnalyzerConfig(num_partitions=2, alive_compaction="off")
 
 
 def test_pair_counters_and_fallback_booked():
     before_raw = _metric_total("kta_alive_pairs_raw_total")
     before_em = _metric_total("kta_alive_pairs_emitted_total")
-    before_off = _metric_total("kta_alive_compaction_off_total")
 
-    result = _wire_scan("auto", superbatch=4)
+    result, _ = _wire_scan(superbatch=4)
     raw = _metric_total("kta_alive_pairs_raw_total") - before_raw
     em = _metric_total("kta_alive_pairs_emitted_total") - before_em
     assert raw > 0 and 0 < em <= raw
     assert result.wire.pairs_raw == int(raw)
     assert result.wire.pairs_emitted == int(em)
-    assert _metric_total("kta_alive_compaction_off_total") == before_off
-
-    off_result = _wire_scan("off")
-    assert (
-        _metric_total("kta_alive_compaction_off_total") == before_off + 1
+    assert "kta_alive_compaction_off_total" not in (
+        default_registry().snapshot()
     )
-    assert off_result.wire.alive_compaction == "off (explicit)"
-    assert off_result.wire.pairs_raw == 0
 
 
 def test_stats_compaction_line_renders():
     from kafka_topic_analyzer_tpu.report import render_telemetry_stats
 
-    result = _wire_scan("auto", superbatch=4)
+    result, _ = _wire_scan(superbatch=4)
     text = render_telemetry_stats(result.telemetry, wire=result.wire)
     assert "alive-compaction: on" in text
     assert "ratio" in text
-
-    off = _wire_scan("off")
-    text_off = render_telemetry_stats(off.telemetry, wire=off.wire)
-    assert "alive-compaction: off (explicit)" in text_off
-    assert off.wire.as_dict()["alive_compaction"] == "off (explicit)"
     doc = result.wire.as_dict()
     assert doc["alive_pairs_raw"] == result.wire.pairs_raw
     assert 0 < doc["alive_compaction_ratio"] <= 1
 
+    no_alive, _ = _wire_scan(count_alive_keys=False)
+    text_off = render_telemetry_stats(no_alive.telemetry, wire=no_alive.wire)
+    assert "alive-compaction" not in text_off
+    assert no_alive.wire.as_dict()["alive_compaction"] == "n/a"
+
 
 def test_worst_case_all_unique_ratio_is_one():
     """All-unique keys: compaction cannot dedupe anything — the ratio is
-    honestly 1.0 and results still match the uncompacted fold."""
+    honestly 1.0 and results still match the oracle."""
     spec = SyntheticSpec(
         num_partitions=2, messages_per_partition=1500,
         keys_per_partition=1_000_000, tombstone_permille=100, seed=13,
     )
 
-    def scan(compaction):
+    def scan(backend_cls):
         cfg = AnalyzerConfig(
             num_partitions=2, batch_size=256, count_alive_keys=True,
-            alive_bitmap_bits=24, wire_format=5,
-            alive_compaction=compaction,
+            alive_bitmap_bits=24,
         )
         return run_scan(
-            "t", SyntheticSource(spec),
-            TpuBackend(cfg, init_now_s=10**10,
-                       dispatch=DispatchConfig(superbatch=4)),
-            256,
+            "t", SyntheticSource(spec), _backend(cfg, backend_cls, 4), 256,
         )
 
-    on = scan("auto")
-    off = scan("off")
-    assert on.metrics.to_dict(None, None) == off.metrics.to_dict(None, None)
+    on = scan(TpuBackend)
+    ref = scan(CpuExactBackend)
+    assert on.metrics.to_dict(None, None) == ref.metrics.to_dict(None, None)
     # Not exactly 1.0 only if the 1M-key draw collides; allow a hair.
     assert on.wire.compaction_ratio > 0.99
 

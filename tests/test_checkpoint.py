@@ -412,3 +412,34 @@ def test_v3_stamped_single_shard_snapshot_still_loads(tmp_path):
     np.savez(path.removesuffix(".npz"), **data)
     loaded = load_snapshot(str(tmp_path), "t", CFG)
     assert loaded is not None
+
+
+#: Fingerprints that snapshots already on disk carry, computed by the code
+#: that still had the wire-format, compaction and Pallas config fields
+#: (excluded from the fingerprint there).  Topic "demo", 10 partitions,
+#: batch 2^16.
+GOLDEN_FINGERPRINTS = {
+    "counters": ({}, "28ef60cdbb4fe30a"),
+    "alive": ({"count_alive_keys": True}, "748bac2e49ba9ae0"),
+    "alive-distinct-quantiles": (
+        {"count_alive_keys": True, "enable_hll": True,
+         "enable_quantiles": True},
+        "295e9ec98342a8e0",
+    ),
+    "mesh21-per-partition-hll-quantiles": (
+        {"distinct_keys_per_partition": True,
+         "quantiles_per_partition": True, "mesh_shape": (2, 1)},
+        "cfdfced32207c555",
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GOLDEN_FINGERPRINTS))
+def test_fingerprint_unchanged_from_parent(name):
+    """A snapshot written before those fields went still resumes: every
+    config's fingerprint string is the one it was stamped with."""
+    from kafka_topic_analyzer_tpu.checkpoint import config_fingerprint
+
+    kw, want = GOLDEN_FINGERPRINTS[name]
+    cfg = AnalyzerConfig(num_partitions=10, batch_size=1 << 16, **kw)
+    assert config_fingerprint(cfg, "demo") == want

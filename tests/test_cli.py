@@ -21,8 +21,8 @@ def test_help_and_version_surface(capsys):
 
     Regression: a bare ``%`` in an argparse help string makes
     ``format_help()`` raise ValueError at print time (r2-r3 shipped one in
-    the --pallas help), so every registered action's help is formatted
-    here, not just spot-checked flags.
+    a flag's help), so every registered action's help is formatted here,
+    not just spot-checked flags.
     """
     from kafka_topic_analyzer_tpu.cli import build_parser
 
@@ -34,7 +34,7 @@ def test_help_and_version_surface(capsys):
             # Same interpolation argparse applies inside format_help().
             formatter._expand_help(action)
     full = parser.format_help()
-    assert "--pallas" in full and "--topic" in full
+    assert "--superbatch" in full and "--topic" in full
 
     for flag in ("-h", "--help"):
         with pytest.raises(SystemExit) as e:
@@ -48,6 +48,18 @@ def test_help_and_version_surface(capsys):
     assert e.value.code == 0
     out = capsys.readouterr().out
     assert "kafka-topic-analyzer-tpu" in out
+
+
+@pytest.mark.parametrize("flags", [
+    ["--pallas"], ["--wire-format", "v4"], ["--alive-compaction", "off"],
+])
+def test_removed_path_flags_are_refused(capsys, flags):
+    """The packed layout, alive compaction and counter merge have one path
+    each: the flags that once chose another are unknown to the parser."""
+    with pytest.raises(SystemExit) as e:
+        main(["-t", "unit.topic", "--source", "synthetic", *flags])
+    assert e.value.code == 2
+    assert "unrecognized arguments" in capsys.readouterr().err
 
 
 def _run(capsys, extra):

@@ -1,5 +1,5 @@
 """Fused native decode→pack (ISSUE 8): one GIL-released C++ pass from
-fetch bytes to wire-v4 rows.
+fetch bytes to packed rows.
 
 The byte-identity bar has two layers:
 
@@ -290,7 +290,7 @@ def test_fused_sharded_rows_equal_pack_chunks():
 
 
 def test_pack_range_violation_raises_packers_error():
-    """A decoded record the wire-v4 layout cannot carry raises the SAME
+    """A decoded record the packed layout cannot carry raises the SAME
     ValueError the numpy packer would (key > 64 KiB)."""
     rows = [(5, 1000, b"k" * 70_000, b"v")]
     data = kc.encode_record_batch(rows)
@@ -298,6 +298,36 @@ def test_pack_range_violation_raises_packers_error():
     sink = FusedPackSink(cfg, 16, dense_of=lambda p: 0)
     with pytest.raises(ValueError, match="key length 70000 exceeds"):
         sink.append_record_set(data, 0, 10**9, 0)
+
+
+@pytest.mark.parametrize(
+    "key_len,value_len", [(70_000, 1), (3, -1)],
+    ids=["oversize-key", "negative-value"],
+)
+def test_column_append_range_violation_raises_packers_error(key_len, value_len):
+    """The fused column append (the chain-fallback half) rejects a record
+    the packed layout cannot carry with the numpy packer's own message."""
+    import re
+
+    from kafka_topic_analyzer_tpu.records import RecordBatch
+
+    cfg = AnalyzerConfig(num_partitions=1, batch_size=16)
+    batch = RecordBatch(
+        partition=np.zeros(2, dtype=np.int32),
+        key_len=np.array([2, key_len], dtype=np.int32),
+        value_len=np.array([1, value_len], dtype=np.int32),
+        key_null=np.zeros(2, dtype=bool),
+        value_null=np.zeros(2, dtype=bool),
+        ts_s=np.zeros(2, dtype=np.int64),
+        key_hash32=np.arange(2, dtype=np.uint32),
+        key_hash64=np.arange(2, dtype=np.uint64),
+        valid=np.ones(2, dtype=bool),
+    )
+    with pytest.raises(ValueError) as numpy_error:
+        pack_batch(batch, cfg, use_native=False)
+    sink = FusedPackSink(cfg, 16, dense_of=lambda p: 0)
+    with pytest.raises(ValueError, match=re.escape(str(numpy_error.value))):
+        sink.append_batch(batch, reason="frame-fallback")
 
 
 def test_pack_range_outside_window_is_filtered_not_raised():

@@ -1,9 +1,5 @@
-"""Packed transfer (wire format v4 — the layout contract is packing.py's
-module docstring): layout roundtrip, host pre-reductions.
-
-Pinned to ``wire_format=4``: these tests assert the v4 per-record column
-layout specifically.  The v5 combiner layout has its own contract suite
-(tests/test_wire_v5.py), including v4↔v5 scan byte-identity."""
+"""Packed transfer (the layout contract is packing.py's module docstring):
+layout roundtrip, host pre-reductions, and the packers' agreement."""
 
 import jax
 import numpy as np
@@ -12,6 +8,7 @@ import pytest
 from kafka_topic_analyzer_tpu.config import AnalyzerConfig
 from kafka_topic_analyzer_tpu.io.synthetic import SyntheticSource, SyntheticSpec
 from kafka_topic_analyzer_tpu.packing import (
+    batch_alive_pairs,
     dedupe_slots_numpy,
     hll_idx_rho_numpy,
     pack_batch,
@@ -19,6 +16,7 @@ from kafka_topic_analyzer_tpu.packing import (
     unpack_device,
     unpack_numpy,
 )
+from kafka_topic_analyzer_tpu.results import COUNTER_CHANNELS
 
 SPEC = SyntheticSpec(
     num_partitions=5,
@@ -36,12 +34,28 @@ CFG = AnalyzerConfig(
     alive_bitmap_bits=18,
     enable_hll=True,
     hll_p=10,
-    wire_format=4,
 )
 
 
 def _batch():
     return next(SyntheticSource(SPEC).batches(400)).pad_to(512)
+
+
+def _reference_counts(batch, n: int, parts: int) -> np.ndarray:
+    """The [P, 7] counter table written from the channel DEFINITIONS,
+    record by record (independently of the packers' vectorized fold)."""
+    want = np.zeros((parts, len(COUNTER_CHANNELS)), dtype=np.int64)
+    for i in range(n):
+        row = want[int(batch.partition[i])]
+        kn, vn = not batch.key_null[i], not batch.value_null[i]
+        row[0] += 1
+        row[1] += not vn
+        row[2] += vn
+        row[3] += not kn
+        row[4] += kn
+        row[5] += int(batch.key_len[i]) if kn else 0
+        row[6] += int(batch.value_len[i]) if vn else 0
+    return want
 
 
 def test_pack_unpack_numpy_roundtrip():
@@ -50,15 +64,14 @@ def test_pack_unpack_numpy_roundtrip():
     assert buf.nbytes == packed_nbytes(CFG, 512)
     got = unpack_numpy(buf, CFG)
     assert int(got["n_valid"]) == 400
-    assert np.array_equal(got["partition"][:400], batch.partition[:400])
-    assert np.array_equal(got["key_len"][:400], batch.key_len[:400])
-    assert np.array_equal(got["value_len"][:400], batch.value_len[:400])
-    assert np.array_equal(got["key_null"][:400], batch.key_null[:400])
-    assert np.array_equal(got["value_null"][:400], batch.value_null[:400])
-    assert np.array_equal(got["valid"], batch.valid)
-    # v2/v4: ts and size extremes ship as host-reduced per-partition
-    # min/max tables (sizes tombstone-excluded, key bytes only when the
-    # key is non-null; identities I64_MAX / I64_MIN and I64_MAX / 0).
+    assert set(got) == {
+        "n_valid", "counts", "ts_min", "ts_max", "sz_min", "sz_max",
+        "hll_regs",
+    }
+    assert np.array_equal(got["counts"], _reference_counts(batch, 400, 5))
+    # ts and size extremes ship as host-reduced per-partition min/max
+    # tables (sizes tombstone-excluded, key bytes only when the key is
+    # non-null; identities I64_MAX / I64_MIN and I64_MAX / 0).
     sizes = (
         np.where(batch.key_null[:400], 0, batch.key_len[:400]).astype(np.int64)
         + batch.value_len[:400]
@@ -149,24 +162,27 @@ def test_native_pack_semantics_match_numpy(hll_p, per_partition):
     ua, ub = unpack_numpy(a, cfg), unpack_numpy(b, cfg)
     nv = int(ua["n_valid"])
     assert nv == int(ub["n_valid"])
+    mode = HLL_MODE[(per_partition, hll_p)]
     hll_names = (
-        ("hll_regs",)
-        if HLL_MODE[(per_partition, hll_p)] == "table"
+        ("hll_regs",) if mode == "table"
+        else ("hll_idx32", "hll_rho") if per_partition
         else ("hll_idx", "hll_rho")
     )
-    per_record = ("partition", "key_len", "value_len", "key_null",
-                  "value_null", "hll_idx", "hll_rho")
-    for name in ("partition", "key_len", "value_len", "key_null",
-                 "value_null", "ts_min", "ts_max", "sz_min", "sz_max") + hll_names:
-        cut = nv if name in per_record else len(ua[name])
-        assert np.array_equal(ua[name][:cut], ub[name][:cut]), name
-    # Dedupe pair ORDER differs (sorted vs first-touch); counts must match
-    # exactly (dict comparison alone would mask duplicate emissions), then
-    # compare as dicts.
-    na, nb = int(ua["n_pairs"]), int(ub["n_pairs"])
-    assert na == nb
-    assert dict(zip(ua["alive_slot"][:na].tolist(), ua["alive_flag"][:na].tolist())) \
-        == dict(zip(ub["alive_slot"][:nb].tolist(), ub["alive_flag"][:nb].tolist()))
+    assert set(ua) == set(ub) == {
+        "n_valid", "counts", "ts_min", "ts_max", "sz_min", "sz_max",
+    } | set(hll_names)
+    for name in ua:
+        assert np.array_equal(ua[name], ub[name]), name
+    assert np.array_equal(a, b)  # header and padding bytes too
+    # Alive pairs leave the row: dedupe pair ORDER differs between the
+    # packers (sorted vs first-touch); counts must match exactly (dict
+    # comparison alone would mask duplicate emissions), then as dicts.
+    sa, fa = batch_alive_pairs(batch, cfg, use_native=False)
+    sb, fb = batch_alive_pairs(batch, cfg, use_native=True)
+    assert len(sa) == len(sb)
+    assert dict(zip(sa.tolist(), fa.tolist())) == dict(
+        zip(sb.tolist(), fb.tolist())
+    )
 
 
 def test_native_pack_odd_batch_size_and_empty():
@@ -183,16 +199,14 @@ def test_native_pack_odd_batch_size_and_empty():
     b = native.pack_batch_native(batch, odd_cfg)
     assert b is not None
     ua, ub = unpack_numpy(a, odd_cfg), unpack_numpy(b, odd_cfg)
-    for name in ("partition", "key_len", "value_len"):
-        assert np.array_equal(ua[name][:400], ub[name][:400]), name
-    for name in ("ts_min", "ts_max", "sz_min", "sz_max"):  # [P] tables
+    for name in ("counts", "ts_min", "ts_max", "sz_min", "sz_max"):
         assert np.array_equal(ua[name], ub[name]), name
     from kafka_topic_analyzer_tpu.records import RecordBatch
 
     empty = native.pack_batch_native(RecordBatch.empty(0), odd_cfg)
     assert empty is not None
     ue = unpack_numpy(empty, odd_cfg)
-    assert int(ue["n_valid"]) == 0 and int(ue["n_pairs"]) == 0
+    assert int(ue["n_valid"]) == 0 and not ue["counts"].any()
 
 
 def test_pack_rejects_negative_lengths():
@@ -248,30 +262,99 @@ def test_pack_rejects_oversize_keys():
         pack_batch(batch, CFG, use_native=False)
 
 
-def test_pack_rejects_oversize_values_only_for_pallas():
-    # The 16 MiB cap exists for the v4 MXU kernel's digit decomposition;
-    # the default scatter path accepts full u32 lengths, and under wire v5
-    # no per-record value length ever reaches a pallas kernel (the counter
-    # fold ships pre-reduced), so only v4+pallas rejects.  (Exercised
-    # directly: the synthetic generator can only draw 24-bit lengths.)
-    batch = _batch()
-    batch.value_len[3] = 1 << 25
-    pack_batch(batch, CFG, use_native=False)  # default path: fine
-    pallas_cfg = AnalyzerConfig(
-        num_partitions=5, batch_size=1024, use_pallas_counters=True,
-        wire_format=4,
-    )
-    with pytest.raises(ValueError, match="value length"):
-        pack_batch(batch.pad_to(1024), pallas_cfg, use_native=False)
-    v5_cfg = AnalyzerConfig(
-        num_partitions=5, batch_size=1024, use_pallas_counters=True,
-        wire_format=5,
-    )
-    pack_batch(batch.pad_to(1024), v5_cfg, use_native=False)  # v5: fine
-
-
 def test_pack_rejects_non_prefix_valid():
     batch = _batch()
     batch.valid[10] = False
     with pytest.raises(ValueError, match="prefix-valid"):
         pack_batch(batch, CFG, use_native=False)
+
+
+def _random_batch(rng, n: int, parts: int, vmin: int, vmax: int):
+    from kafka_topic_analyzer_tpu.records import RecordBatch
+
+    key_null = rng.random(n) < 0.1
+    value_null = rng.random(n) < 0.15
+    h32 = rng.integers(0, 2**32, n, dtype=np.uint32)
+    return RecordBatch(
+        partition=rng.integers(0, parts, n).astype(np.int32),
+        key_len=np.where(key_null, 0, rng.integers(0, 1 << 16, n)).astype(
+            np.int32
+        ),
+        value_len=np.where(value_null, 0, rng.integers(vmin, vmax, n)).astype(
+            np.int32
+        ),
+        key_null=key_null,
+        value_null=value_null,
+        ts_s=rng.integers(0, 2**31, n),
+        key_hash32=np.where(key_null, 0, h32).astype(np.uint32),
+        key_hash64=np.where(
+            key_null, 0, rng.integers(0, 2**63, n, dtype=np.uint64)
+        ).astype(np.uint64),
+        valid=np.ones(n, dtype=bool),
+    )
+
+
+def _packers():
+    from kafka_topic_analyzer_tpu.io.native import native_available
+
+    return [False, True] if native_available() else [False]
+
+
+@pytest.mark.parametrize("parts", [1, 3, 16, 64, 200, 300])
+def test_counter_table_matches_oracle(parts):
+    """Every packer's [P, 7] counter table, and the device's fold of two
+    of them, equal the CPU oracle's counters — at a batch size that is no
+    multiple of 1024 and partition counts past 128."""
+    from kafka_topic_analyzer_tpu.backends.cpu import CpuExactBackend
+    from kafka_topic_analyzer_tpu.backends.tpu import TpuBackend
+
+    b = 1000
+    rng = np.random.default_rng(parts)
+    cfg = AnalyzerConfig(num_partitions=parts, batch_size=b)
+    batches = [_random_batch(rng, b, parts, 0, 5000) for _ in range(2)]
+    cpu = CpuExactBackend(cfg, init_now_s=0)
+    tpu = TpuBackend(cfg, init_now_s=0)
+    for batch in batches:
+        one = CpuExactBackend(cfg, init_now_s=0)
+        one.update(batch)
+        want = one.finalize().per_partition
+        for native in _packers():
+            got = unpack_numpy(pack_batch(batch, cfg, use_native=native), cfg)
+            assert np.array_equal(got["counts"], want), native
+        cpu.update(batch)
+        tpu.update(batch)
+    assert np.array_equal(
+        tpu.finalize().per_partition, cpu.finalize().per_partition
+    )
+
+
+@pytest.mark.parametrize("native", [False, True], ids=["numpy", "native"])
+def test_large_values_exact_against_oracle(native):
+    """Values of 2^24 bytes and more, whose byte sums pass 2^32 within a
+    single batch and partition, fold exactly: the whole report equals the
+    oracle's (sketch lines through tests/oracle.py)."""
+    from kafka_topic_analyzer_tpu.backends.cpu import CpuExactBackend
+    from kafka_topic_analyzer_tpu.backends.tpu import TpuBackend
+
+    from oracle import sketched
+
+    if native and len(_packers()) == 1:
+        pytest.skip("native shim unavailable")
+    rng = np.random.default_rng(24)
+    cfg = AnalyzerConfig(
+        num_partitions=2, batch_size=800, count_alive_keys=True,
+        alive_bitmap_bits=16, enable_hll=True, hll_p=10,
+        enable_quantiles=True, quantiles_per_partition=True,
+    )
+    batches = [
+        _random_batch(rng, 800, 2, 1 << 24, (1 << 24) + (1 << 22))
+        for _ in range(2)
+    ]
+    one = unpack_numpy(pack_batch(batches[0], cfg, use_native=native), cfg)
+    assert int(one["counts"][:, 6].max()) > 1 << 32
+    cpu = CpuExactBackend(cfg, init_now_s=0)
+    tpu = TpuBackend(cfg, init_now_s=0, use_native=native)
+    for batch in batches:
+        cpu.update(batch)
+        tpu.update(batch)
+    assert tpu.finalize().to_dict() == sketched(cpu, cpu.finalize()).to_dict()

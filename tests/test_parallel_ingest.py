@@ -750,7 +750,7 @@ def _staged_rows_peak(monkeypatch, workers):
         ),
         init_now_s=10**10,
     )
-    assert backend.superbatch_k == 1 and backend.config.compact_alive
+    assert backend.superbatch_k == 1 and backend.config.count_alive_keys
     row = (packed_nbytes(backend.config, backend.config.batch_size),)
     lock = threading.Lock()
     live = {"now": 0, "peak": 0}

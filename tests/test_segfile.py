@@ -304,7 +304,7 @@ def test_read_batch_is_zero_copy_and_matches_copy(seg_dir):
 
 
 def test_pack_from_memmap_views_matches_copy_pack(seg_dir):
-    """wire-v4 rows built straight from mapped columns (the cold path's
+    """Packed rows built straight from mapped columns (the cold path's
     pack) must be byte-identical to packing detached copies, with and
     without the native shim, including the pack_batch(out=) row path."""
     from kafka_topic_analyzer_tpu.packing import pack_batch, packed_nbytes

@@ -137,7 +137,7 @@ def test_step_scopes_in_the_lowered_step():
         pair_table_nbytes,
     )
 
-    assert CFG.compact_alive
+    assert CFG.count_alive_keys
     cap = pair_table_capacity(CFG, CFG.batch_size, 1)
     args = (
         jax.eval_shape(lambda: AnalyzerState.init(CFG)),

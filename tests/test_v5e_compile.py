@@ -1,11 +1,10 @@
 """Compile the device path for a described v5e chip (no chip attached).
 
-The TPU compiler refuses what the CPU backend and the Pallas interpreter
-accept: a Mosaic kernel that cannot be legalized, a program that does not
+The TPU compiler refuses what the CPU backend accepts: a Mosaic kernel that cannot be legalized, a program that does not
 fit the chip's memory.  These tests hand it the real shapes: the packed
-step at the CLI defaults and at a larger batch, the superbatch step, and
-both Pallas kernels with ``interpret=False``, and the mesh backend's
-collective merge on a 2x2 mesh.  Nothing runs; a pass is not a chip run.
+step at the CLI defaults and at a larger batch, the superbatch step, the
+alive-table apply in both its forms, and the mesh backend's collective
+merge on a 2x2 mesh.  Nothing runs; a pass is not a chip run.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every xdist worker imports this
@@ -109,7 +108,6 @@ def test_packed_step_compiles_for_v5e(one_chip, bits, batch_size):
     from kafka_topic_analyzer_tpu.packing import packed_nbytes
 
     config = _scan_config(bits, batch_size)
-    assert config.compact_alive
     args = (
         _state_shapes(config, one_chip),
         _shape(one_chip, (packed_nbytes(config, batch_size),), jnp.uint8),
@@ -134,36 +132,32 @@ def test_superstep_compiles_for_v5e(one_chip):
     _compile(make_packed_superstep(config, k), args, donate=(0,))
 
 
-def test_pallas_counters_update_compiles_for_v5e(one_chip):
-    from kafka_topic_analyzer_tpu.ops.pallas_counters import (
-        pallas_counters_update,
+@pytest.mark.parametrize(
+    "bits,form", [(32, 1), (26, 2)], ids=["pairs-bits32", "masks-bits26"]
+)
+def test_apply_pair_table_compiles_for_v5e(one_chip, bits, form):
+    """The dispatch's alive table applied to the state, in each form
+    ``packing.alive_table_mode`` picks: the bounded pair list's scatter at
+    the CLI's 2^32-slot bitmap, the elementwise set/clear masks below."""
+    from kafka_topic_analyzer_tpu.backends.step import apply_pair_table
+    from kafka_topic_analyzer_tpu.packing import (
+        alive_table_mode,
+        pair_table_capacity,
+        unpack_pair_table_device,
     )
 
-    p, b = 64, 1 << 18
+    config = _scan_config(bits, 1 << 18)
+    cap = pair_table_capacity(config, config.batch_size, 1)
+    assert alive_table_mode(config, cap) == form
 
-    def fn(per_partition, part, klen, vlen, knull, vnull, valid):
-        return pallas_counters_update(
-            per_partition, part, klen, vlen, knull, vnull, valid, p,
-            interpret=False,
+    def fn(state, pairbuf):
+        return apply_pair_table(
+            state, unpack_pair_table_device(pairbuf, config, cap), config
         )
 
-    i32 = [_shape(one_chip, (b,), jnp.int32) for _ in range(3)]
-    flags = [_shape(one_chip, (b,), jnp.bool_) for _ in range(3)]
-    compiled = _compile(fn, [_shape(one_chip, (p, 7), jnp.int64)] + i32 + flags)
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_pallas_counters_merge_compiles_for_v5e(one_chip):
-    from kafka_topic_analyzer_tpu.ops.pallas_counters import (
-        pallas_counters_merge,
-    )
-
-    table = _shape(one_chip, (64, 7), jnp.int64)
-    compiled = _compile(
-        lambda a, b: pallas_counters_merge(a, b, interpret=False),
-        [table, table],
-    )
-    assert "tpu_custom_call" in compiled.as_text()
+    args = (_state_shapes(config, one_chip), _pair_shape(config, 1, one_chip))
+    compiled = _compile(fn, args, donate=(0,))
+    assert ("scatter" in compiled.as_text()) == (form == 1)
 
 
 @pytest.mark.parametrize(

@@ -1,15 +1,15 @@
-"""Wire-v5 combiner rows (ISSUE 9): host pre-reduced per-partition fold
-tables replace the last per-record columns.
+"""Combiner rows: host pre-reduced per-partition fold tables are the
+packed layout (packing.py module docstring).
 
-The byte-identity bar has two layers:
+The bar has two layers:
 
 - TABLE bytes: the combiner tables a packer emits (counter deltas,
   DDSketch buckets, extremes) must equal a straight numpy reference
   reduction over the same records — native and numpy packers alike
   (the hypothesis property test mirrors the PR-8 row-bytes parity suite).
-- SCAN results: a v5 scan's full document must equal the v4 scan's across
-  (wire, segfile) × workers × K × mesh, including corruption/quarantine
-  parity and v4↔v5 cross-format resume.
+- SCAN results: a device scan's full document must equal the CPU
+  oracle's (tests/oracle.py) across (wire, segfile) × workers × K × mesh,
+  including corruption/quarantine parity.
 """
 
 from __future__ import annotations
@@ -22,6 +22,7 @@ import sys
 import numpy as np
 import pytest
 
+from kafka_topic_analyzer_tpu.backends.cpu import CpuExactBackend
 from kafka_topic_analyzer_tpu.backends.tpu import TpuBackend
 from kafka_topic_analyzer_tpu.config import (
     AnalyzerConfig,
@@ -33,6 +34,7 @@ from kafka_topic_analyzer_tpu.io.kafka_wire import KafkaWireSource
 from kafka_topic_analyzer_tpu.io.synthetic import SyntheticSource, SyntheticSpec
 from kafka_topic_analyzer_tpu.obs.registry import default_registry
 from kafka_topic_analyzer_tpu.packing import (
+    batch_alive_pairs,
     pack_batch,
     packed_nbytes,
     section_byte_split,
@@ -41,6 +43,7 @@ from kafka_topic_analyzer_tpu.packing import (
 from kafka_topic_analyzer_tpu.records import RecordBatch
 
 from fake_broker import CorruptionInjector, FakeBroker
+from oracle import sketched
 
 pytestmark = pytest.mark.wirev5
 
@@ -69,7 +72,7 @@ N_REC = 300
 RECORDS = {p: _mk_records(p, N_REC) for p in range(N_PARTS)}
 
 
-def _cfg(wire_format: int, **kw) -> AnalyzerConfig:
+def _cfg(**kw) -> AnalyzerConfig:
     base = dict(
         num_partitions=N_PARTS,
         batch_size=128,
@@ -81,12 +84,12 @@ def _cfg(wire_format: int, **kw) -> AnalyzerConfig:
         quantiles_per_partition=True,
     )
     base.update(kw)
-    return AnalyzerConfig(wire_format=wire_format, **base)
+    return AnalyzerConfig(**base)
 
 
-def _full_doc(result) -> dict:
+def _full_doc(result, metrics=None) -> dict:
     return {
-        "metrics": result.metrics.to_dict(
+        "metrics": (metrics or result.metrics).to_dict(
             result.start_offsets, result.end_offsets
         ),
         "start": result.start_offsets,
@@ -96,30 +99,43 @@ def _full_doc(result) -> dict:
     }
 
 
-def _wire_scan(wire_format, workers=1, superbatch=1, backend_cls=TpuBackend,
+def _backend(cfg, backend_cls, superbatch=1):
+    if backend_cls is CpuExactBackend:
+        return CpuExactBackend(cfg, init_now_s=10**10)
+    return backend_cls(
+        cfg, init_now_s=10**10, dispatch=DispatchConfig(superbatch=superbatch)
+    )
+
+
+def _doc(result, backend) -> dict:
+    """The scan's document; the oracle's with its sketches taken."""
+    if isinstance(backend, CpuExactBackend):
+        return _full_doc(result, sketched(backend, result.metrics))
+    return _full_doc(result)
+
+
+def _wire_scan(workers=1, superbatch=1, backend_cls=TpuBackend,
                mesh=None, **cfg_kw):
-    cfg = _cfg(wire_format, **cfg_kw)
+    """(result, document) of one scan of the fake broker's topic."""
+    cfg = _cfg(**cfg_kw)
     if mesh is not None:
         cfg = dataclasses.replace(cfg, mesh_shape=mesh)
     with FakeBroker(TOPIC, RECORDS, max_records_per_fetch=60) as broker:
         src = KafkaWireSource(
             f"127.0.0.1:{broker.port}", TOPIC, overrides=dict(FAST_RETRY)
         )
-        backend = backend_cls(
-            cfg, init_now_s=10**10,
-            dispatch=DispatchConfig(superbatch=superbatch),
-        )
+        backend = _backend(cfg, backend_cls, superbatch)
         result = run_scan(
             TOPIC, src, backend, cfg.batch_size, ingest_workers=workers
         )
         src.close()
-    return result
+    return result, _doc(result, backend)
 
 
 @pytest.fixture(scope="module")
-def wire_v4_baseline():
-    """The v4 scan — the byte-exact referee for every v5 configuration."""
-    return _full_doc(_wire_scan(4))
+def wire_oracle():
+    """The CPU oracle's document — the referee for every device scan."""
+    return _wire_scan(backend_cls=CpuExactBackend)[1]
 
 
 # ---------------------------------------------------------------------------
@@ -129,30 +145,30 @@ def wire_v4_baseline():
 @pytest.mark.parametrize("workers,superbatch", [
     (1, 1), (4, 1), (1, 4), (4, 4),
 ])
-def test_v5_wire_scan_identical(wire_v4_baseline, workers, superbatch):
-    result = _wire_scan(5, workers=workers, superbatch=superbatch)
-    assert _full_doc(result) == wire_v4_baseline
+def test_v5_wire_scan_identical(wire_oracle, workers, superbatch):
+    result, doc = _wire_scan(workers=workers, superbatch=superbatch)
+    assert doc == wire_oracle
     assert result.wire is not None and result.wire.format == 5
 
 
 @pytest.mark.parametrize("mesh,superbatch", [((2, 1), 1), ((2, 1), 4)])
-def test_v5_sharded_scan_identical(wire_v4_baseline, mesh, superbatch):
+def test_v5_sharded_scan_identical(wire_oracle, mesh, superbatch):
     from kafka_topic_analyzer_tpu.parallel.sharded import ShardedTpuBackend
 
-    for wf in (4, 5):
-        result = _wire_scan(wf, mesh=mesh, superbatch=superbatch,
-                            backend_cls=ShardedTpuBackend)
-        assert _full_doc(result) == wire_v4_baseline, wf
+    _, doc = _wire_scan(mesh=mesh, superbatch=superbatch,
+                        backend_cls=ShardedTpuBackend)
+    assert doc == wire_oracle
 
 
-def test_v5_flat_hll_pair_mode_scan_identical(wire_v4_baseline):
-    """Per-partition HLL in PAIR mode (the one v5 section that cannot ride
-    unchanged — idx32 carries the register row).  hll_p=14 at B=128 forces
-    pair mode; referee is the v4 scan of the same config."""
-    a = _full_doc(_wire_scan(4, distinct_keys_per_partition=True, hll_p=14))
-    b = _full_doc(_wire_scan(5, distinct_keys_per_partition=True, hll_p=14))
+def test_v5_flat_hll_pair_mode_scan_identical(wire_oracle):
+    """Per-partition HLL in PAIR mode (idx32 carries the register row).
+    hll_p=14 at B=128 forces pair mode; referee is the oracle of the same
+    config."""
+    kw = dict(distinct_keys_per_partition=True, hll_p=14)
+    a = _wire_scan(backend_cls=CpuExactBackend, **kw)[1]
+    b = _wire_scan(**kw)[1]
     assert a == b
-    assert a != wire_v4_baseline  # the per-partition rows actually differ
+    assert a != wire_oracle  # the per-partition rows actually differ
 
 
 # ---------------------------------------------------------------------------
@@ -177,21 +193,20 @@ def test_v5_segfile_scan_identical(tmp_path):
         writer.append(b)
     writer.close()
 
-    def scan(wf, workers=1):
+    def scan(backend_cls=TpuBackend, workers=1):
         cfg = AnalyzerConfig(
             num_partitions=3, batch_size=128, count_alive_keys=True,
             alive_bitmap_bits=14, enable_hll=True, hll_p=8,
-            enable_quantiles=True, wire_format=wf,
+            enable_quantiles=True,
         )
         s = SegmentFileSource(d, "seg.topic")
-        r = run_scan("seg.topic", s, TpuBackend(cfg, init_now_s=10**10),
-                     128, ingest_workers=workers)
-        return _full_doc(r)
+        backend = _backend(cfg, backend_cls)
+        r = run_scan("seg.topic", s, backend, 128, ingest_workers=workers)
+        return _doc(r, backend)
 
-    base = scan(4)
-    assert scan(5) == base
-    assert scan(5, workers=2) == base
-    assert scan(4, workers=2) == base
+    base = scan(CpuExactBackend)
+    assert scan() == base
+    assert scan(workers=2) == base
 
 
 # ---------------------------------------------------------------------------
@@ -199,8 +214,8 @@ def test_v5_segfile_scan_identical(tmp_path):
 
 
 def test_v5_corruption_quarantine_parity(tmp_path):
-    """Deterministic poison under --on-corruption=quarantine: the v5 scan
-    classifies, accounts, and quarantines EXACTLY like the v4 scan."""
+    """Deterministic poison under --on-corruption=quarantine: the device
+    scan classifies, accounts, and quarantines EXACTLY like the oracle."""
     def poisoned():
         inj = (
             CorruptionInjector()
@@ -212,8 +227,8 @@ def test_v5_corruption_quarantine_parity(tmp_path):
             honor_partition_max_bytes=True,
         )
 
-    def run(wf, qdir):
-        cfg = _cfg(wf)
+    def run(backend_cls, qdir):
+        cfg = _cfg()
         with poisoned() as broker:
             src = KafkaWireSource(
                 f"127.0.0.1:{broker.port}", TOPIC,
@@ -222,76 +237,20 @@ def test_v5_corruption_quarantine_parity(tmp_path):
                     policy="quarantine", quarantine_dir=qdir
                 ),
             )
-            r = run_scan(TOPIC, src, TpuBackend(cfg, init_now_s=10**10), 128)
+            backend = _backend(cfg, backend_cls)
+            r = run_scan(TOPIC, src, backend, 128)
             spans = src.corruption_spans()
             src.close()
-        return _full_doc(r), spans
+        return _doc(r, backend), spans
 
-    doc4, spans4 = run(4, str(tmp_path / "q4"))
-    doc5, spans5 = run(5, str(tmp_path / "q5"))
-    assert doc5 == doc4
-    assert sorted(doc5["corrupt"]) == [1, 2]
-    assert spans5 == spans4
-    assert sorted(os.listdir(tmp_path / "q5")) == sorted(
-        os.listdir(tmp_path / "q4")
+    doc_cpu, spans_cpu = run(CpuExactBackend, str(tmp_path / "qcpu"))
+    doc_tpu, spans_tpu = run(TpuBackend, str(tmp_path / "qtpu"))
+    assert doc_tpu == doc_cpu
+    assert sorted(doc_tpu["corrupt"]) == [1, 2]
+    assert spans_tpu == spans_cpu
+    assert sorted(os.listdir(tmp_path / "qtpu")) == sorted(
+        os.listdir(tmp_path / "qcpu")
     )
-
-
-# ---------------------------------------------------------------------------
-# cross-format resume
-
-
-class _Interrupt(Exception):
-    pass
-
-
-class _InterruptingSource(SyntheticSource):
-    def __init__(self, spec, limit):
-        super().__init__(spec)
-        self.limit = limit
-
-    def batches(self, batch_size, partitions=None, start_at=None):
-        it = super().batches(batch_size, partitions, start_at)
-        for i, b in enumerate(it):
-            if start_at is None and i >= self.limit:
-                raise _Interrupt()
-            yield b
-
-
-RESUME_SPEC = SyntheticSpec(
-    num_partitions=3, messages_per_partition=2_000, keys_per_partition=80,
-    tombstone_permille=150, seed=31,
-)
-
-
-@pytest.mark.parametrize("wf_first,wf_second", [(4, 5), (5, 4)])
-def test_cross_format_resume(tmp_path, wf_first, wf_second):
-    """A snapshot taken mid-scan under one wire format resumes under the
-    other, reproducing the uninterrupted scan exactly — the format is
-    execution strategy, outside the checkpoint fingerprint."""
-    cfg_first = AnalyzerConfig(
-        num_partitions=3, batch_size=512, count_alive_keys=True,
-        alive_bitmap_bits=18, enable_hll=True, hll_p=10,
-        enable_quantiles=True, wire_format=wf_first,
-    )
-    cfg_second = dataclasses.replace(cfg_first, wire_format=wf_second)
-    full = run_scan(
-        "t", SyntheticSource(RESUME_SPEC),
-        TpuBackend(cfg_second, init_now_s=10**10), 512,
-    ).metrics.to_dict(None, None)
-
-    with pytest.raises(_Interrupt):
-        run_scan(
-            "t", _InterruptingSource(RESUME_SPEC, limit=5),
-            TpuBackend(cfg_first, init_now_s=10**10), 512,
-            snapshot_dir=str(tmp_path), snapshot_every_s=0.0,
-        )
-    resumed = run_scan(
-        "t", SyntheticSource(RESUME_SPEC),
-        TpuBackend(cfg_second, init_now_s=0), 512,
-        snapshot_dir=str(tmp_path), resume=True,
-    )
-    assert resumed.metrics.to_dict(None, None) == full
 
 
 # ---------------------------------------------------------------------------
@@ -398,7 +357,6 @@ def test_combiner_tables_match_reference_reduction():
         cfg = AnalyzerConfig(
             num_partitions=parts, batch_size=96, enable_quantiles=True,
             quantiles_per_partition=data.draw(st.booleans()),
-            wire_format=5,
         )
         ref_counts, ref_qt = _reference_tables(batch, cfg)
         for nat in ([False, True] if use_native else [False]):
@@ -436,44 +394,34 @@ def _rand_batch(seed: int, n: int, parts: int) -> RecordBatch:
 
 
 def test_v5_native_rows_equal_numpy_rows():
-    """Native and numpy v5 packers agree byte-for-byte on every section
-    except the alive pairs' documented ordering difference (compared as
-    sets, counts exact)."""
+    """Native and numpy packers agree byte-for-byte on every row; the
+    alive pairs (outside the row) agree up to their documented ordering
+    difference (compared as maps, counts exact)."""
     native = pytest.importorskip("kafka_topic_analyzer_tpu.io.native")
     if not native.native_available():
         pytest.skip("native shim unavailable")
     batch = _rand_batch(2, 500, 4)
     for kw in ({}, {"enable_hll": True, "hll_p": 8},
                {"distinct_keys_per_partition": True, "hll_p": 14},
-               {"enable_quantiles": True, "quantiles_per_partition": True}):
-        cfg = AnalyzerConfig(
-            num_partitions=4, batch_size=500, wire_format=5, **kw
-        )
+               {"enable_quantiles": True, "quantiles_per_partition": True},
+               {"count_alive_keys": True, "alive_bitmap_bits": 14}):
+        cfg = AnalyzerConfig(num_partitions=4, batch_size=500, **kw)
         a = pack_batch(batch, cfg, use_native=False)
         b = pack_batch(batch, cfg, use_native=True)
         assert np.array_equal(a, b), kw
-    # alive combo: pair order differs (sorted vs first-touch).  Pinned to
-    # --alive-compaction off: this asserts the UNCOMPACTED v5 pair-section
-    # layout (the compacted rows — no pair sections at all — are covered
-    # by tests/test_alive_compaction.py).
-    cfg = AnalyzerConfig(num_partitions=4, batch_size=500, wire_format=5,
-                         count_alive_keys=True, alive_bitmap_bits=14,
-                         alive_compaction="off")
-    ua = unpack_numpy(pack_batch(batch, cfg, use_native=False).copy(), cfg)
-    ub = unpack_numpy(pack_batch(batch, cfg, use_native=True).copy(), cfg)
-    np_pairs = int(ua["n_pairs"])
-    assert np_pairs == int(ub["n_pairs"])
-    assert dict(zip(ua["alive_slot"][:np_pairs].tolist(),
-                    ua["alive_flag"][:np_pairs].tolist())) == dict(
-        zip(ub["alive_slot"][:np_pairs].tolist(),
-            ub["alive_flag"][:np_pairs].tolist()))
-    assert np.array_equal(np.asarray(ua["counts"]), np.asarray(ub["counts"]))
+    # alive pairs: pair order differs (sorted vs first-touch).
+    sa, fa = batch_alive_pairs(batch, cfg, use_native=False)
+    sb, fb = batch_alive_pairs(batch, cfg, use_native=True)
+    assert len(sa) == len(sb) > 0
+    assert dict(zip(sa.tolist(), fa.tolist())) == dict(
+        zip(sb.tolist(), fb.tolist())
+    )
 
 
 def test_v5_empty_batch_is_identity_pad():
     """A packed empty v5 batch is the superbatch identity pad: zero
     counter/quantile tables, identity-filled extremes."""
-    cfg = _cfg(5)
+    cfg = _cfg()
     buf = pack_batch(RecordBatch.empty(0), cfg, use_native=False)
     got = unpack_numpy(buf, cfg)
     assert int(got["n_valid"]) == 0
@@ -484,86 +432,68 @@ def test_v5_empty_batch_is_identity_pad():
 
 
 def test_section_byte_split_sums_to_packed_nbytes():
-    for wf in (4, 5):
-        for kw in ({}, {"count_alive_keys": True},
-                   {"enable_quantiles": True, "quantiles_per_partition": True}):
-            cfg = AnalyzerConfig(num_partitions=7, batch_size=256,
-                                 wire_format=wf, **kw)
-            per_rec, table = section_byte_split(cfg, 256)
-            assert per_rec + table == packed_nbytes(cfg, 256), (wf, kw)
-    # v5 without the alive pairs ships NO per-record bytes at all.
-    cfg = AnalyzerConfig(num_partitions=7, batch_size=256, wire_format=5)
+    for kw in ({}, {"count_alive_keys": True},
+               {"enable_quantiles": True, "quantiles_per_partition": True},
+               {"distinct_keys_per_partition": True, "hll_p": 14}):
+        cfg = AnalyzerConfig(num_partitions=7, batch_size=256, **kw)
+        per_rec, table = section_byte_split(cfg, 256)
+        assert per_rec + table == packed_nbytes(cfg, 256), kw
+    # Without HLL pairs a row ships NO per-record bytes at all.
+    cfg = AnalyzerConfig(num_partitions=7, batch_size=256,
+                         count_alive_keys=True, enable_quantiles=True)
     per_rec, table = section_byte_split(cfg, 256)
     assert per_rec == 0 and table == packed_nbytes(cfg, 256)
 
 
-def test_pallas_counters_merge_exact():
-    """The v5 pallas table-merge (u32 digit planes + carry) is exact for
-    adversarial i64 values — carries across the 2^32 boundary, negative
-    sentinels, INT64 extremes."""
-    from kafka_topic_analyzer_tpu.ops.pallas_counters import (
-        pallas_counters_merge,
-    )
-
-    rng = np.random.default_rng(9)
-    a = rng.integers(-2**62, 2**62, size=(37, 7), dtype=np.int64)
-    b = rng.integers(-2**62, 2**62, size=(37, 7), dtype=np.int64)
-    a[0, 0] = (1 << 32) - 1
-    b[0, 0] = 1  # lo-word carry
-    a[0, 1] = -1
-    b[0, 1] = 1
-    a[0, 2] = np.iinfo(np.int64).max
-    b[0, 2] = np.iinfo(np.int64).min
-    got = np.asarray(pallas_counters_merge(a, b, interpret=True))
-    assert np.array_equal(got, a + b)
-
-
 def test_ddsketch_edges_match_device_buckets():
-    """The integer edge table and the device update agree on every bucket
-    — including exact edge values, edge+1, and 0."""
-    import jax
-
-    from kafka_topic_analyzer_tpu.jax_support import jnp
+    """The native packers' quantile tables — the whole-batch packer and
+    the fused sink's column append — bucket every size exactly like the
+    numpy edge-table rule, at exact edge values, edge+1, and 0."""
     from kafka_topic_analyzer_tpu.ops.ddsketch import (
         ddsketch_bucket_numpy,
         ddsketch_edges,
-        ddsketch_update,
+        ddsketch_num_buckets,
     )
+    from kafka_topic_analyzer_tpu.packing import FusedPackSink
 
+    native = pytest.importorskip("kafka_topic_analyzer_tpu.io.native")
+    if not native.native_available():
+        pytest.skip("native shim unavailable")
     gamma, nbuckets = (1.0 + 0.005) / (1.0 - 0.005), 2560
     edges = ddsketch_edges(gamma, nbuckets)
+    reachable = edges[edges < 2**31 - 1]  # a value length is an int32
     probe = np.unique(np.concatenate([
         np.array([0, 1, 2, 3], dtype=np.int64),
         edges[:200], edges[:200] + 1,
-        np.array([int(edges[-1]), int(edges[-1]) + 1], dtype=np.int64),
+        reachable[-3:], reachable[-3:] + 1,
     ]))
-    host = ddsketch_bucket_numpy(probe, gamma, nbuckets)
-    counts = jnp.zeros((1, nbuckets + 2), dtype=jnp.int64)
-    dev = np.asarray(jax.jit(
-        lambda c, s: ddsketch_update(
-            c, s, jnp.ones(len(probe), dtype=bool), gamma, nbuckets
-        )
-    )(counts, jnp.asarray(probe)))[0]
-    ref = np.zeros(nbuckets + 2, dtype=np.int64)
-    np.add.at(ref, host, 1)
-    assert np.array_equal(dev, ref)
+    n = len(probe)
+    batch = RecordBatch(
+        partition=np.zeros(n, dtype=np.int32),
+        key_len=np.zeros(n, dtype=np.int32),
+        value_len=probe.astype(np.int32),
+        key_null=np.ones(n, dtype=bool),
+        value_null=np.zeros(n, dtype=bool),
+        ts_s=np.zeros(n, dtype=np.int64),
+        key_hash32=np.zeros(n, dtype=np.uint32),
+        key_hash64=np.zeros(n, dtype=np.uint64),
+        valid=np.ones(n, dtype=bool),
+    )
+    cfg = AnalyzerConfig(num_partitions=1, batch_size=n,
+                         enable_quantiles=True)
+    ref = np.zeros((1, ddsketch_num_buckets(nbuckets)), dtype=np.int64)
+    np.add.at(ref[0], ddsketch_bucket_numpy(probe, gamma, nbuckets), 1)
+    whole = unpack_numpy(pack_batch(batch, cfg, use_native=True), cfg)
+    assert np.array_equal(whole["qcounts"], ref)
+    sink = FusedPackSink(cfg, n, dense_of=lambda p: p)
+    sink.append_batch(batch, reason="frame-fallback")
+    sink.flush()
+    (row,) = sink.take_completed()
+    assert np.array_equal(unpack_numpy(row.buf, cfg)["qcounts"], ref)
 
 
 # ---------------------------------------------------------------------------
 # gating, telemetry, stats
-
-
-def test_env_kill_switch_forces_v4(monkeypatch):
-    monkeypatch.setenv("KTA_WIRE_V4", "1")
-    cfg = AnalyzerConfig(num_partitions=2, batch_size=64)
-    assert cfg.wire_format == 4
-    assert cfg.wire_v4_reason == "env-kill-switch"
-    monkeypatch.delenv("KTA_WIRE_V4")
-    assert AnalyzerConfig(num_partitions=2, batch_size=64).wire_format == 5
-    explicit = AnalyzerConfig(num_partitions=2, batch_size=64, wire_format=4)
-    assert explicit.wire_v4_reason == "explicit"
-    with pytest.raises(ValueError, match="wire_format"):
-        AnalyzerConfig(num_partitions=2, batch_size=64, wire_format=3)
 
 
 def _metric_total(name: str) -> float:
@@ -572,14 +502,12 @@ def _metric_total(name: str) -> float:
 
 
 def test_v4_fallback_booked_and_wire_bytes_counted():
-    before_fb = _metric_total("kta_wire_v4_fallback_total")
     before_bytes = _metric_total("kta_wire_bytes_total")
-    result = _wire_scan(4)
-    assert _metric_total("kta_wire_v4_fallback_total") == before_fb + 1
+    result, _ = _wire_scan()
     grew = _metric_total("kta_wire_bytes_total") - before_bytes
     assert grew > 0
     assert result.wire is not None
-    assert result.wire.format == 4
+    assert result.wire.format == 5
     assert result.wire.bytes_total == int(grew)
     assert result.wire.records == N_PARTS * N_REC
     assert result.wire.bytes_per_record > 0
@@ -588,15 +516,15 @@ def test_v4_fallback_booked_and_wire_bytes_counted():
 def test_stats_wire_line_renders():
     from kafka_topic_analyzer_tpu.report import render_telemetry_stats
 
-    result = _wire_scan(5)
+    result, _ = _wire_scan()
     text = render_telemetry_stats(
         result.telemetry, wire=result.wire,
     )
     assert "wire-format: v5" in text
     assert "fold-table" in text
-    # v5's fold tables dominate this config's buffers (only the alive
-    # pairs remain per-record).
-    assert result.wire.table_bytes > 0
+    # This config's rows are all fold tables: the alive pairs travel in
+    # the dispatch's table and HLL ships as a register table.
+    assert result.wire.table_bytes > 0 and result.wire.per_record_bytes == 0
 
 
 def test_scan_v5_with_native_disabled_subprocess():
@@ -610,7 +538,6 @@ def test_scan_v5_with_native_disabled_subprocess():
         "from kafka_topic_analyzer_tpu.engine import run_scan;"
         "spec = SyntheticSpec(num_partitions=2, messages_per_partition=50, keys_per_partition=9, seed=3);"
         "cfg = AnalyzerConfig(num_partitions=2, batch_size=32, enable_quantiles=True);"
-        "assert cfg.wire_format == 5;"
         "r = run_scan('t', SyntheticSource(spec), TpuBackend(cfg, init_now_s=0, use_native=False), 32);"
         "assert r.metrics.overall_count == 100, r.metrics.overall_count;"
         "assert r.wire.format == 5"

@@ -510,20 +510,6 @@ if "KTA_DISABLE_FUSED" not in (PKG / "packing.py").read_text():
         "packing.py: KTA_DISABLE_FUSED env knob missing from "
         "fused_ingest_enabled"
     )
-# (c) alive-pair compaction is an optimization with the same contract:
-# the env kill switch must exist at the one resolution site (config.py),
-# and the engine must book every bypassed compaction with its reason
-# (kta_alive_compaction_off_total — a silent bypass is a lint failure).
-if "KTA_DISABLE_COMPACTION" not in (PKG / "config.py").read_text():
-    failures.append(
-        "config.py: KTA_DISABLE_COMPACTION env knob missing from the "
-        "alive_compaction resolution (__post_init__)"
-    )
-if "ALIVE_COMPACTION_OFF.labels(" not in (PKG / "engine.py").read_text():
-    failures.append(
-        "engine.py: kta_alive_compaction_off_total booking missing — an "
-        "alive-key scan running uncompacted must record its reason"
-    )
 
 if failures:
     print("lint: fused decode→pack call sites must be gated so the")
@@ -535,11 +521,12 @@ print("lint: OK (fused call sites keep a reachable python-chain fallback)")
 EOF
 
 # Seventh rule: wire-layout offsets and dtypes may only be derived from
-# packing._sections (the single layout source for BOTH wire formats).
+# packing._sections (the single layout source for the rows and the alive
+# tables).
 # Backends, the native shim glue, the parallel layer, and tests must not
 # hand-carve a packed buffer — a literal-offset slice-and-view would pin
-# one format's layout and silently skew when the section list changes
-# (v4→v5 moved every offset).  AST rule: in the scoped files, (a) no
+# today's layout and silently skew when the section list changes (a new
+# section moves every offset after it).  AST rule: in the scoped files, (a) no
 # references to packing.HEADER_BYTES (offset arithmetic belongs next to
 # the section list), and (b) no `.view(dtype)` / `frombuffer`-style
 # retyping of a subscript whose slice bounds are integer literals >= 16
@@ -557,7 +544,7 @@ SCOPE = (
     + sorted(pathlib.Path("tests").glob("*.py"))
 )
 
-#: The compacted pair-table layout (PR 12) has exactly one source too:
+#: The alive-table layout has exactly one source too:
 #: packing._sections(pair_table=True) behind these helpers.  Scoped files
 #: may CALL them (imported from packing) but never re-derive the layout.
 PAIR_HELPERS = {
@@ -637,8 +624,8 @@ for path in SCOPE:
 
 if failures:
     print("lint: wire-layout offsets hard-coded outside packing._sections")
-    print("lint: (the section list is the single layout source — wire v4")
-    print("lint: AND v5; see packing.py module docstring / DESIGN.md §16):")
+    print("lint: (the section list is the single layout source; see")
+    print("lint: packing.py module docstring / DESIGN.md §16):")
     for f in failures:
         print(f"  {f}")
     sys.exit(1)
