@@ -15,9 +15,16 @@ from kafka_topic_analyzer_tpu.results import (
 )
 
 
-def metrics_from_state(state, config: AnalyzerConfig, init_now_s: int) -> TopicMetrics:
+def metrics_from_state(
+    state,
+    config: AnalyzerConfig,
+    init_now_s: int,
+    alive_keys: "int | None" = None,
+) -> TopicMetrics:
     """``state`` is an AnalyzerState whose leaves are host numpy arrays
-    (already merged across devices if the run was sharded)."""
+    (already merged across devices if the run was sharded).  Its alive
+    bitmap is not read: ``alive_keys`` is the bitmap's popcount, counted
+    where the bitmap lives."""
     m = state.metrics
     # Per-partition extremes reduce to the reference's global lines.
     earliest, latest, smallest = finalize_extremes(
@@ -35,10 +42,6 @@ def metrics_from_state(state, config: AnalyzerConfig, init_now_s: int) -> TopicM
         ],
         axis=1,
     )
-    alive_keys = None
-    if state.alive is not None:
-        words = np.asarray(state.alive.words)
-        alive_keys = int(np.bitwise_count(words).sum())
     hll = None
     hll_pp = None
     if state.hll is not None:

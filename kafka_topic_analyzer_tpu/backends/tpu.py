@@ -9,7 +9,8 @@ Design (SURVEY.md §7 M3 + the transfer work in packing.py):
   contract), and each dispatch of an alive-key scan one more buffer, its
   merged alive table;
 - dispatch is asynchronous — the host thread returns immediately and keeps
-  packing the next batch while the device works; `finalize` synchronizes;
+  packing the next batch while the device works; `finalize` synchronizes,
+  counts the alive bitmap on the device and copies back all but the bitmap;
 - at ``--superbatch K`` > 1, K packed buffers stack into one contiguous
   ``uint8[K, N]`` host array folded by a single jitted ``lax.scan`` dispatch
   (state donated once per superbatch, one large transfer), with up to
@@ -23,6 +24,7 @@ Multi-device runs go through `kafka_topic_analyzer_tpu.parallel.sharded`
 
 from __future__ import annotations
 
+import dataclasses
 import os
 
 import jax
@@ -44,6 +46,7 @@ from kafka_topic_analyzer_tpu.jax_support import require_device_platform
 from kafka_topic_analyzer_tpu.models.state import AnalyzerState
 from kafka_topic_analyzer_tpu.obs import metrics as obs_metrics
 from kafka_topic_analyzer_tpu.obs import trace as obs_trace
+from kafka_topic_analyzer_tpu.ops.bitmap import bitmap_popcount
 from kafka_topic_analyzer_tpu.packing import (
     SuperbatchStager,
     batch_alive_pairs,
@@ -190,6 +193,10 @@ def self_check_unpack(device=None) -> None:
 
 
 _checked_devices: "set[str]" = set()
+
+#: The alive bitmap's popcount, one program a process: every audit builds a
+#: backend of its own, and a jit per backend would build it again each time.
+_alive_count = jax.jit(bitmap_popcount)
 
 
 @instrument_steps
@@ -462,16 +469,28 @@ class TpuBackend(MetricBackend):
         )
 
     def finalize(self) -> TopicMetrics:
-        # Retire every in-flight dispatch first so the latency histogram
-        # is complete (device_get below syncs anyway).
-        self.drain_dispatch()
-        # The copy waits for the last step, then moves the whole state.
+        # Retire every in-flight step first, so the latency histogram
+        # closes and the reduce span times the popcount alone.
+        self.block_until_ready()
+        # The alive bitmap stays on the device: only its popcount crosses.
+        count = None
+        if self.state.alive is not None:
+            with obs_trace.maybe_span("finalize.reduce", cat="finalize"):
+                count = jax.block_until_ready(
+                    _alive_count(self.state.alive.words)
+                )
         with obs_trace.maybe_span("finalize.device_get", cat="finalize"):
-            host_state = jax.tree.map(np.asarray, jax.device_get(self.state))
+            host_state, count = jax.tree.map(
+                np.asarray,
+                jax.device_get(
+                    (dataclasses.replace(self.state, alive=None), count)
+                ),
+            )
         obs_metrics.FINALIZE_D2H_BYTES.inc(
-            sum(leaf.nbytes for leaf in jax.tree.leaves(host_state))
+            sum(leaf.nbytes for leaf in jax.tree.leaves((host_state, count)))
         )
         with obs_trace.maybe_span("finalize.host", cat="finalize"):
             return metrics_from_state(
-                host_state, self.config, self.init_now_s
+                host_state, self.config, self.init_now_s,
+                alive_keys=None if count is None else int(count),
             )

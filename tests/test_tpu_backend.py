@@ -69,6 +69,32 @@ def test_alive_bitmap_parity():
     assert m_cpu.alive_keys == sum(replay.values())
 
 
+def test_alive_count_on_the_device_matches_the_host_popcount():
+    from kafka_topic_analyzer_tpu.backends import tpu
+
+    rng = np.random.default_rng(28)
+    words = rng.integers(0, 1 << 32, size=1 << 14, dtype=np.uint32)
+    words[rng.integers(0, words.size, size=500)] = 0xFFFFFFFF
+    words[rng.integers(0, words.size, size=500)] = 0
+    count = tpu._alive_count(words)
+    assert count.dtype == np.int64
+    assert int(count) == int(np.bitwise_count(words).sum())
+
+
+def test_alive_count_is_built_once_a_process():
+    from kafka_topic_analyzer_tpu.obs import metrics as obs_metrics
+
+    cfg = AnalyzerConfig(
+        num_partitions=3, batch_size=256, count_alive_keys=True,
+        alive_bitmap_bits=18,
+    )
+    TpuBackend(cfg, init_now_s=10**10).finalize()
+    second = TpuBackend(cfg, init_now_s=10**10)
+    built = obs_metrics.PROGRAM_BUILD_SECONDS.value
+    assert second.finalize().alive_keys == 0
+    assert obs_metrics.PROGRAM_BUILD_SECONDS.value == built
+
+
 def test_hll_within_error_budget():
     cfg = AnalyzerConfig(num_partitions=3, batch_size=2048, enable_hll=True, hll_p=14)
     m_cpu, m_tpu = run_both(cfg)

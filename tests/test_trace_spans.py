@@ -70,7 +70,7 @@ def test_wire_scan_spans_land_in_the_profiler_trace(tmp_path, workers):
     names = {n for n, _ in events}
     assert {"kta.scan", "kta.ingest", "kta.dispatch", "kta.fetch",
             "kta.fetch.first_byte", "kta.decode_pack", "kta.stage",
-            "kta.finalize", "kta.finalize.device_get",
+            "kta.finalize", "kta.finalize.reduce", "kta.finalize.device_get",
             "kta.finalize.host"} <= names
     (scan_args,) = [a for n, a in events if n == "kta.scan"]
     scan = scan_args["scan"]
@@ -99,8 +99,13 @@ def test_trace_json_keeps_its_names_and_gains_the_new_spans(tmp_path):
     names = {e["name"] for e in tracer.events()}
     assert {"ingest", "dispatch", "finalize", "fetch"} <= names
     assert {"scan", "fetch.first_byte", "decode_pack", "stage",
-            "finalize.device_get", "finalize.host"} <= names
+            "finalize.reduce", "finalize.device_get", "finalize.host"} <= names
     assert not any(n.startswith(obs_trace.PREFIX) for n in names)
+    spans = {e["name"]: e for e in tracer.events()
+             if e["name"].startswith("finalize.")}
+    # The bitmap is counted on the device before the copy starts.
+    assert (spans["finalize.reduce"]["ts"] + spans["finalize.reduce"]["dur"]
+            <= spans["finalize.device_get"]["ts"])
 
 
 def test_spans_stay_jax_free_where_jax_is_not_loaded():
@@ -176,11 +181,19 @@ def test_program_build_listener_is_registered_once():
     assert listeners.count(obs_metrics._on_duration_event) == 1
 
 
-def test_finalize_d2h_bytes_equal_the_state_bytes():
+@pytest.mark.parametrize("alive", [True, False], ids=["alive", "no-alive"])
+def test_finalize_d2h_bytes_equal_the_state_bytes(alive):
+    """The copy moves the state's leaves; with an alive bitmap, the
+    bitmap's int64 popcount in place of its words."""
+    import dataclasses
+
     import jax
 
-    backend = TpuBackend(CFG, init_now_s=10**10)
+    cfg = dataclasses.replace(CFG, count_alive_keys=alive)
+    backend = TpuBackend(cfg, init_now_s=10**10)
     state_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(backend.state))
+    if alive:
+        state_bytes += 8 - backend.state.alive.words.nbytes
     before = obs_metrics.FINALIZE_D2H_BYTES.value
     backend.finalize()
     assert obs_metrics.FINALIZE_D2H_BYTES.value - before == state_bytes

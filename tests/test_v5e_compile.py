@@ -3,8 +3,9 @@
 The TPU compiler refuses what the CPU backend accepts: a Mosaic kernel that cannot be legalized, a program that does not
 fit the chip's memory.  These tests hand it the real shapes: the packed
 step at the CLI defaults and at a larger batch, the superbatch step, the
-alive-table apply in both its forms, and the mesh backend's collective
-merge on a 2x2 mesh.  Nothing runs; a pass is not a chip run.
+alive-table apply in both its forms, one chip's alive count at finalize,
+and the mesh backend's collective merge on a 2x2 mesh.  Nothing runs; a
+pass is not a chip run.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every xdist worker imports this
@@ -158,6 +159,18 @@ def test_apply_pair_table_compiles_for_v5e(one_chip, bits, form):
     args = (_state_shapes(config, one_chip), _pair_shape(config, 1, one_chip))
     compiled = _compile(fn, args, donate=(0,))
     assert ("scatter" in compiled.as_text()) == (form == 1)
+
+
+def test_alive_count_compiles_for_v5e(one_chip):
+    """Finalize's popcount of the CLI's 2^32-slot bitmap: one fused pass
+    over the 512 MiB of words, with no per-word int64 temporary."""
+    import numpy as np
+
+    from kafka_topic_analyzer_tpu.backends.tpu import _alive_count
+
+    words = _shape(one_chip, (1 << 27,), np.uint32)
+    mem = _alive_count.lower(words).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20, mem
 
 
 @pytest.mark.parametrize(
