@@ -317,7 +317,7 @@ def _child(pipe, cfg: dict, seed: int, warmup_records: int) -> None:
     topic = generator.generate(cfg, seed, threads)
     t1 = time.monotonic()
     logs = generator.encode(topic, int(cfg["records_per_batch"]),
-                            crc32c_native, threads)
+                            crc32c_native, threads, generator.codec(cfg))
     del topic
     broker = Broker(logs, warmup_records).start()
     print(f"bench: broker serves {sum(g.end_offset for g in logs)} records "

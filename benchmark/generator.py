@@ -25,6 +25,23 @@ A configuration (``benchmark/configs/<name>.json``) fixes:
 - ``records_per_batch``: records per v2 record batch, per partition;
 - ``produce_rate_per_s``: the topic spans ``records / rate`` seconds, and
   each partition's records are spread evenly over that span.
+
+Optional key; a configuration without it gives the bytes it always gave:
+
+- ``compression``: the producer's ``compression.type``, ``"none"`` (the
+  default) or ``"zstd"``.  A zstd batch is framed as Kafka's Java producer
+  writes it at its default level, 3: the 61-byte header with attribute
+  bits 0-2 set to 4, then the records section after ``count`` as one zstd
+  frame written by a streaming compressor (zstd-jni's output stream: no
+  content size, no checksum).  Batch lengths and CRCs cover the compressed
+  batch; offsets, timestamps and counts are those of the uncompressed
+  batch.  Uncompressed values are zero bytes.  Compressed ones are
+  OpenMessaging Benchmark's randomized payloads (its workload fields
+  ``useRandomizedPayloads``, ``randomBytesRatio``,
+  ``randomizedPayloadPoolSize``): a seeded pool of ``PAYLOAD_POOL_SIZE``
+  payloads, each ``RANDOM_BYTES_RATIO`` of its bytes random and the rest
+  zeros, one drawn at random for each record.  A value shorter than the
+  pool's payloads takes that share of its own length in random bytes.
 """
 
 from __future__ import annotations
@@ -32,7 +49,7 @@ from __future__ import annotations
 import dataclasses
 import math
 from concurrent.futures import ThreadPoolExecutor
-from typing import List
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -42,6 +59,54 @@ BATCH_HEADER_BYTES = 61
 #: Bytes of a v2 batch header before the CRC-covered part (base offset,
 #: batch length, leader epoch, magic, crc).
 CRC_START = 21
+CODECS = ("none", "zstd")
+#: The Java producer's zstd level where ``compression.level`` is unset.
+ZSTD_DEFAULT_LEVEL = 3
+#: Attribute bits 0-2 of a zstd batch.
+ATTR_ZSTD = 4
+#: OMB's ``randomBytesRatio`` and ``randomizedPayloadPoolSize`` for a
+#: compressed topic; OMB sets no default for either.  This share puts
+#: zstd-3 at about three to one on batches of 50 values of 1 KiB.
+RANDOM_BYTES_RATIO = 0.3
+PAYLOAD_POOL_SIZE = 1000
+#: A compressed partition is encoded and compressed in pieces of about this
+#: many records, one job each, so that every thread works to the end and
+#: none holds a whole partition uncompressed.
+PIECE_RECORDS = 1 << 16
+
+
+def codec(cfg: dict) -> str:
+    """The configuration's producer codec."""
+    name = cfg.get("compression", "none")
+    if name not in CODECS:
+        raise ValueError(f"unknown compression {name!r}")
+    if name == "zstd":
+        try:
+            import zstandard  # noqa: F401
+        except ImportError as e:
+            raise RuntimeError(
+                "compression 'zstd' needs the zstandard module, the "
+                "benchmark's own zstd binding") from e
+    return name
+
+
+def random_bytes(value_len: np.ndarray) -> np.ndarray:
+    """Random bytes at the head of each value, as OMB counts them:
+    ``(int) (messageSize * randomBytesRatio)``; none for a null value."""
+    return (np.maximum(value_len, 0) * RANDOM_BYTES_RATIO).astype(np.int64)
+
+
+def payload_pool(seed: int, width: int) -> np.ndarray:
+    """``uint8[PAYLOAD_POOL_SIZE, width]``: the random bytes of the pool's
+    payloads, at most ``width`` of each."""
+    return np.random.default_rng([int(seed) % (1 << 64), 0]).integers(
+        0, 256, size=(PAYLOAD_POOL_SIZE, width), dtype=np.uint8)
+
+
+def payload_choice(seed: int, n: int) -> np.ndarray:
+    """The pool payload of each of a topic's ``n`` records."""
+    return np.random.default_rng([int(seed) % (1 << 64), 1]).integers(
+        0, PAYLOAD_POOL_SIZE, size=n, dtype=np.int32)
 
 
 @dataclasses.dataclass
@@ -55,6 +120,7 @@ class Topic:
     key_bytes: int         # 0: every key is null
     value_len: np.ndarray  # int32, -1 for a null value (tombstone)
     ts_ms: np.ndarray      # int64
+    payload_seed: int = 0  # draws a compressed topic's payloads
 
     @property
     def n(self) -> int:
@@ -220,7 +286,10 @@ def generate(cfg: dict, seed: int, threads: int = 8) -> Topic:
     with pool:
         list(pool.map(fill, range(parts)))
     partition = np.repeat(np.arange(parts, dtype=np.int32), sizes)
-    return Topic(parts, partition, key_id, key_bytes, value_len, ts_ms)
+    # drawn after everything above, which it leaves as it was
+    payload_seed = int(rng.integers(0, 1 << 63))
+    return Topic(parts, partition, key_id, key_bytes, value_len, ts_ms,
+                 payload_seed)
 
 
 # -- wire encoding -----------------------------------------------------------
@@ -274,11 +343,14 @@ class PartitionLog:
 
 
 def encode_partition(ts: np.ndarray, key_id: np.ndarray, key_bytes: int,
-                     value_len: np.ndarray, records_per_batch: int
-                     ) -> PartitionLog:
-    """Encode one partition's records, in offset order, as uncompressed v2
-    batches of ``records_per_batch`` records, CRCs left zero (``put_crcs``
-    writes them)."""
+                     value_len: np.ndarray, records_per_batch: int,
+                     payloads: Optional[Tuple[np.ndarray, np.ndarray]] = None,
+                     first_offset: int = 0) -> PartitionLog:
+    """Encode one partition's records, in offset order from
+    ``first_offset``, as uncompressed v2 batches of ``records_per_batch``
+    records, CRCs left zero (``put_crcs`` writes them).  Values are zero
+    bytes, or with ``payloads = (pool, choice)`` each value starts with
+    the random bytes of its pool payload ``pool[choice[i]]``."""
     n = ts.shape[0]
     offset = np.arange(n, dtype=np.int64)
     batch = offset // records_per_batch
@@ -308,7 +380,7 @@ def encode_partition(ts: np.ndarray, key_id: np.ndarray, key_bytes: int,
     buf = np.zeros(int(batch_end[-1]) if nbatches else 0, dtype=np.uint8)
 
     heads = np.zeros(nbatches, dtype=_HEADER)
-    heads["base_offset"] = bstart_rec
+    heads["base_offset"] = bstart_rec + first_offset
     heads["batch_length"] = batch_total - 12
     heads["magic"] = 2
     counts = np.diff(np.append(bstart_rec, n))
@@ -339,35 +411,132 @@ def encode_partition(ts: np.ndarray, key_id: np.ndarray, key_bytes: int,
             buf[pos[keyed] + col] = keys[:, col]
     pos = pos + np.maximum(key_len, 0)
     _put_varint(buf, pos, z_val, l_val)
-    # Values stay zero bytes and the headers count is 0: nothing to write.
+    if payloads is not None:
+        pool, choice = payloads
+        _put_values(buf, pos + l_val, random_bytes(value_len), pool.ravel(),
+                    choice.astype(np.int64) * pool.shape[1])
+    # The headers count is 0: nothing to write.
 
-    return PartitionLog(buf, batch_start, batch_end, bstart_rec, n)
+    return PartitionLog(buf, batch_start, batch_end,
+                        bstart_rec + first_offset, first_offset + n)
+
+
+def _put_values(buf: np.ndarray, pos: np.ndarray, lens: np.ndarray,
+                src: np.ndarray, offs: np.ndarray,
+                chunk: int = 1 << 16) -> None:
+    """``buf[pos[i]:pos[i] + lens[i]] = src[offs[i]:offs[i] + lens[i]]``
+    for every value: a gather and a scatter of rows for each length."""
+    order = np.argsort(lens, kind="stable")
+    cuts = np.flatnonzero(np.diff(lens[order])) + 1
+    for rows in np.split(order, cuts):
+        length = int(lens[rows[0]]) if rows.size else 0
+        if length <= 0:
+            continue
+        rows_src = np.lib.stride_tricks.sliding_window_view(src, length)
+        dst = np.lib.stride_tricks.sliding_window_view(buf, length,
+                                                       writeable=True)
+        for a in range(0, rows.size, chunk):
+            r = rows[a:a + chunk]
+            dst[pos[r]] = rows_src[offs[r]]
+
+
+def _put_be(buf: np.ndarray, at: np.ndarray, values, dtype: str) -> None:
+    """Write ``values`` big-endian as ``dtype`` at ``buf[at[i]:]``."""
+    width = np.dtype(dtype).itemsize
+    be = np.broadcast_to(np.asarray(values, dtype=dtype), at.shape)
+    be = np.ascontiguousarray(be).view(np.uint8).reshape(at.shape[0], width)
+    for col in range(width):
+        buf[at + col] = be[:, col]
+
+
+def compress_zstd(log: PartitionLog) -> PartitionLog:
+    """``log`` with each batch's records section compressed into one zstd
+    frame as Kafka's Java producer writes it (attributes 4; CRCs left
+    zero).
+
+    The compressor is the ``zstandard`` module's, the benchmark's own
+    binding: a streaming frame (zstd-jni's output stream), so no content
+    size, and no checksum.  It releases the GIL."""
+    import zstandard
+
+    cctx = zstandard.ZstdCompressor(level=ZSTD_DEFAULT_LEVEL,
+                                    write_content_size=False,
+                                    write_checksum=False)
+    parts: List[bytes] = []
+    view = memoryview(log.buf)
+    for a, b in zip(log.starts.tolist(), log.ends.tolist()):
+        stream = cctx.compressobj()
+        parts += (bytes(view[a:a + BATCH_HEADER_BYTES]),
+                  stream.compress(view[a + BATCH_HEADER_BYTES:b])
+                  + stream.flush())
+    total = BATCH_HEADER_BYTES + np.array([len(f) for f in parts[1::2]],
+                                          dtype=np.int64)
+    ends = np.cumsum(total)
+    starts = ends - total
+    buf = np.frombuffer(bytearray().join(parts), dtype=np.uint8)
+    _put_be(buf, starts + _HEADER.fields["batch_length"][1], total - 12,
+            ">i4")
+    _put_be(buf, starts + _HEADER.fields["attributes"][1], ATTR_ZSTD, ">i2")
+    return PartitionLog(buf, starts, ends, log.base_offsets, log.end_offset)
+
+
+def _join(pieces: List[PartitionLog]) -> PartitionLog:
+    """One partition's log from its pieces, in offset order."""
+    if len(pieces) == 1:
+        return pieces[0]
+    shift = np.cumsum([0] + [g.buf.size for g in pieces[:-1]])
+    return PartitionLog(
+        np.concatenate([g.buf for g in pieces]),
+        np.concatenate([g.starts + d for g, d in zip(pieces, shift)]),
+        np.concatenate([g.ends + d for g, d in zip(pieces, shift)]),
+        np.concatenate([g.base_offsets for g in pieces]),
+        pieces[-1].end_offset)
 
 
 def put_crcs(log: PartitionLog, lo: int, hi: int, crc32c) -> None:
     """Checksum batches ``lo:hi`` of a partition and write their CRCs."""
     starts = log.starts[lo:hi]
     crcs = crc32c(log.buf, starts + CRC_START, log.ends[lo:hi])
-    crc_be = np.asarray(crcs, dtype=">u4").view(np.uint8).reshape(-1, 4)
-    for col in range(4):
-        log.buf[starts + 17 + col] = crc_be[:, col]
+    _put_be(log.buf, starts + _HEADER.fields["crc"][1], crcs, ">u4")
 
 
 def encode(topic: Topic, records_per_batch: int, crc32c,
-           threads: int = 8) -> List[PartitionLog]:
-    """Every partition's log, encoded and checksummed on ``threads``
-    threads (numpy and the checksum release the GIL).  ``crc32c(buf,
-    starts, stops)`` returns the CRC of each ``buf[starts[i]:stops[i]]``."""
+           threads: int = 8, compression: str = "none"
+           ) -> List[PartitionLog]:
+    """Every partition's log, encoded, compressed with the producer codec
+    ``compression`` and checksummed on ``threads`` threads (numpy, the
+    compressor and the checksum release the GIL).  ``crc32c(buf, starts,
+    stops)`` returns the CRC of each ``buf[starts[i]:stops[i]]``."""
+    if compression not in CODECS:
+        raise ValueError(f"unknown compression {compression!r}")
     bounds = np.searchsorted(topic.partition, np.arange(topic.partitions + 1))
+    sizes = np.diff(bounds)
+    if compression == "none":
+        payloads = None
+        piece = int(sizes.max(initial=1))
+    else:
+        payloads = (payload_pool(topic.payload_seed, int(random_bytes(
+                        topic.value_len).max(initial=0))),
+                    payload_choice(topic.payload_seed, topic.n))
+        piece = records_per_batch * max(1, PIECE_RECORDS // records_per_batch)
+    jobs = [(p, a, min(a + piece, int(sizes[p])))
+            for p in range(topic.partitions)
+            for a in range(0, max(int(sizes[p]), 1), piece)]
 
-    def one(p: int) -> PartitionLog:
-        lo, hi = bounds[p], bounds[p + 1]
-        return encode_partition(topic.ts_ms[lo:hi], topic.key_id[lo:hi],
-                                topic.key_bytes, topic.value_len[lo:hi],
-                                records_per_batch)
+    def one(job) -> PartitionLog:
+        p, a, b = job
+        lo, hi = int(bounds[p]) + a, int(bounds[p]) + b
+        log = encode_partition(
+            topic.ts_ms[lo:hi], topic.key_id[lo:hi], topic.key_bytes,
+            topic.value_len[lo:hi], records_per_batch,
+            None if payloads is None else (payloads[0], payloads[1][lo:hi]),
+            a)
+        return log if payloads is None else compress_zstd(log)
 
     with ThreadPoolExecutor(threads) as pool:
-        logs = list(pool.map(one, range(topic.partitions)))
+        done = list(pool.map(one, jobs))
+        logs = [_join([g for (q, _, _), g in zip(jobs, done) if q == p])
+                for p in range(topic.partitions)]
         # Checksums in equal slices of all batches, so every thread works
         # to the end whatever the partitions' sizes.
         total = sum(len(g.starts) for g in logs)

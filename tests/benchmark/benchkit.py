@@ -43,6 +43,44 @@ def read(run):
 '''
 
 
+with open(os.path.join(REPO, "benchmark", "configs",
+                       "omb-1topic-16p-1kb.json")) as _f:
+    #: ``omb-1topic-16p-1kb`` with the producer's ``compression.type=zstd``,
+    #: cut to 4,096 records: 16 partitions of six batches.
+    TINY_ZSTD_CONFIG = dict(json.load(_f), name="tiny-omb-zstd",
+                            records=4096, compression="zstd")
+TINY_ZSTD_TRAFFIC = {
+    "why": "test-only: counters and size quantiles, small device batches",
+    "flags": ["--quantiles", "--batch-size", "1024"],
+    "warmup_records": 1600,
+}
+
+
+def add_cell(root: str, workload: str, config: dict, traffic: dict,
+             chips: int = 1) -> dict:
+    """Add the cell ``workload`` to the benchmark copy at ``root`` by
+    writing its configuration and traffic files and their manifest
+    entries; returns the manifest, written."""
+    bench = os.path.join(root, "benchmark")
+    with open(os.path.join(bench, "configs", f"{config['name']}.json"),
+              "w") as f:
+        json.dump(config, f)
+    with open(os.path.join(bench, "traffic", f"{workload}.json"), "w") as f:
+        json.dump(traffic, f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    doc["configs"].append({
+        "name": config["name"], "source": "test",
+        "file": f"benchmark/configs/{config['name']}.json",
+        "reduced": ["records"], "why": "test"})
+    doc["workloads"].append({
+        "name": workload, "config": config["name"], "traffic": workload,
+        "chips": chips, "why": "test"})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(doc, f)
+    return doc
+
+
 def copy_with_tiny_cell(dest: str) -> str:
     """A copy of the benchmark under ``dest`` with one more cell,
     ``tiny-alive``, added by writing files and manifest entries only."""
@@ -50,29 +88,26 @@ def copy_with_tiny_cell(dest: str) -> str:
     shutil.copytree(os.path.join(REPO, "benchmark"),
                     os.path.join(root, "benchmark"),
                     ignore=shutil.ignore_patterns("__pycache__"))
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    bench = os.path.join(root, "benchmark")
-    with open(os.path.join(bench, "configs", "tiny-changelog.json"),
+    shutil.copy(os.path.join(REPO, "BENCHMARK.json"), root)
+    with open(os.path.join(root, "benchmark", "metrics", "audits_run.py"),
               "w") as f:
-        json.dump(TINY_CONFIG, f)
-    with open(os.path.join(bench, "traffic", "tiny-alive.json"), "w") as f:
-        json.dump(TINY_TRAFFIC, f)
-    with open(os.path.join(bench, "metrics", "audits_run.py"), "w") as f:
         f.write(TINY_METRIC)
-    doc["configs"].append({
-        "name": "tiny-changelog", "source": "test",
-        "file": "benchmark/configs/tiny-changelog.json",
-        "reduced": ["records"], "why": "test"})
-    doc["workloads"].append({
-        "name": "tiny-alive", "config": "tiny-changelog",
-        "traffic": "tiny-alive", "chips": 1, "why": "test"})
+    doc = add_cell(root, "tiny-alive", TINY_CONFIG, TINY_TRAFFIC)
     doc["per_layer"].append({
         "name": "audits_run", "unit": "audits", "better": "higher",
         "source": "host_clock", "layer": "test", "moves":
         "audit_records_per_s", "workloads": ["tiny-alive"]})
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(doc, f)
+    return root
+
+
+def copy_with_tiny_zstd_cell(dest: str) -> str:
+    """The tiny cell's copy of the benchmark with one more cell,
+    ``tiny-zstd``, a zstd-compressed topic, added by files and manifest
+    entries only."""
+    root = copy_with_tiny_cell(dest)
+    add_cell(root, "tiny-zstd", TINY_ZSTD_CONFIG, TINY_ZSTD_TRAFFIC)
     return root
 
 

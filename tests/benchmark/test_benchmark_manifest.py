@@ -6,7 +6,7 @@ import os
 
 import pytest
 
-from benchmark import manifest
+from benchmark import generator, manifest
 from benchkit import REPO, copy_with_tiny_cell
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
@@ -18,7 +18,7 @@ def test_each_cell_loads_from_its_files(cell):
     c = manifest.load_cell(REPO, cell)
     entry = next(w for w in DOC["workloads"] if w["name"] == cell)
     assert c.config["name"] == entry["config"]
-    assert c.chips == entry["chips"] == 1
+    assert c.chips == entry["chips"] in (1, 4)
     assert isinstance(c.traffic["flags"], list)
     kinds = {m.kind for m in c.metrics}
     assert kinds == {"end_to_end", "per_layer"}
@@ -34,6 +34,8 @@ def test_config_files_state_what_the_manifest_says(cfg):
     assert data["reduced"] == cfg["reduced"]
     for key in data["reduced"]:
         assert data[key] < data["published"][key]
+    # the generator's optional key, where a configuration states it
+    assert data.get("compression", "none") in generator.CODECS
 
 
 def test_config_guarantees_are_the_cli_defaults():

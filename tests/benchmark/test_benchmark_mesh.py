@@ -79,24 +79,11 @@ def _copy_with_tiny_mesh_cell(dest):
     """The tiny changelog cell's copy of the benchmark, with a four-chip
     cell ``tiny-mesh4`` added by files and entries, and listed for the
     mesh metrics."""
-    from benchkit import TINY_CONFIG, TINY_TRAFFIC
+    from benchkit import TINY_CONFIG, TINY_TRAFFIC, add_cell
 
     root = copy_with_tiny_cell(dest)
-    bench = os.path.join(root, "benchmark")
-    with open(os.path.join(bench, "configs", "tiny-changelog-mesh4.json"),
-              "w") as f:
-        json.dump({**TINY_CONFIG, **TINY_MESH_CONFIG}, f)
-    with open(os.path.join(bench, "traffic", "tiny-mesh4.json"), "w") as f:
-        json.dump({**TINY_TRAFFIC, "flags": TINY_MESH_FLAGS}, f)
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    doc["configs"].append({
-        "name": "tiny-changelog-mesh4", "source": "test",
-        "file": "benchmark/configs/tiny-changelog-mesh4.json",
-        "reduced": ["records"], "why": "test"})
-    doc["workloads"].append({
-        "name": "tiny-mesh4", "config": "tiny-changelog-mesh4",
-        "traffic": "tiny-mesh4", "chips": 4, "why": "test"})
+    doc = add_cell(root, "tiny-mesh4", {**TINY_CONFIG, **TINY_MESH_CONFIG},
+                   {**TINY_TRAFFIC, "flags": TINY_MESH_FLAGS}, chips=4)
     for m in doc["per_layer"]:
         if m["name"] in READERS:
             m["workloads"].append("tiny-mesh4")
