@@ -7,7 +7,8 @@ began; the run ends when the audit in progress completes.  Each audit reads
 a topic name no earlier audit of the run has read.  After the window the
 device's peak memory is read, the broker stops, and every audit's report is
 compared with the plain reference (reference.py).  With ``trace`` a
-profiler trace of a few seconds inside the window is reduced
+profiler trace inside the window, of a few seconds and at least until the
+scan has put work on the device (``Tracer``), is reduced
 (trace_reduce.py) and the cell's per-layer metrics are reported; without
 it, its end-to-end metrics.
 """
@@ -38,9 +39,18 @@ from benchmark.broker import WARMUP_PREFIX, BrokerProcess
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 #: Where a traced run starts its trace, after the window's start, and for
-#: how long it traces.
+#: how long it traces at least.
 TRACE_DELAY_S = 2.0
 TRACE_S = 3.0
+#: The trace then goes on until the scan has dispatched this many steps
+#: since it began (the first step's device work has then surely run), the
+#: audits have ended, or TRACE_MAX_S has passed; it checks every
+#: TRACE_POLL_S.  A trace that holds no device op reads nothing, and a scan
+#: that folds slowly may dispatch no step for most of a minute: each of its
+#: streams dispatches when it has packed a whole device batch.
+TRACE_MIN_BATCHES = 2
+TRACE_MAX_S = 120.0
+TRACE_POLL_S = 0.05
 #: A reading that could not be taken (a missing line) prints as this.
 MISSING = 1e9
 
@@ -156,21 +166,33 @@ def audit(topic: str, port: int, flags: List[str]) -> Audit:
 
 
 class Tracer:
-    """Traces ``TRACE_S`` seconds, ``TRACE_DELAY_S`` after ``start()``, on a
-    thread, and counts the records folded meanwhile."""
+    """Traces on a thread, from ``TRACE_DELAY_S`` after ``start()``, for
+    ``TRACE_S`` and on until the scan has dispatched ``TRACE_MIN_BATCHES``
+    steps, ``stop()`` is called or ``TRACE_MAX_S`` has passed; counts the
+    records folded and the steps dispatched meanwhile."""
 
     def __init__(self):
         self.dir = tempfile.mkdtemp(prefix="bench-trace-")
         self.seconds = 0.0
         self.records = 0.0
+        self.batches = 0.0
         self.error: Optional[BaseException] = None
+        self._stopped = threading.Event()
         self._thread = threading.Thread(target=self._trace, daemon=True)
 
     def start(self) -> None:
         self._thread.start()
 
-    def _records(self) -> float:
-        return sum(counters().get("kta_scan_records_total", {}).values())
+    def stop(self) -> None:
+        """The audits have ended: nothing more will be dispatched."""
+        self._stopped.set()
+
+    @staticmethod
+    def _counts() -> tuple:
+        """The records folded and the steps dispatched so far."""
+        c = counters()
+        return tuple(sum(c.get(name, {}).values()) for name in
+                     ("kta_scan_records_total", "kta_scan_batches_total"))
 
     def _trace(self) -> None:
         import jax
@@ -181,11 +203,17 @@ class Tracer:
             opts.python_tracer_level = 0  # device ops and annotations only
             opts.host_tracer_level = 1
             jax.profiler.start_trace(self.dir, profiler_options=opts)
-            n0, t0 = self._records(), time.monotonic()
+            (n0, b0), t0 = self._counts(), time.monotonic()
             time.sleep(TRACE_S)
-            n1, t1 = self._records(), time.monotonic()
+            while True:
+                (n1, b1), t1 = self._counts(), time.monotonic()
+                if (b1 - b0 >= TRACE_MIN_BATCHES or t1 - t0 >= TRACE_MAX_S
+                        or self._stopped.is_set()):
+                    break
+                self._stopped.wait(TRACE_POLL_S)
             jax.profiler.stop_trace()
             self.seconds, self.records = t1 - t0, n1 - n0
+            self.batches = b1 - b0
         except BaseException as e:  # reported by finish()
             self.error = e
 
@@ -279,10 +307,16 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
             while not audits or time.monotonic() - window_start < seconds:
                 audits.append(audit(f"audit-{len(audits):04d}", port, flags))
         finally:
+            if tracer:
+                tracer.stop()
             jax.monitoring.unregister_event_duration_listener(on_event)
             jax.monitoring.unregister_event_listener(on_event)
         deltas = delta(before, counters())
         summary = tracer.finish() if tracer else None
+        if tracer:
+            print(f"bench: traced {tracer.seconds:.2f}s, "
+                  f"{tracer.batches:.0f} scan batches, "
+                  f"{tracer.records:.0f} records", file=sys.stderr)
         peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                    for d in devices[:cell.chips])
         hits = builds.count(CACHE_HIT_EVENT)
