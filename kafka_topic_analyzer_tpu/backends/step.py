@@ -50,8 +50,9 @@ def apply_pair_table(
     dispatch instead of once per batch.  The table's form decides the
     kernel (one rule, packing.alive_table_mode, read here via the section
     names): set/clear word MASKS merge elementwise like any other fold
-    table — no scatter at all — while the bounded pair list keeps the
-    scatter apply for slot spaces too large to mask.  Under a
+    table — no scatter at all — while the bounded pair list is applied
+    in place, a gather and a scatter-add on the words its pairs touch,
+    for slot spaces too large to mask.  Under a
     space-sharded mesh each shard masks/slices to its slot range
     (``space_index``); the table is replicated over the space axis by its
     input spec, so no per-step collective exists."""

@@ -7,7 +7,8 @@ value; collisions conflate keys exactly as the reference's 32-bit hash does —
 but updated a dispatch at a time on device.  The host resolves
 last-writer-wins per slot (packing.py's alive table), so the device only
 applies the result: ``words = (words & ~clear) | set``, with the set/clear
-masks shipped as words or built from deduped (slot, aliveness) pairs.
+masks shipped as words, or deduped (slot, aliveness) pairs applied to the
+words they touch.
 
 Correctness across dispatches relies on batches arriving in per-partition
 offset order, and across devices on every partition being pinned to one
@@ -23,6 +24,11 @@ batch and the final merge over the data axis is an elementwise OR (pmax).
 from __future__ import annotations
 
 from kafka_topic_analyzer_tpu.jax_support import jnp
+
+
+#: Odd multiplier (2^32 / golden ratio) that spreads the pair apply's
+#: no-op entries over the bitmap (`bitmap_apply_pairs`).
+_SPREAD = 0x9E3779B1
 
 
 def bitmap_num_words(bits: int, space_shards: int = 1) -> int:
@@ -42,29 +48,50 @@ def bitmap_apply_pairs(
     space_shards: int = 1,
 ):
     """Apply host-deduped (slot, aliveness) pairs: the alive table's PAIR
-    form (packing.alive_table_mode == 1).
+    form (packing.alive_table_mode == 1), in place at O(pairs).
 
     The host already performed last-writer-wins per slot
-    (packing.py::dedupe_slots_*), so each live slot appears exactly once —
-    distinct slots in a word own distinct bits, making scatter-add equal to
-    bitwise OR, and no device-side sort is needed.
+    (packing.py::dedupe_slots_*), so each live slot appears exactly once
+    and distinct slots in a word own distinct bits.  Each pair gathers its
+    word, turns its bit to what the pair says, and scatter-adds the
+    difference (``+bit``, ``-bit`` mod 2^32, or 0) back into ``words``:
+    every delta touches its own bit, so no carry or borrow crosses bits
+    and the sum equals ``(words & ~clear) | set`` bit for bit.  Nothing of
+    the bitmap's size is built besides the (donated) result.
+
+    Entries past ``n_pairs`` and slots outside this space shard add 0 at
+    a position hashed from their own: in bounds, and spread over the
+    bitmap, since updates that crowd one stretch of words serialize.
     """
+    from kafka_topic_analyzer_tpu.jax_support import lax
+
     B = slot.shape[0]
     W = bitmap_num_words(bits, space_shards)
-    live = jnp.arange(B, dtype=jnp.int32) < n_pairs
-    s = slot.astype(jnp.int64)
-    shard_base = jnp.int64(W * 32) * space_index
-    in_shard = live & (s >= shard_base) & (s < shard_base + W * 32)
-    local = s - shard_base
-    word = jnp.where(in_shard, local >> 5, W).astype(jnp.int32)
-    bit = jnp.uint32(1) << (local & 31).astype(jnp.uint32)
-    alive = alive_flag.astype(bool)
-    set_mask = jnp.where(in_shard & alive, bit, jnp.uint32(0))
-    clear_mask = jnp.where(in_shard & ~alive, bit, jnp.uint32(0))
-    scratch = jnp.zeros((W + 1,), dtype=jnp.uint32)
-    set_words = scratch.at[word].add(set_mask)[:W]
-    clear_words = scratch.at[word].add(clear_mask)[:W]
-    return (words & ~clear_words) | set_words
+    pos = lax.iota(jnp.int64, B)
+    local = slot.astype(jnp.int64) - jnp.int64(W * 32) * space_index
+    in_shard = (pos < n_pairs) & (local >= 0) & (local < W * 32)
+    # W is a power of two and an odd multiplier permutes the low bits; in
+    # 32 bits, as a 64-bit remainder added ~65 ms to each build of the step
+    # on a v5e.
+    spread = (lax.iota(jnp.uint32, B) * jnp.uint32(_SPREAD)) & jnp.uint32(W - 1)
+    word = lax.select(
+        in_shard, (local >> 5).astype(jnp.int32), spread.astype(jnp.int32)
+    )[:, None]
+    bit = lax.shift_left(jnp.uint32(1), (local & 31).astype(jnp.uint32))
+    # lax's gather and scatter, not jnp's ``.at[]``, which traces and lowers
+    # slower: the step is built anew for every scan.
+    dims = lax.GatherDimensionNumbers(
+        offset_dims=(), collapsed_slice_dims=(0,), start_index_map=(0,))
+    old = lax.gather(words, word, dims, (1,),
+                     mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    new = lax.select(alive_flag != 0, old | bit, old & ~bit)
+    delta = lax.select(in_shard, new - old, lax.full_like(old, 0))
+    return lax.scatter_add(
+        words, word, delta,
+        lax.ScatterDimensionNumbers(
+            update_window_dims=(), inserted_window_dims=(0,),
+            scatter_dims_to_operand_dims=(0,)),
+        mode=lax.GatherScatterMode.PROMISE_IN_BOUNDS)
 
 
 def bitmap_apply_masks(

@@ -694,3 +694,174 @@ def test_mesh_pinned_resume_error_names_feature(tmp_path):
     with pytest.raises(ValueError, match="alive keys"):
         load_snapshot(str(tmp_path), "other-topic".replace("-", "_"),
                       dataclasses.replace(cfg11, num_partitions=5))
+
+
+# ---------------------------------------------------------------------------
+# the pair form's device apply: exact, and O(pairs) per dispatch
+
+#: (bits, table capacity): a 2^10-slot bitmap of 32 words with a 96-pair
+#: table, so several pairs share a word; and a 2^17-slot bitmap of 4,096
+#: words (four of the chip's (8, 128) tiles) with a 2,500-pair table.
+_APPLY_SMALL = (10, 96)
+_APPLY_TILED = (17, 2500)
+
+
+def _three_pass_reference(words, slot, flag, n_pairs, bits, space_index,
+                          space_shards):
+    """The pair form's LWW apply as a formula on numpy arrays: scatter the
+    live in-shard set and clear bits into two zeroed word arrays, then
+    ``(words & ~clear) | set``."""
+    w = (1 << (bits - 5)) // space_shards
+    base = w * 32 * space_index
+    local = slot[:n_pairs].astype(np.int64) - base
+    keep = (local >= 0) & (local < w * 32)
+    local, alive = local[keep], flag[:n_pairs][keep].astype(bool)
+    bit = np.left_shift(np.uint32(1), (local & 31).astype(np.uint32))
+    set_words = np.zeros(w, dtype=np.uint32)
+    clear_words = np.zeros(w, dtype=np.uint32)
+    np.add.at(set_words, local[alive] >> 5, bit[alive])
+    np.add.at(clear_words, local[~alive] >> 5, bit[~alive])
+    return (words & ~clear_words) | set_words
+
+
+def _pair_apply_case(bits, cap, n_live, seed=2147400032):
+    """A deduped pair table and a non-zero starting bitmap of the whole
+    2^bits-slot space.  In every space shard's first word the table sets
+    a clear bit 0, clears a set bit 31, sets an already-set bit, clears an
+    already-clear bit, and puts four slots in one word; random unique
+    slots fill the rest.  Entries past ``n_live`` hold in-range slots, so
+    an apply that read them would change the bitmap."""
+    rng = np.random.default_rng(seed)
+    total_words = 1 << (bits - 5)
+    start = rng.integers(0, 1 << 32, size=total_words,
+                         dtype=np.uint64).astype(np.uint32)
+    forced = {}   # slot -> (alive flag, starting bit)
+    for shard_base in range(0, 1 << bits, (1 << bits) // 4):
+        forced.update({
+            shard_base + 0: (1, 0),    # bit 0: set a clear bit
+            shard_base + 31: (0, 1),   # bit 31: clear a set bit
+            shard_base + 5: (1, 1),    # set an already-set bit
+            shard_base + 6: (0, 0),    # clear an already-clear bit
+        })
+    for s, (_, was) in forced.items():
+        w, b = s >> 5, np.uint32(1 << (s & 31))
+        start[w] = (start[w] | b) if was else (start[w] & ~b)
+    others = rng.permutation(
+        np.setdiff1d(np.arange(1 << bits), list(forced))
+    )[: cap - len(forced)]
+    slot = np.concatenate([list(forced), others]).astype(np.uint32)
+    flag = np.concatenate([
+        [f for f, _ in forced.values()],
+        rng.integers(0, 2, size=others.size),
+    ]).astype(np.uint8)
+    order = rng.permutation(cap)
+    slot, flag = slot[order], flag[order]
+    dead = np.arange(n_live, cap)
+    slot[dead] = rng.integers(0, 1 << bits, size=dead.size)
+    flag[dead] = rng.integers(0, 2, size=dead.size)
+    return start, slot, flag
+
+
+def _pair_apply_params():
+    """Every space shard of the small table at no, few, part and all live
+    pairs; the tiled table at one shard of each split."""
+    small = [
+        (_APPLY_SMALL, shards, index, n_live)
+        for shards in (1, 2, 4) for index in range(shards)
+        for n_live in (0, 5, _APPLY_SMALL[1] // 2 + 5, _APPLY_SMALL[1])
+    ]
+    tiled = [
+        (_APPLY_TILED, shards, shards - 1, n_live)
+        for shards in (1, 2, 4) for n_live in (0, 5, 1300, _APPLY_TILED[1])
+    ]
+    return [
+        pytest.param(*p, id=f"bits{p[0][0]}-space{p[1]}.{p[2]}-live{p[3]}")
+        for p in small + tiled
+    ]
+
+
+@pytest.mark.parametrize("geometry,space_shards,space_index,n_live",
+                         _pair_apply_params())
+def test_pair_apply_matches_three_pass_reference(geometry, space_shards,
+                                                 space_index, n_live):
+    """``bitmap_apply_pairs`` (gather, per-bit delta, scatter-add) equals
+    the three-pass LWW formula bit for bit, on each shard's slot range."""
+    import jax
+
+    from kafka_topic_analyzer_tpu.jax_support import jnp
+    from kafka_topic_analyzer_tpu.ops.bitmap import (
+        bitmap_apply_pairs,
+        bitmap_num_words,
+    )
+
+    bits, cap = geometry
+    start, slot, flag = _pair_apply_case(bits, cap, n_live)
+    w = bitmap_num_words(bits, space_shards)
+    shard = start[space_index * w:(space_index + 1) * w].copy()
+    apply = jax.jit(
+        lambda words, s, f, n, i: bitmap_apply_pairs(
+            words, s, f, n, bits=bits, space_index=i,
+            space_shards=space_shards,
+        )
+    )
+    got = np.asarray(apply(
+        jnp.asarray(shard), jnp.asarray(slot), jnp.asarray(flag),
+        jnp.int32(n_live), jnp.int32(space_index),
+    ))
+    want = _three_pass_reference(shard, slot, flag, n_live, bits,
+                                 space_index, space_shards)
+    np.testing.assert_array_equal(got, want)
+    if n_live == 0:
+        np.testing.assert_array_equal(got, shard)
+
+
+def _jaxpr_values(jaxpr):
+    """Every value an equation of ``jaxpr`` (or of a jaxpr nested in its
+    parameters) defines, with the primitive that defines it."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield eqn.primitive.name, v
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                if isinstance(sub, ClosedJaxpr):
+                    yield from _jaxpr_values(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    yield from _jaxpr_values(sub)
+
+
+@pytest.mark.parametrize("space_shards", [1, 4], ids=["one-chip", "space-4"])
+def test_pair_apply_builds_nothing_of_bitmap_size(space_shards):
+    """At a 2^28-bit bitmap (2^23 words a shard at one space shard) and a
+    2^18-pair table, the apply's only value of W or W+1 elements is the
+    scatter-add into the state: no zeroed scratch, no elementwise pass, no
+    copy, so a dispatch costs its pairs and not the bitmap."""
+    import jax
+
+    from kafka_topic_analyzer_tpu.jax_support import jnp
+    from kafka_topic_analyzer_tpu.ops.bitmap import (
+        bitmap_apply_pairs,
+        bitmap_num_words,
+    )
+
+    bits, cap = 28, 1 << 18
+    w = bitmap_num_words(bits, space_shards)
+    closed = jax.make_jaxpr(
+        lambda words, s, f, n, i: bitmap_apply_pairs(
+            words, s, f, n, bits=bits, space_index=i,
+            space_shards=space_shards,
+        )
+    )(
+        jax.ShapeDtypeStruct((w,), jnp.uint32),
+        jax.ShapeDtypeStruct((cap,), jnp.uint32),
+        jax.ShapeDtypeStruct((cap,), jnp.uint8),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+    )
+    big = [
+        (prim, v) for prim, v in _jaxpr_values(closed.jaxpr)
+        if getattr(v.aval, "size", 0) in (w, w + 1)
+    ]
+    assert [prim for prim, _ in big] == ["scatter-add"], big
+    assert big[0][1] is closed.jaxpr.outvars[0]

@@ -139,7 +139,8 @@ def test_superstep_compiles_for_v5e(one_chip):
 def test_apply_pair_table_compiles_for_v5e(one_chip, bits, form):
     """The dispatch's alive table applied to the state, in each form
     ``packing.alive_table_mode`` picks: the bounded pair list's scatter at
-    the CLI's 2^32-slot bitmap, the elementwise set/clear masks below."""
+    the CLI's 2^32-slot bitmap, in place with no temporary near the
+    bitmap's 512 MiB, and the elementwise set/clear masks below."""
     from kafka_topic_analyzer_tpu.backends.step import apply_pair_table
     from kafka_topic_analyzer_tpu.packing import (
         alive_table_mode,
@@ -159,6 +160,9 @@ def test_apply_pair_table_compiles_for_v5e(one_chip, bits, form):
     args = (_state_shapes(config, one_chip), _pair_shape(config, 1, one_chip))
     compiled = _compile(fn, args, donate=(0,))
     assert ("scatter" in compiled.as_text()) == (form == 1)
+    if form == 1:
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 16 << 20, mem
 
 
 def test_alive_count_compiles_for_v5e(one_chip):
